@@ -1,24 +1,21 @@
 """Fail when the SPMD mesh path stops landing its programs in progcache.
 
-Sibling of ``check_bench_cache.py``, for the sharded whole-stage
-programs: the in-program shuffle (``parallel/shuffle.py``) funnels every
+The in-program shuffle (``parallel/shuffle.py``) funnels every
 exchange through ONE module-level jit entry (``_run_shuffle_step``), so
-its executable must persist through ``utils/progcache`` exactly like the
-single-device bench kernel does — otherwise every fresh worker process
+its executable must persist through ``utils/progcache`` — otherwise every fresh worker process
 eats the shard_map program's cold compile per plan shape, which is the
-regression this fence makes loud. Unlike the bench fence it needs no
-tracked seed and no TPU box: it is a live two-process proof under
+regression this fence makes loud. It is a live two-process proof under
 ``JAX_PLATFORMS=cpu`` with 8 virtual devices.
 
-**Probe 1 (land).** A subprocess points progcache at a throwaway
-directory, runs a real 8-device ``shuffle_step`` over a ``data_mesh``,
+**Probe 1 (land).** A subprocess is given a throwaway cache directory
+through ``JAX_COMPILATION_CACHE_DIR``, runs a real 8-device ``shuffle_step`` over a ``data_mesh``,
 and the parent asserts a ``jit__run_shuffle_step-*-cache`` entry
 appeared — the mesh-path program key landed in progcache.
 
 **Probe 2 (hit).** A SECOND subprocess replays the same program against
 the same directory with actual compilation FORBIDDEN (the
 ``jax._src.compiler`` backend-compile chokepoint monkeypatched to
-raise, the same trick as the bench fence's --device mode). Success proves the
+raise). Success proves the
 persistent entry is keyed reproducibly across processes — a cold worker
 starts hot. The parent also asserts no NEW main-program entry was
 written: a second key for the identical program would mean the cache key
@@ -69,15 +66,19 @@ def _main_entries(cache_dir: str) -> list:
 
 def probe(cache_dir: str, forbid_compile: bool) -> int:
     """Child-process body: run one real in-program exchange with
-    progcache installed at ``cache_dir``. With ``forbid_compile`` the
-    executable MUST come from the persistent cache."""
+    progcache installed over ``cache_dir`` (which the parent named in
+    ``JAX_COMPILATION_CACHE_DIR``: the package sets no directory in
+    code then). With ``forbid_compile`` the executable MUST come from
+    the persistent cache."""
     from spark_rapids_tpu.utils import progcache
 
     import jax
 
-    if not progcache.install(cache_dir):
-        print("probe: progcache.install() refused the directory",
-              file=sys.stderr)
+    if not progcache.install() or \
+            os.path.abspath(progcache.installed_dir()) != \
+            os.path.abspath(cache_dir):
+        print("probe: progcache is not serving the directory the "
+              "environment names", file=sys.stderr)
         return 2
 
     import numpy as np
@@ -125,8 +126,6 @@ def probe(cache_dir: str, forbid_compile: bool) -> int:
         print(f"probe: exchange lost rows ({total} != {N_ROWS})",
               file=sys.stderr)
         return 2
-    # the parent reads the platform-suffixed directory from here rather
-    # than re-deriving the suffix (one definition: progcache's)
     print(f"probe-ok dir={progcache.installed_dir()}")
     return 0
 
@@ -136,7 +135,9 @@ def _run_probe(base_dir: str, forbid: bool):
            "--_probe", base_dir]
     if forbid:
         cmd.append("--_forbid-compile")
-    r = subprocess.run(cmd, env=_probe_env(), cwd=REPO,
+    env = _probe_env()
+    env["JAX_COMPILATION_CACHE_DIR"] = base_dir
+    r = subprocess.run(cmd, env=env, cwd=REPO,
                        capture_output=True, text=True, timeout=600)
     installed = None
     for line in r.stdout.splitlines():

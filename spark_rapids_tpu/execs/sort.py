@@ -76,7 +76,7 @@ class SortExec(TpuExec):
             # single-batch fast path sorts without any host sync at
             # all, and the multi-batch path realizes every count in the
             # one batched get concat already pays — the per-batch
-            # realize here used to cost one ~105 ms round trip each
+            # realize here used to cost one host sync each
             caps = 0
             staged: List[SpillableBatch] = []
             for b in self.children[0].execute(partition):

@@ -84,15 +84,14 @@ def cache_token() -> tuple:
 def interpret_mode() -> bool:
     """True when kernels must run through the Pallas interpreter: any
     backend that is not a real TPU (CPU CI, GPU). The decision is made
-    once per process — backends don't change under a running query."""
+    once per process — backends don't change under a running query. A
+    backend that cannot be asked raises: "no answer" is not "interpret"
+    (a chip that failed to attach must not pass for the CPU)."""
     global _interpret
     if _interpret is None:
-        try:
-            import jax
+        import jax
 
-            _interpret = jax.default_backend() != "tpu"
-        except Exception:  # pragma: no cover - no backend at all
-            _interpret = True
+        _interpret = jax.default_backend() != "tpu"
     return _interpret
 
 

@@ -238,9 +238,8 @@ def force_cpu_mesh(n_devices: int) -> None:
     import os
 
     # set the flag BEFORE the first backend touch: XLA parses XLA_FLAGS
-    # once at client creation, and late-0.4.x jax cannot grow the CPU
-    # device count after that (clear_backends no longer re-reads it).
-    # Harmless on real accelerators — it only sizes the host platform.
+    # once at client creation. Harmless on real accelerators — it only
+    # sizes the host platform.
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -252,12 +251,7 @@ def force_cpu_mesh(n_devices: int) -> None:
     from spark_rapids_tpu.shims import get_shims
 
     get_shims().clear_backends()
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except AttributeError:
-        # pre-0.5 jax has no jax_num_cpu_devices knob; the XLA_FLAGS
-        # device-count flag set above does the job on backend rebuild
-        pass
+    jax.config.update("jax_num_cpu_devices", n_devices)
     assert len(jax.devices()) >= n_devices, (
         f"need {n_devices} devices, have {jax.devices()} — this jax "
         f"cannot resize an initialized backend; set "

@@ -1213,20 +1213,6 @@ class PlanOnCpuError(AssertionError):
 def apply_overrides(plan: pn.PlanNode,
                     conf: Optional[RapidsConf] = None) -> TpuExec:
     conf = conf or RapidsConf()
-    if conf.get(cfg.COMPILE_CACHE_DIR):
-        # before any trace of this query: compiled executables then
-        # land in (and come from) the persistent cache
-        from spark_rapids_tpu.utils import progcache
-
-        if not progcache.install(conf.get(cfg.COMPILE_CACHE_DIR)):
-            import warnings
-
-            warnings.warn(
-                f"rapids.tpu.sql.compileCacheDir="
-                f"{conf.get(cfg.COMPILE_CACHE_DIR)!r} ignored: a "
-                f"different persistent cache "
-                f"({progcache.installed_dir()!r}) is already active "
-                f"in this process (jax holds one global cache)")
     if conf.get(cfg.UDF_COMPILER_ENABLED):
         from spark_rapids_tpu.udf import compile_udfs_in_plan
 

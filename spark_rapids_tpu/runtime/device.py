@@ -35,16 +35,18 @@ class TpuDeviceManager:
         return self._device
 
     def hbm_bytes(self) -> Optional[int]:
-        """Total device memory (Cuda.memGetInfo analogue). None when the
-        backend doesn't report it (CPU host platform)."""
-        try:
-            stats = self.device.memory_stats()
-        except Exception:
-            return None
-        if not stats:
-            return None
-        return stats.get("bytes_limit") or stats.get(
+        """Total device memory (Cuda.memGetInfo analogue). None on the
+        CPU host platform, which reports none; an accelerator that
+        reports none raises — a silent None there would turn the HBM
+        budget off on the very device it exists for."""
+        stats = self.device.memory_stats()
+        total = (stats or {}).get("bytes_limit") or (stats or {}).get(
             "bytes_reservable_limit")
+        if total is None and self.device.platform != "cpu":
+            raise RuntimeError(
+                f"{self.device} reports no memory limit "
+                f"(memory_stats()={stats!r}): cannot size the HBM budget")
+        return total
 
     def device_budget(self, conf: RapidsConf) -> Optional[int]:
         """allocFraction * hbm - reserve (GpuDeviceManager.scala:159-258

@@ -5,8 +5,8 @@ backpressure working, queue/run-time histograms (now with p50/p95/p99)
 show fairness AND feed the sustained-QPS SLO harness, the
 compile-cache hit rate shows tenants sharing compiled programs — a
 repeated plan shape admitted for tenant B reuses tenant A's XLA
-executables (utils/progcache), which is the dominant cost behind the
-remote-compile tunnel — and the batching block shows the micro-batcher
+executables (utils/progcache) instead of compiling its own — and the
+batching block shows the micro-batcher
 turning that sharing into coalesced physical launches
 (service/batching).
 """

@@ -7,10 +7,13 @@ SparkShimServiceProvider.scala:25), overridable via
 ``spark.rapids.shims-provider-override`` (RapidsConf.scala:707). Our host
 framework is jax, whose public surface also moves between releases
 (``shard_map`` graduated from ``jax.experimental.shard_map`` to
-``jax.shard_map``; backend-reset moved into ``jax.extend``). Same design:
-providers declare the versions they serve, the loader probes the installed
-jax exactly once, and everything version-sensitive in the package goes
-through the resolved ``JaxShims``.
+``jax.shard_map`` and renamed ``check_rep`` to ``check_vma``;
+backend-reset moved into ``jax.extend``). Same design: providers declare
+the versions they serve, the loader probes the installed jax exactly
+once, and everything version-sensitive in the package goes through the
+resolved ``JaxShims``. One provider is registered: the one for the jax
+this package is installed and run with. A provider for a jax nobody
+installs is code nobody runs.
 """
 from __future__ import annotations
 
@@ -73,7 +76,7 @@ class JaxShimServiceProvider:
 
 
 def _kernel_safe_shard_map(sm):
-    """Default ``check_rep=False`` while the native-kernel gate is on:
+    """Default ``check_vma=False`` while the native-kernel gate is on:
     interpret-mode ``pallas_call`` has no shard_map replication rule,
     so a kernel routed inside a mesh device step would fail to trace
     otherwise. Replication checking is a trace-time assertion, not a
@@ -84,18 +87,19 @@ def _kernel_safe_shard_map(sm):
 
     @functools.wraps(sm)
     def wrapped(f, **kw):
-        if "check_rep" not in kw:
+        if "check_vma" not in kw:
             from spark_rapids_tpu.native import kernels as nk
 
             if nk.cache_token()[0]:
-                kw["check_rep"] = False
+                kw["check_vma"] = False
         return sm(f, **kw)
 
     return wrapped
 
 
 class _ModernJaxShims(JaxShims):
-    """jax >= 0.6: public top-level shard_map, jax.extend backend API."""
+    """jax >= 0.7: public top-level shard_map (``check_vma``),
+    jax.extend backend API."""
 
     def shard_map(self):
         from jax import shard_map
@@ -117,43 +121,14 @@ class _ModernJaxShims(JaxShims):
 
 
 class ModernJaxShimProvider(JaxShimServiceProvider):
-    VERSION_RANGE = ("0.6", None)
+    VERSION_RANGE = ("0.7", None)
 
     def build(self) -> JaxShims:
         return _ModernJaxShims()
 
 
-class _LegacyJaxShims(_ModernJaxShims):
-    """jax 0.4.x-0.5.x: shard_map lives in jax.experimental, backend
-    reset is jax.clear_backends."""
-
-    def shard_map(self):
-        from jax.experimental.shard_map import shard_map  # type: ignore
-
-        return _kernel_safe_shard_map(shard_map)
-
-    def clear_backends(self):
-        import jax
-
-        # jax.clear_backends was removed mid-0.4.x (0.4.36); late 0.4.x
-        # already carries the jax.extend.backend API
-        if hasattr(jax, "clear_backends"):
-            jax.clear_backends()  # type: ignore[attr-defined]
-        else:
-            from jax.extend import backend
-
-            backend.clear_backends()
-
-
-class LegacyJaxShimProvider(JaxShimServiceProvider):
-    VERSION_RANGE = ("0.4", "0.6")
-
-    def build(self) -> JaxShims:
-        return _LegacyJaxShims()
-
-
 #: discovery order — the ServiceLoader registry (ShimLoader.scala:26)
-PROVIDERS: List[type] = [ModernJaxShimProvider, LegacyJaxShimProvider]
+PROVIDERS: List[type] = [ModernJaxShimProvider]
 
 OVERRIDE_ENV = "RAPIDS_TPU_SHIMS_PROVIDER_OVERRIDE"
 

@@ -504,7 +504,7 @@ def test_gate_json_output(tmp_path):
 def test_q26_sync_map_exact():
     """tpcxbb q26 sf0.1: the compiled plan's sync map is EXACTLY the
     batched duplicate-flag fetch plus the root result fetch — any third
-    entry is a new ~105 ms round trip the dispatch fence would pay for.
+    entry is a new host sync the dispatch fence would pay for.
     Subprocess for the same reason as the dispatch fence: planning
     imports compute modules, and the shared dataset dir is reused."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")

@@ -102,12 +102,15 @@ def test_aggregate_functions_declare_partial_contract():
 def test_shim_provider_version_probe():
     from spark_rapids_tpu import shims
 
+    import jax
+
+    # one provider is left: the installed jax's (range match, bounds)
+    assert shims.PROVIDERS == [shims.ModernJaxShimProvider]
+    assert shims.ModernJaxShimProvider.matches(jax.__version__)
     assert shims.ModernJaxShimProvider.matches("0.9.0")
     assert shims.ModernJaxShimProvider.matches("1.2.3")
     assert not shims.ModernJaxShimProvider.matches("0.4.30")
-    assert shims.LegacyJaxShimProvider.matches("0.4.30")
-    assert shims.LegacyJaxShimProvider.matches("0.5.1")
-    assert not shims.LegacyJaxShimProvider.matches("0.6.0")
+    assert not shims.ModernJaxShimProvider.matches("0.6.0")
 
 
 def test_shim_loader_resolves_and_caches():
@@ -136,6 +139,6 @@ def test_shim_provider_override(monkeypatch):
 
     monkeypatch.setenv(
         shims.OVERRIDE_ENV,
-        "spark_rapids_tpu.shims.LegacyJaxShimProvider")
+        "spark_rapids_tpu.shims.ModernJaxShimProvider")
     resolved = shims._resolve("0.3.25")  # probe would fail; override wins
-    assert type(resolved).__name__ == "_LegacyJaxShims"
+    assert type(resolved).__name__ == "_ModernJaxShims"

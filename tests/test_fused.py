@@ -348,7 +348,7 @@ def test_dense_probe_multi_key_stays_hash():
 def test_wide_agg_compacts_before_sort_path(monkeypatch):
     """A wide (chunk-forcing) aggregate over a fused filter compacts
     survivors first when the batch is large: the 2^23-capacity chunked
-    groupby shape costs a multi-ten-minute remote compile (q26 @ sf 1).
+    groupby shape costs a multi-ten-minute compile (q26 @ sf 1).
     Forced here via a tiny threshold; results must match fusion-off."""
     from spark_rapids_tpu.execs.aggregate import HashAggregateExec
 

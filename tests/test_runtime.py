@@ -63,3 +63,74 @@ def test_budget_none_without_memory_stats():
 def test_bad_device_ordinal():
     with pytest.raises(RuntimeError, match="out of range"):
         runtime.initialize(RapidsConf(), device_ordinal=512)
+
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform = platform
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("cpu", None, None),
+    ("tpu", {"bytes_limit": 16 << 30}, 16 << 30),
+    ("tpu", {"bytes_reservable_limit": 15 << 30}, 15 << 30),
+])
+def test_hbm_bytes_reads_the_device(platform, stats, want):
+    dm = TpuDeviceManager()
+    dm._device = _FakeDevice(platform, stats)
+    assert dm.hbm_bytes() == want
+
+
+@pytest.mark.parametrize("stats", [None, {}])
+def test_hbm_bytes_raises_on_an_accelerator_that_reports_none(stats):
+    """A silent None would turn the HBM budget off on the chip."""
+    dm = TpuDeviceManager()
+    dm._device = _FakeDevice("tpu", stats)
+    with pytest.raises(RuntimeError, match="no memory limit"):
+        dm.hbm_bytes()
+
+
+def test_compile_cache_directory_rule(monkeypatch):
+    """One rule (utils/progcache): the environment's directory is JAX's
+    to read and the package sets none in code; unset, the directory is
+    <checkout>/.jax_cache, a fixed path."""
+    import os
+
+    import jax
+
+    from spark_rapids_tpu.utils import progcache
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setenv(progcache.CACHE_DIR_ENV, "/somewhere/else")
+    progcache.configure()
+    assert "jax_compilation_cache_dir" not in updates
+    monkeypatch.delenv(progcache.CACHE_DIR_ENV)
+    progcache.configure()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert updates["jax_compilation_cache_dir"] == \
+        os.path.join(repo, ".jax_cache")
+
+
+def test_interpret_mode_raises_when_the_backend_cannot_be_asked(
+        monkeypatch):
+    """'No answer' is not 'interpret': a chip that failed to attach must
+    not pass for the CPU."""
+    import jax
+
+    from spark_rapids_tpu.native import kernels as nk
+
+    def boom():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(nk, "_interpret", None)
+    monkeypatch.setattr(jax, "default_backend", boom)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        nk.interpret_mode()
+    monkeypatch.undo()
+    assert nk.interpret_mode() is True  # the CPU suite still interprets
