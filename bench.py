@@ -159,7 +159,6 @@ def bench_full_query(benchmark: str = "tpcxbb_q26", sf: float = 0.1,
                 compare=True)
     wall = res["min_time_sec"]
     dt = res.get("dispatch_telemetry", {})
-    devt = res.get("device_timing", {})
     cmp_ = res.get("compare", {})
     cpu_s = cmp_.get("cpu_time_sec", 0.0)
     mem = res.get("memory", {})
@@ -179,10 +178,6 @@ def bench_full_query(benchmark: str = "tpcxbb_q26", sf: float = 0.1,
         # a regression in fusion shows up as a program-name diff rather
         # than a bare count bump (round-7)
         "per_stage_programs": dt.get("per_stage_programs"),
-        # measured on-device seconds per (stage, program) from the
-        # serialized timing pass — the stage breakdown in TIME, not
-        # just round trips (a stage can be 1 dispatch and 4 seconds)
-        "per_stage_device_s": devt.get("per_stage_programs_device_s"),
         # mesh-requested shuffles that stayed on the host/TCP path,
         # with the spmd gate's reason (empty = all folded in-program)
         "shuffle_fallbacks": dt.get("shuffle_fallbacks"),
@@ -201,7 +196,6 @@ def bench_full_query(benchmark: str = "tpcxbb_q26", sf: float = 0.1,
         "rtt_share": round(
             min(dt.get("est_dispatch_overhead_s", 0.0) / wall, 1.0), 3)
         if wall else None,
-        "on_device_s_measured": devt.get("on_device_s"),
         "cpu_oracle_s": round(cpu_s, 3),
         "vs_cpu_oracle": round(cpu_s / wall, 3) if wall else None,
         "matches_cpu": cmp_.get("matches_cpu"),

@@ -10,6 +10,7 @@ from spark_rapids_tpu.expressions.base import (Alias, BoundReference,
                                                Expression)
 from spark_rapids_tpu.ops.sortkeys import SortKeySpec
 from spark_rapids_tpu.plan import nodes as pn
+from spark_rapids_tpu.utils import tracing
 
 ColumnOrName = Union[Column, str]
 
@@ -321,7 +322,10 @@ class DataFrame:
     def collect(self):
         from spark_rapids_tpu.execs.base import collect
 
-        return collect(self._exec(), conf=self.session.conf)
+        with tracing.QueryRange() as query:
+            out = collect(self._exec(), conf=self.session.conf)
+        self._last_query = query.query_id
+        return out
 
     def collect_async(self, tenant: str = "default", priority: int = 0,
                       deadline=None):
@@ -345,6 +349,15 @@ class DataFrame:
                        "op_time_ms": round(m.op_time_ns / 1e6, 3)}
                 for name, m in exec_.all_metrics().items()}
 
+    def last_profile(self) -> dict:
+        """Where the host time of the most recent collect()/count() went:
+        its span tree from the ``query`` root down (utils/tracing.profile:
+        planning, every exec's batch pulls, launches, waits, the result
+        fetch), each node with its self time. Empty unless
+        ``utils/dispatch.install()`` ran before the engine was imported,
+        and once 64 later queries have pushed it out."""
+        return tracing.profile(getattr(self, "_last_query", None))
+
     to_pandas = collect
     toPandas = collect
 
@@ -356,8 +369,10 @@ class DataFrame:
         from spark_rapids_tpu.execs.base import collect
         from spark_rapids_tpu.plan.overrides import apply_overrides
 
-        df = collect(apply_overrides(plan, self.session.conf),
-                     conf=self.session.conf)
+        with tracing.QueryRange() as query:
+            df = collect(apply_overrides(plan, self.session.conf),
+                         conf=self.session.conf)
+        self._last_query = query.query_id
         return int(df["count"].iloc[0])
 
     def show(self, n: int = 20) -> None:  # pragma: no cover - console

@@ -230,9 +230,13 @@ class Session:
         DataFrame like any other (the whole override/oracle machinery
         downstream is shared). Unsupported SQL raises SqlError."""
         from spark_rapids_tpu.sql import parse, plan_statement
+        from spark_rapids_tpu.utils.tracing import TraceRange
 
-        return DataFrame(plan_statement(parse(query), self._catalog),
-                         self)
+        with TraceRange("sql.parse"):
+            statement = parse(query)
+        with TraceRange("sql.plan"):
+            plan = plan_statement(statement, self._catalog)
+        return DataFrame(plan, self)
 
 
 class DataFrameReader:

@@ -247,47 +247,6 @@ class BenchmarkRunner:
                 "replan_events": disp.replan_delta(run_pre_replan),
                 "compile_cache": progcache.stats(),
             }
-            # MEASURED on-device time (round-5): one extra serialized
-            # pass where every jit call blocks and records its own
-            # device seconds (per-kernel attribution), cross-checkable
-            # against the wall-based estimate above. Task threads MUST
-            # be 1: overlapped partitions would each time the other's
-            # kernels. try/finally so a failing pass can't leave
-            # blocking-mode timing enabled for later measurements.
-            from spark_rapids_tpu import config as cfg
-
-            serial_conf = self.conf.with_overrides(
-                {cfg.TASK_THREADS.key: 1})
-            disp.enable_device_timing()
-            try:
-                plan = plan_fn(self.data_dir)
-                exec_m = apply_overrides(plan, serial_conf)
-                t0 = time.perf_counter()
-                collect(exec_m, conf=serial_conf)
-                wall_m = time.perf_counter() - t0
-            finally:
-                kt = disp.disable_device_timing()
-            per_kernel = {
-                k: {"calls": c, "device_s": round(s, 4)}
-                for k, (c, s) in sorted(
-                    (i for i in kt.items() if i[0] != "__total__"),
-                    key=lambda i: i[1][1], reverse=True)[:12]}
-            # same seconds split per (stage, program): "stage0 spends
-            # 2.1s in chain@a1b2" rather than a global program total
-            per_stage_device = {
-                label: {p: {"calls": c, "device_s": round(s, 4)}
-                        for p, (c, s) in sorted(
-                            progs.items(), key=lambda i: i[1][1],
-                            reverse=True)[:8]}
-                for label, progs in disp.stage_device_times().items()}
-            result["device_timing"] = {
-                "mode": "serialized",
-                "wall_s": round(wall_m, 3),
-                "on_device_s": round(kt["__total__"][1], 4),
-                "timed_jit_calls": kt["__total__"][0],
-                "per_kernel": per_kernel,
-                "per_stage_programs_device_s": per_stage_device,
-            }
         result["query_plan"] = exec_.tree_string()
         result["metrics"] = {
             name: {"rows": m.num_output_rows,

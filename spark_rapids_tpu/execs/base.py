@@ -115,10 +115,23 @@ class TpuExec:
             lines.append(c.tree_string(indent + 1))
         return "\n".join(lines)
 
+    def metric_children(self) -> List["TpuExec"]:
+        """The execs under this one that did its work (a fused exec that
+        fell back answers with the subtree that ran)."""
+        return self.children
+
     def all_metrics(self) -> Dict[str, Metrics]:
-        out = {self.name: self.metrics}
-        for c in self.children:
-            out.update(c.all_metrics())
+        """Every exec's metrics, root first, keyed by class name; the
+        second and later exec of one class get ``#2``, ``#3``, ... (q1's
+        final and partial aggregate are both ``HashAggregateExec``)."""
+        out: Dict[str, Metrics] = {}
+        seen: Dict[str, int] = {}
+        stack = [self]
+        while stack:
+            e = stack.pop()
+            n = seen[e.name] = seen.get(e.name, 0) + 1
+            out[e.name if n == 1 else f"{e.name}#{n}"] = e.metrics
+            stack.extend(reversed(e.metric_children()))
         return out
 
 
@@ -128,27 +141,40 @@ def timed(owner, it: Iterator[ColumnarBatch]
     the TpuExec (self time = pull time minus children's pipeline time); a
     bare Metrics is accepted for exec-less iterators."""
     from spark_rapids_tpu.utils import dispatch as _disp
+    from spark_rapids_tpu.utils import tracing as _tracing
 
     if isinstance(owner, Metrics):
         metrics, children = owner, ()
         stage = None
+        span_name = None
     else:
         metrics, children = owner.metrics, owner.children
         # stage-cutting label (plan/optimizer.cut_stages): dispatches
         # issued while this exec's iterator advances attribute to its
         # pipeline stage in the telemetry
         stage = getattr(owner, "_stage_label", None)
+        # with recording on, every pull that yields a batch is the span
+        # `<ExecName>.next`, from the two clock reads the metrics take
+        span_name = owner.name + ".next" if _tracing.recording() else None
     while True:
         child0 = sum(c.metrics.pipeline_time_ns for c in children)
         t0 = time.perf_counter_ns()
+        span = _tracing.open_span(span_name, t0, annotate=True) \
+            if span_name is not None else None
         tok = _disp.enter_stage(stage)
+        batch = None
         try:
             batch = next(it)
         except StopIteration:
             return
         finally:
             _disp.exit_stage(tok)
+            # no batch (the iterator ended, or raised): no metric, no span
+            if batch is None and span is not None:
+                _tracing.abandon_span(span)
         elapsed = time.perf_counter_ns() - t0
+        if span is not None:
+            _tracing.close_span(span, t0 + elapsed)
         child_ns = sum(c.metrics.pipeline_time_ns
                        for c in children) - child0
         metrics.record(batch, elapsed, child_ns)
@@ -172,40 +198,50 @@ def run_partitions(n_partitions: int, fn, task_threads: int = 4):
                                                  set_buffer_owner)
     from spark_rapids_tpu.service.batching import microbatch as _mb
     from spark_rapids_tpu.utils import dispatch as _disp
+    from spark_rapids_tpu.utils import tracing as _tracing
 
-    # propagate the caller's buffer-owner tag, dispatch query tag and
-    # micro-batching slice context (all thread-local) onto the pool
-    # threads: a query-service slice that fans out here must have every
-    # batch the tasks register and every dispatch they issue attributed
-    # to its query — and its stage programs must stay coalescible — or
-    # cancel/deadline cleanup, stalled-query spill demotion,
-    # ServiceStats per-query dispatch counts and cross-query
-    # micro-batching would all miss pool work
-    owner = current_buffer_owner()
-    qid = _disp.current_query()
-    bctx = _mb.current()
-    run = fn
-    if owner is not None or qid is not None or bctx is not None:
-        def run(p, _fn=fn, _owner=owner, _qid=qid, _bctx=bctx):
-            prev = set_buffer_owner(_owner) if _owner is not None \
-                else None
-            qtok = _disp.enter_query(_qid)
-            btok = None
-            if _bctx is not None:
-                btok = _mb.enter_slice(_bctx.batcher, _bctx.query_id,
-                                       _bctx.multi)
-            try:
-                return _fn(p)
-            finally:
+    # the calling thread's wait on the pool; the tasks' spans hang under it
+    with _tracing.TraceRange("run_partitions.wait"):
+        # propagate the caller's buffer-owner tag, dispatch query tag,
+        # micro-batching slice context and open span (all thread-local)
+        # onto the pool threads: a query-service slice that fans out here
+        # must have every batch the tasks register and every dispatch
+        # they issue attributed to its query — and its stage programs
+        # must stay coalescible — or cancel/deadline cleanup,
+        # stalled-query spill demotion, ServiceStats per-query dispatch
+        # counts, cross-query micro-batching and the query's span tree
+        # would all miss pool work
+        owner = current_buffer_owner()
+        qid = _disp.current_query()
+        bctx = _mb.current()
+        span = _tracing.current()
+        run = fn
+        if owner is not None or qid is not None or bctx is not None \
+                or span is not None:
+            def run(p, _fn=fn, _owner=owner, _qid=qid, _bctx=bctx,
+                    _span=span):
+                prev = set_buffer_owner(_owner) if _owner is not None \
+                    else None
+                qtok = _disp.enter_query(_qid)
+                btok = None
                 if _bctx is not None:
-                    _mb.exit_slice(btok)
-                _disp.exit_query(qtok)
-                if _owner is not None:
-                    set_buffer_owner(prev)
+                    btok = _mb.enter_slice(_bctx.batcher, _bctx.query_id,
+                                           _bctx.multi)
+                prev_span = _tracing.adopt(_span)
+                try:
+                    return _fn(p)
+                finally:
+                    _tracing.adopt(prev_span)
+                    if _bctx is not None:
+                        _mb.exit_slice(btok)
+                    _disp.exit_query(qtok)
+                    if _owner is not None:
+                        set_buffer_owner(prev)
 
-    with ThreadPoolExecutor(max_workers=min(task_threads, n_partitions),
-                            thread_name_prefix="tpu-task") as pool:
-        return list(pool.map(run, range(n_partitions)))
+        with ThreadPoolExecutor(
+                max_workers=min(task_threads, n_partitions),
+                thread_name_prefix="tpu-task") as pool:
+            return list(pool.map(run, range(n_partitions)))
 
 
 def collect(exec_: TpuExec, conf=None):
@@ -218,6 +254,7 @@ def collect(exec_: TpuExec, conf=None):
 
     from spark_rapids_tpu import config as cfg
     from spark_rapids_tpu.utils import dispatch as _disp
+    from spark_rapids_tpu.utils.tracing import TraceRange
 
     threads = (conf.get(cfg.TASK_THREADS) if conf is not None
                else cfg.TASK_THREADS.default)
@@ -233,7 +270,8 @@ def collect(exec_: TpuExec, conf=None):
         for batch in exec_.execute(p):
             tok = _disp.enter_stage("result_sync")
             try:
-                frames.append(batch.to_pandas(exec_.schema))
+                with TraceRange("collect.fetch"):
+                    frames.append(batch.to_pandas(exec_.schema))
             finally:
                 _disp.exit_stage(tok)
         return [f for f in frames if len(f)]
@@ -245,4 +283,5 @@ def collect(exec_: TpuExec, conf=None):
         cols = {n: pd.Series([], dtype=object)
                 for n in exec_.schema.names}
         return pd.DataFrame(cols)
-    return pd.concat(frames, ignore_index=True)
+    with TraceRange("collect.concat"):
+        return pd.concat(frames, ignore_index=True)

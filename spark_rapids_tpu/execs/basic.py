@@ -225,7 +225,8 @@ class ExpandExec(TpuExec):
             for b in self.children[0].execute(partition):
                 parts = [proj(b) for proj in self.projections]
                 with TraceRange("ExpandExec.interleave"):
-                    yield interleave_batches(parts)
+                    out = interleave_batches(parts)
+                yield out
         return timed(self, it())
 
 

@@ -9,6 +9,7 @@ identical machinery to shuffle blocks and broadcast tables."""
 from __future__ import annotations
 
 import threading
+from contextlib import ExitStack
 from spark_rapids_tpu.utils import lockorder
 from typing import Dict, Iterator, List, Optional
 
@@ -17,6 +18,7 @@ from spark_rapids_tpu.execs.base import TpuExec, timed
 from spark_rapids_tpu.memory import priorities
 from spark_rapids_tpu.memory.spillable import SpillableBatch
 from spark_rapids_tpu.plan.nodes import PlanNode
+from spark_rapids_tpu.utils.tracing import TraceRange
 
 
 class CacheNode(PlanNode):
@@ -99,6 +101,8 @@ class CachedExec(TpuExec):
                 yield ColumnarBatch.empty(self.schema)
                 return
             for h in handles:
-                with h.acquired() as batch:
+                with ExitStack() as held:
+                    with TraceRange("CachedExec.acquire"):
+                        batch = held.enter_context(h.acquired())
                     yield batch
         return timed(self, it())

@@ -431,13 +431,18 @@ class ShuffleExchangeExec(TpuExec):
             else {p: [] for p in range(self.num_out_partitions)}
         for b in source:
             with TraceRange("ShuffleExchangeExec.partition"):
-                sorted_b, counts = self._partition_batch(b)
-                subs = part_ops.slice_partitions(sorted_b, counts)
-            for p, sub in enumerate(subs):
-                if sub is None:
-                    continue
-                blocks[p].append(SpillableBatch(
-                    sub, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY))
+                with TraceRange("ShuffleExchangeExec.partitionKernel"):
+                    sorted_b, counts = self._partition_batch(b)
+                # the eager dynamic_slice/concatenate/broadcast_in_dim
+                # launches of an exchange come from here
+                with TraceRange("ShuffleExchangeExec.slice"):
+                    subs = part_ops.slice_partitions(sorted_b, counts)
+                with TraceRange("ShuffleExchangeExec.register"):
+                    for p, sub in enumerate(subs):
+                        if sub is None:
+                            continue
+                        blocks[p].append(SpillableBatch(
+                            sub, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY))
         return blocks
 
     def map_output_sizes(self) -> List[int]:
@@ -450,7 +455,10 @@ class ShuffleExchangeExec(TpuExec):
     def _input_batches(self):
         for in_p in range(self.children[0].num_partitions):
             for b in self.children[0].execute(in_p):
-                if b.realized_num_rows() == 0:
+                # a host sync a batch: the count decides whether it is sent
+                with TraceRange("ShuffleExchangeExec.inputRows"):
+                    n = b.realized_num_rows()
+                if n == 0:
                     continue
                 yield b
 

@@ -1380,8 +1380,8 @@ class FusedChainExec(TpuExec):
         return _fused_tree_string(self, indent,
                                   f"[{len(self.chain.steps)} fused steps]")
 
-    def all_metrics(self):
-        return _fused_all_metrics(self)
+    def metric_children(self):
+        return _fused_metric_children(self)
 
 
 def _fused_tree_string(exec_, indent: int, note: str) -> str:
@@ -1400,14 +1400,12 @@ def _fused_tree_string(exec_, indent: int, note: str) -> str:
     return "\n".join(lines)
 
 
-def _fused_all_metrics(exec_):
-    out = {exec_.name: exec_.metrics}
+def _fused_metric_children(exec_):
+    """What ``all_metrics`` walks under a fused exec: the preserved
+    unfused subtree when the duplicate-build fallback did the work."""
     if exec_._preps_ok is False:
-        out.update(exec_.fallback.all_metrics())
-    else:
-        for c in exec_.children:
-            out.update(c.all_metrics())
-    return out
+        return [exec_.fallback]
+    return exec_.children
 
 
 class _InlineDupFallback(Exception):
@@ -1510,8 +1508,8 @@ class FusedAggregateExec(agg_exec.HashAggregateExec):
             self, indent,
             f"[{len(self.chain.steps)} fused steps, {self.mode}]")
 
-    def all_metrics(self):
-        return _fused_all_metrics(self)
+    def metric_children(self):
+        return _fused_metric_children(self)
 
 
 # ---------------------------------------------------------------------------

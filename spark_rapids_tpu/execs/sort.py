@@ -61,7 +61,8 @@ class SortExec(TpuExec):
             if not self.global_sort:
                 for b in self.children[0].execute(partition):
                     with TraceRange("SortExec.local"):
-                        yield sort_batch(b, self.specs, types)
+                        out = sort_batch(b, self.specs, types)
+                    yield out
                 return
             from spark_rapids_tpu.memory import priorities
             from spark_rapids_tpu.memory.retry import with_retry_no_split

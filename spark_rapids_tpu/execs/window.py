@@ -381,7 +381,8 @@ class WindowExec(TpuExec):
                 return
             b = self._concat_staged(staged)
             with TraceRange("WindowExec"):
-                yield self._run(b)
+                out = self._run(b)
+            yield out
         return timed(self, it())
 
     @staticmethod

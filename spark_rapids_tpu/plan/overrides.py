@@ -33,6 +33,7 @@ from spark_rapids_tpu.expressions import arithmetic, bitwise, cast, \
 from spark_rapids_tpu.expressions.base import (Alias, BoundReference,
                                                Expression, Literal)
 from spark_rapids_tpu.plan import nodes as pn
+from spark_rapids_tpu.utils.tracing import TraceRange
 
 
 def _session_mesh(conf):
@@ -1212,51 +1213,59 @@ class PlanOnCpuError(AssertionError):
 
 def apply_overrides(plan: pn.PlanNode,
                     conf: Optional[RapidsConf] = None) -> TpuExec:
+    """Logical plan to physical exec tree, under the span
+    ``plan.physical`` and its four children."""
     conf = conf or RapidsConf()
-    if conf.get(cfg.UDF_COMPILER_ENABLED):
-        from spark_rapids_tpu.udf import compile_udfs_in_plan
+    with TraceRange("plan.physical"):
+        with TraceRange("plan.optimize"):
+            if conf.get(cfg.UDF_COMPILER_ENABLED):
+                from spark_rapids_tpu.udf import compile_udfs_in_plan
 
-        plan = compile_udfs_in_plan(plan)
-    if conf.get(cfg.OPTIMIZER_ENABLED):
-        from spark_rapids_tpu.plan.optimizer import optimize
+                plan = compile_udfs_in_plan(plan)
+            if conf.get(cfg.OPTIMIZER_ENABLED):
+                from spark_rapids_tpu.plan.optimizer import optimize
 
-        plan = optimize(plan)
-    plan = push_down_file_filters(plan, conf)
-    pn.gate_split_packing(plan)
-    meta = NodeMeta(plan, conf)
-    meta.tag_for_tpu()
-    explain_mode = conf.get(cfg.EXPLAIN).upper()
-    if explain_mode in ("ALL", "NOT_ON_TPU"):
-        print(meta.explain(only_not_on_tpu=explain_mode == "NOT_ON_TPU"))
-    # plan-time partition-count queries must see STATIC shuffle counts:
-    # without this, a rule asking an adaptive reader for num_partitions
-    # materializes (executes!) the whole map stage mid-planning, before
-    # fusion/coalesce have rewritten the subtree
-    with adaptive_exec.planning_mode():
-        exec_ = meta.convert()
-        if conf.get(cfg.FUSION_ENABLED):
-            from spark_rapids_tpu.execs.fused import fuse_pipelines
+                plan = optimize(plan)
+            plan = push_down_file_filters(plan, conf)
+            pn.gate_split_packing(plan)
+        with TraceRange("plan.tag"):
+            meta = NodeMeta(plan, conf)
+            meta.tag_for_tpu()
+            explain_mode = conf.get(cfg.EXPLAIN).upper()
+            if explain_mode in ("ALL", "NOT_ON_TPU"):
+                print(meta.explain(
+                    only_not_on_tpu=explain_mode == "NOT_ON_TPU"))
+        # plan-time partition-count queries must see STATIC shuffle
+        # counts: without this, a rule asking an adaptive reader for
+        # num_partitions materializes (executes!) the whole map stage
+        # mid-planning, before fusion/coalesce have rewritten the subtree
+        with TraceRange("plan.convert"), adaptive_exec.planning_mode():
+            exec_ = meta.convert()
+            if conf.get(cfg.FUSION_ENABLED):
+                from spark_rapids_tpu.execs.fused import fuse_pipelines
 
-            exec_ = fuse_pipelines(exec_, conf)
-        exec_ = insert_coalesce(exec_)
-    if _cluster_mode(conf):
-        from spark_rapids_tpu.runtime.cluster import (
-            install_cluster_exchanges, session_cluster)
+                exec_ = fuse_pipelines(exec_, conf)
+            exec_ = insert_coalesce(exec_)
+        with TraceRange("plan.stages"):
+            if _cluster_mode(conf):
+                from spark_rapids_tpu.runtime.cluster import (
+                    install_cluster_exchanges, session_cluster)
 
-        runtime = session_cluster(conf)
-        if runtime is not None:
-            exec_ = install_cluster_exchanges(exec_, runtime)
-    _enable_in_program_exchanges(exec_, conf)
-    if conf.get(cfg.TEST_ENABLED):
-        allowed = {s.strip() for s in
-                   conf.get(cfg.TEST_ALLOWED_NON_TPU).split(",")
-                   if s.strip()}
-        _assert_on_tpu(exec_, allowed)
-    # label every exec with its pipeline stage so dispatch telemetry
-    # (and bench output) attributes round trips per stage
-    from spark_rapids_tpu.plan.optimizer import cut_stages
+                runtime = session_cluster(conf)
+                if runtime is not None:
+                    exec_ = install_cluster_exchanges(exec_, runtime)
+            _enable_in_program_exchanges(exec_, conf)
+            if conf.get(cfg.TEST_ENABLED):
+                allowed = {s.strip() for s in
+                           conf.get(cfg.TEST_ALLOWED_NON_TPU).split(",")
+                           if s.strip()}
+                _assert_on_tpu(exec_, allowed)
+            # label every exec with its pipeline stage so dispatch
+            # telemetry (and bench output) attributes round trips per
+            # stage
+            from spark_rapids_tpu.plan.optimizer import cut_stages
 
-    cut_stages(exec_)
+            cut_stages(exec_)
     return exec_
 
 

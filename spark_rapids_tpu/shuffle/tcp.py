@@ -192,6 +192,13 @@ class TcpShuffleServer:
 
     def close(self):
         self._closed = True
+        # close() alone leaves a thread blocked in accept() holding the
+        # kernel's socket open: a peer would still connect, and wait out
+        # its whole request timeout for an answer nobody sends
+        try:
+            self._listener.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._listener.close()
         except OSError:

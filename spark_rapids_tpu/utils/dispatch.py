@@ -14,16 +14,24 @@ sources:
   between jitted kernels — each one is its own tiny executable),
 - explicit device->host transfers (``jax.device_get``).
 
+Each of the three also times the host seconds spent inside the call and
+hands them to ``utils/tracing`` as a launch timer (``launch.jit``,
+``launch.eager``, ``launch.device_get``): ``install()`` is also the one
+switch that turns span recording on, and ``snapshot()``/``delta()`` carry
+the span table beside the counts.
+
 ``install()`` must run before importing any ``spark_rapids_tpu``
-compute module; the benchmark runner does this when
-``--dispatch-telemetry`` is passed. Zero overhead when not installed.
+compute module (``import jax`` may come first or after); the benchmark
+runner does this when ``--dispatch-telemetry`` is passed. Zero overhead
+when not installed.
 """
 from __future__ import annotations
 
 import functools
 import threading
-from spark_rapids_tpu.utils import lockorder
 import time
+
+from spark_rapids_tpu.utils import lockorder, tracing
 
 _installed = False
 _jit_calls = 0
@@ -47,6 +55,13 @@ _stage_counts: dict = {}
 # "eager:<prim>", transfers as "device_get".
 _stage_programs: dict = {}
 _stage_lock = lockorder.make_lock("utils.dispatch.stage")
+# A query launches hundreds to thousands of times from four task threads,
+# so a launch inside a stage takes no lock: it counts into the thread's
+# own ``_tls.pending`` ({(kind, program): n}), which reaches the shared
+# tables under ONE lock when the stage is left, and before any of the
+# thread's tags (stage, query, coalesced) changes, so every launch lands
+# under the tags it ran with. ``_tls.pending`` is a dict exactly while a
+# stage is set.
 
 
 def enter_stage(label):
@@ -55,13 +70,20 @@ def enter_stage(label):
     if not _installed or label is None:
         return None
     prev = getattr(_tls, "stage", None)
+    if prev is None:
+        _tls.pending = {}
+    else:
+        _flush()
     _tls.stage = label
     return (prev,)
 
 
 def exit_stage(token) -> None:
     if token is not None:
+        _flush()
         _tls.stage = token[0]
+        if token[0] is None:
+            _tls.pending = None
 
 
 # -- per-query attribution --------------------------------------------------
@@ -85,6 +107,7 @@ def enter_query(query_id):
     for exit_query. No-op (None token) when telemetry isn't installed."""
     if not _installed or query_id is None:
         return None
+    _flush()
     prev = getattr(_tls, "query", None)
     _tls.query = query_id
     return (prev,)
@@ -92,6 +115,7 @@ def enter_query(query_id):
 
 def exit_query(token) -> None:
     if token is not None:
+        _flush()
         _tls.query = token[0]
 
 
@@ -111,6 +135,7 @@ def enter_coalesced(query_ids):
     exit_coalesced; no-op (None) when telemetry isn't installed."""
     if not _installed or not query_ids:
         return None
+    _flush()
     prev = getattr(_tls, "coalesced", None)
     _tls.coalesced = tuple(query_ids)
     return (prev,)
@@ -118,6 +143,7 @@ def enter_coalesced(query_ids):
 
 def exit_coalesced(token) -> None:
     if token is not None:
+        _flush()
         _tls.coalesced = token[0]
 
 
@@ -158,43 +184,46 @@ def pop_query_coalesced(query_id) -> int:
 
 
 def _bump_stage(kind: str, program: str = None) -> None:
+    key = (kind, program)
+    pending = getattr(_tls, "pending", None)
+    if pending is None:
+        _count({key: 1})    # outside any stage: rare
+    else:
+        pending[key] = pending.get(key, 0) + 1
+
+
+def _flush() -> None:
+    pending = getattr(_tls, "pending", None)
+    if pending:
+        _count(pending)
+        pending.clear()
+
+
+def _count(launches: dict) -> None:
+    """``{(kind, program): n}`` launches of this thread, under its tags."""
     global _tagged_total
     label = getattr(_tls, "stage", None) or "<unstaged>"
     qid = getattr(_tls, "query", None)
     group = getattr(_tls, "coalesced", None)
+    total = sum(launches.values())
     with _stage_lock:
         d = _stage_counts.get(label)
         if d is None:
             d = _stage_counts[label] = {"jit": 0, "eager": 0, "get": 0}
-        d[kind] += 1
-        if program is not None:
-            progs = _stage_programs.setdefault(label, {})
-            progs[program] = progs.get(program, 0) + 1
+        for (kind, program), n in launches.items():
+            d[kind] += n
+            if program is not None:
+                progs = _stage_programs.setdefault(label, {})
+                progs[program] = progs.get(program, 0) + n
         if group:
-            share = 1.0 / len(group)
+            share = total / len(group)
             for g in group:
                 _query_counts[g] = _query_counts.get(g, 0) + share
-                _query_coalesced[g] = _query_coalesced.get(g, 0) + 1
-            _tagged_total += 1
+                _query_coalesced[g] = _query_coalesced.get(g, 0) + total
+            _tagged_total += total
         elif qid is not None:
-            _query_counts[qid] = _query_counts.get(qid, 0) + 1
-            _tagged_total += 1
-
-# -- measured device timing (serialized mode) -------------------------------
-# When enabled, every counted jit call BLOCKS until its result is ready
-# and records (elapsed - RTT floor) as that kernel's measured device
-# time, attributed per function name. This measures rather than infers
-# on-device time. Serializing kills dispatch pipelining, so wall clock
-# inflates — run it as a separate measurement pass, never during the
-# timed iterations. The runner cross-checks the sum against the
-# wall-based estimate and reports both.
-_device_timing = False
-_rtt_floor = 0.0
-_kernel_times: dict = {}
-# per-(stage, program) split of the same measured seconds: answers
-# "which stage's launches of chain@a1b2 are the expensive ones" when
-# one compiled program serves several pipeline stages
-_stage_kernel_times: dict = {}
+            _query_counts[qid] = _query_counts.get(qid, 0) + total
+            _tagged_total += total
 
 
 def install() -> None:
@@ -204,6 +233,25 @@ def install() -> None:
     if _installed:
         return
     import jax
+    from jax._src import dispatch as jdispatch
+
+    # eager primitives: every primitive bound its impl to the ORIGINAL
+    # apply_primitive (a partial) when jax was imported, so replacing
+    # that attribute is never seen. What apply_primitive looks up at call
+    # time is the module global xla_primitive_callable, which hands back
+    # the primitive's jitted callable: that is wrapped below. A JAX that
+    # moved either is an error here, before anything is replaced, and not
+    # a counter that reads 0.
+    real_callable = getattr(jdispatch, "xla_primitive_callable", None)
+    apply_code = getattr(getattr(jdispatch, "apply_primitive", None),
+                         "__code__", None)
+    if real_callable is None or apply_code is None \
+            or "xla_primitive_callable" not in apply_code.co_names:
+        raise RuntimeError(
+            f"utils/dispatch.install(): jax {jax.__version__} does not "
+            f"apply eager primitives through jax._src.dispatch."
+            f"apply_primitive -> xla_primitive_callable; the eager-launch "
+            f"counter has nothing to hook")
 
     real_jit = jax.jit
 
@@ -221,20 +269,11 @@ def install() -> None:
                 global _jit_calls
                 _jit_calls += 1
                 _bump_stage("jit", name)
-                if not _device_timing:
+                t0 = time.perf_counter_ns()
+                try:
                     return compiled(*a, **k)
-                t0 = time.perf_counter()
-                out = compiled(*a, **k)
-                jax.block_until_ready(out)
-                dt = max(time.perf_counter() - t0 - _rtt_floor, 0.0)
-                calls, secs = _kernel_times.get(name, (0, 0.0))
-                _kernel_times[name] = (calls + 1, secs + dt)
-                label = getattr(_tls, "stage", None) or "<unstaged>"
-                with _stage_lock:
-                    progs = _stage_kernel_times.setdefault(label, {})
-                    c2, s2 = progs.get(name, (0, 0.0))
-                    progs[name] = (c2 + 1, s2 + dt)
-                return out
+                finally:
+                    tracing.leaf("launch.jit", t0, time.perf_counter_ns())
 
             def __getattr__(self, name_):
                 return getattr(compiled, name_)
@@ -248,20 +287,25 @@ def install() -> None:
 
     jax.jit = counting_jit
 
-    try:
-        from jax._src import dispatch as jdispatch
+    def counting_callable(prim, **params):
+        fun = real_callable(prim, **params)
+        label = "eager:" + getattr(prim, "name", "?")
 
-        real_apply = jdispatch.apply_primitive
-
-        def counting_apply(prim, *a, **k):
+        def launch(*args):
             global _eager_calls
             _eager_calls += 1
-            _bump_stage("eager", "eager:" + getattr(prim, "name", "?"))
-            return real_apply(prim, *a, **k)
+            _bump_stage("eager", label)
+            t0 = time.perf_counter_ns()
+            try:
+                return fun(*args)
+            finally:
+                tracing.leaf("launch.eager", t0, time.perf_counter_ns())
 
-        jdispatch.apply_primitive = counting_apply
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
+        return launch
+
+    # keeps the cache's cache_clear/cache_info, which jax's test_util calls
+    functools.update_wrapper(counting_callable, real_callable)
+    jdispatch.xla_primitive_callable = counting_callable
 
     real_get = jax.device_get
 
@@ -269,9 +313,14 @@ def install() -> None:
         global _transfers
         _transfers += 1
         _bump_stage("get", "device_get")
-        return real_get(x)
+        t0 = time.perf_counter_ns()
+        try:
+            return real_get(x)
+        finally:
+            tracing.leaf("launch.device_get", t0, time.perf_counter_ns())
 
     jax.device_get = counting_get
+    tracing.start_recording()
     _installed = True
 
 
@@ -279,15 +328,25 @@ def installed() -> bool:
     return _installed
 
 
+_LAUNCH_KEYS = ("jit_calls", "eager_op_calls", "transfers")
+
+
 def snapshot() -> dict:
     return {"jit_calls": _jit_calls, "eager_op_calls": _eager_calls,
-            "transfers": _transfers}
+            "transfers": _transfers, "spans": tracing.table(),
+            "queries": tracing.queries()}
 
 
 def delta(before: dict) -> dict:
+    """Since ``before`` (a ``snapshot()``): the three launch counts and
+    their sum ``dispatch_count``, the span table's rows that moved
+    (``spans``: ``{name: {"count", "total_s", "self_s"}}``) and the
+    ``query`` roots closed (``queries``)."""
     now = snapshot()
-    d = {k: now[k] - before[k] for k in now}
+    d = {k: now[k] - before[k] for k in _LAUNCH_KEYS}
     d["dispatch_count"] = sum(d.values())
+    d["spans"] = tracing.table_delta(before["spans"])
+    d["queries"] = now["queries"] - before["queries"]
     return d
 
 
@@ -376,40 +435,6 @@ def executable_count() -> int:
         except Exception:
             total += 1
     return total
-
-
-def enable_device_timing() -> None:
-    """Start serialized per-kernel device-time measurement (requires
-    install()). Measures the RTT floor once so each sample subtracts
-    the fixed dispatch overhead."""
-    global _device_timing, _rtt_floor, _kernel_times
-    assert _installed, "dispatch.install() must run first"
-    _rtt_floor = measure_rtt()
-    _kernel_times = {}
-    with _stage_lock:
-        _stage_kernel_times.clear()
-    _device_timing = True
-
-
-def disable_device_timing() -> dict:
-    """Stop measuring; returns {kernel_name: (calls, device_seconds)}
-    plus the totals under the '__total__' key."""
-    global _device_timing
-    _device_timing = False
-    out = dict(_kernel_times)
-    total_calls = sum(c for c, _ in out.values())
-    total_s = sum(s for _, s in out.values())
-    out["__total__"] = (total_calls, total_s)
-    return out
-
-
-def stage_device_times() -> dict:
-    """Measured device seconds split per (stage, program):
-    {stage: {program: (calls, device_seconds)}}. Populated only while
-    device timing is enabled; read it AFTER disable_device_timing."""
-    with _stage_lock:
-        return {label: dict(progs)
-                for label, progs in _stage_kernel_times.items()}
 
 
 def measure_rtt(samples: int = 5) -> float:

@@ -143,6 +143,9 @@ LOCK_HIERARCHY: Dict[str, int] = {
     "native.init": 184,
     "shims.init": 188,
     "config.registry": 192,
+    # span table of utils/tracing: taken as a span closes, which may be
+    # under any other lock, so it is the innermost of all
+    "utils.tracing.table": 196,
 }
 
 #: Per-instance locks whose DISTINCT instances may nest (same name at

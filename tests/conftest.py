@@ -20,10 +20,18 @@ if "xla_force_host_platform_device_count" not in flags:
 # pytest_sessionfinish below fails the run if any were observed.
 os.environ.setdefault("RAPIDS_TPU_DEBUG_LOCKORDER_ENABLED", "1")
 
+# XLA's C++ log writes straight to fd 2. Loading a CPU executable from
+# the compile cache (below) logs two ERROR lines each time, about
+# `+prefer-no-gather/-scatter`, tuning hints XLA adds itself and then
+# misses among the host's features; a line that a background thread
+# writes between two tests would land inside pytest's line of dots. A
+# real XLA error still reaches the test as a Python exception.
+os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
+
 import pytest  # noqa: E402
 
 import spark_rapids_tpu  # noqa: E402,F401  (enables x64 before jax use)
-from spark_rapids_tpu.utils import lockorder  # noqa: E402
+from spark_rapids_tpu.utils import lockorder, progcache  # noqa: E402
 
 import jax  # noqa: E402
 
@@ -31,6 +39,14 @@ assert jax.devices()[0].platform == "cpu", \
     "tests must run on the virtual CPU mesh, not the real TPU"
 assert len(jax.devices()) >= 8, \
     "xla_force_host_platform_device_count=8 did not take effect"
+
+# The suite's time is XLA's: thousands of small x64 CPU programs, most of
+# them compiled again by every run and, where a file sheds jax's caches
+# between tests (test_benchmarks.py), again inside one run. Keep every
+# executable in the compile cache (utils/progcache's directory rule
+# decides where), not only those that took over two seconds: a program
+# seen before is then loaded, which costs its lowering and a read.
+progcache.install()
 
 
 @pytest.fixture(scope="session")

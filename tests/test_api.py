@@ -240,6 +240,21 @@ def test_last_metrics_after_collect(df):
     assert m[agg_key]["rows"] > 0
 
 
+def test_last_metrics_two_execs_of_one_class(pdf):
+    """q1's shape without fusion: the partial and the final aggregate are
+    both HashAggregateExec; the second gets a suffix, neither is lost."""
+    s = Session({"rapids.tpu.sql.fusion.enabled": False})
+    pipe = (s.create_dataframe(pdf).repartition(3).group_by("k")
+             .agg(F.sum(col("v")).alias("sv")))
+    assert len(pipe.collect()) == pdf["k"].nunique()
+    m = pipe.last_metrics()
+    final, partial = m["HashAggregateExec"], m["HashAggregateExec#2"]
+    assert final["rows"] == pdf["k"].nunique()
+    assert partial["rows"] >= final["rows"] and partial["batches"] >= 1
+    assert "ShuffleExchangeExec#2" in m
+    assert len(m) == pipe._last_exec.tree_string().count("\n") + 1
+
+
 def test_na_functions(session):
     pdf2 = pd.DataFrame({"a": [1.0, None, 3.0, None],
                          "s": ["x", None, "z", "w"],

@@ -33,8 +33,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # whole-plan coalescing, 16 before that): stage0 = build-inlined chain
 # + groupby + sort-tail chain, stage3 = 1 chain, result_sync = 1 fetch.
 # See docs/tuning-guide.md "Dispatch cost model & stage fusion" for the
-# stage-by-stage budget.
+# stage-by-stage budget. The ceiling holds compiled programs and
+# transfers; the eager launches have a ceiling of their own.
 Q26_DISPATCH_BUDGET = 5
+# eager primitives of the same query: three convert_element_type, two in
+# stage0 and one in stage3 (each its own tiny executable and launch)
+Q26_EAGER_BUDGET = 3
 
 _FENCE_SCRIPT = r"""
 import json, os, sys
@@ -73,7 +77,8 @@ print(json.dumps({
 
 
 def test_q26_full_query_dispatch_budget(tmp_path):
-    """tpcxbb q26 sf0.1, warm, end to end: dispatch_count <= 5 AND the
+    """tpcxbb q26 sf0.1, warm, end to end: at most 5 compiled programs
+    and transfers and 3 eager primitives AND the
     result still matches the CPU oracle (a budget met by breaking the
     query would be worthless). Every dispatch must also carry a stage
     label — the old stray ``<unstaged>`` device_get is now part of the
@@ -90,11 +95,19 @@ def test_q26_full_query_dispatch_budget(tmp_path):
     assert out.returncode == 0, out.stderr[-3000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["matches_cpu"], rec["mismatch"]
-    assert rec["dispatch_count"] <= Q26_DISPATCH_BUDGET, (
-        f"dispatch_count {rec['dispatch_count']} exceeds the "
-        f"{Q26_DISPATCH_BUDGET}-dispatch fence; per-source "
-        f"{rec['detail']}, per-stage {rec['per_stage']} — a new host "
+    d = rec["detail"]
+    sources = {k: d[k] for k in ("jit_calls", "eager_op_calls",
+                                 "transfers")}
+    assert rec["dispatch_count"] == sum(sources.values())
+    assert d["jit_calls"] + d["transfers"] <= Q26_DISPATCH_BUDGET, (
+        f"{d['jit_calls']} programs + {d['transfers']} transfers exceed "
+        f"the {Q26_DISPATCH_BUDGET}-dispatch fence; per-source "
+        f"{sources}, per-stage {rec['per_stage']} — a new host "
         f"sync or un-fused launch crept into the pipeline")
+    assert d["eager_op_calls"] <= Q26_EAGER_BUDGET, (
+        f"{d['eager_op_calls']} eager primitives exceed the "
+        f"{Q26_EAGER_BUDGET} the query is known to launch; per-stage "
+        f"{rec['per_stage']} — op-by-op glue crept in between programs")
     # attribution fence: every warm dispatch belongs to a pipeline
     # stage or the documented end-of-query result_sync fetch; an
     # <unstaged> bucket means an unattributed host sync came back

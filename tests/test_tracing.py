@@ -1,0 +1,362 @@
+"""Spans and launch timers (utils/tracing, utils/dispatch).
+
+Recording is on exactly when ``dispatch.install()`` has run, and that must
+precede the engine's imports, so everything "on" runs in ONE subprocess
+(as tests/test_dispatch_budget.py does) that prints a record a check; the
+tests below each assert on their part of it. The pytest process itself
+never installs: it is the "off" case.
+"""
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pandas as pd
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_ON_SCRIPT = r"""
+import json, sys, threading
+sys.path.insert(0, __ROOT__)
+from spark_rapids_tpu.utils import dispatch as disp
+disp.install()   # BEFORE any compute module import
+from spark_rapids_tpu.utils import tracing
+import numpy as np, pandas as pd
+import jax, jax.numpy as jnp
+from jax import lax
+from spark_rapids_tpu.api import Session, col, functions as F
+from spark_rapids_tpu.execs.base import run_partitions
+
+rec = {}
+
+# -- a hand-made tree on made-up clocks: a[0,100] > b[10,30] > two
+#    launch timers, [12,15] and [20,22]
+before = tracing.table()
+with tracing.QueryRange() as q:
+    a = tracing.open_span("hand.a", 0)
+    b = tracing.open_span("hand.b", 10)
+    tracing.leaf("hand.launch", 12, 15)
+    tracing.leaf("hand.launch", 20, 22)
+    tracing.close_span(b, 30)
+    c = tracing.open_span("hand.c", 40)
+    tracing.abandon_span(c)          # leaves no record
+    tracing.close_span(a, 100)
+rec["hand_tree"] = tracing.profile(q.query_id)
+rec["hand_table"] = tracing.table_delta(before)
+
+# -- one query across run_partitions' pool threads; each task launches
+#    250 times inside a stage and once outside any
+def task(p):
+    disp._bump_stage("jit", "hand.outside")
+    tok = disp.enter_stage("hand.stage")
+    with tracing.TraceRange("hand.task"):
+        for _ in range(250):
+            disp._bump_stage("eager", "hand.inside")
+    disp.exit_stage(tok)
+    return threading.get_ident()
+stages, progs = disp.stage_snapshot(), disp.stage_programs_snapshot()
+with tracing.QueryRange() as q:
+    idents = run_partitions(4, task, 4)
+rec["pool_tree"] = tracing.profile(q.query_id)
+rec["pool_query"] = q.query_id
+rec["pool_idents"] = idents
+rec["pool_stages"] = disp.stage_delta(stages)
+rec["pool_programs"] = disp.stage_program_delta(progs)
+rec["main_ident"] = threading.get_ident()
+
+# -- the ring holds RING_QUERIES queries and no more
+ids = []
+for _ in range(tracing.RING_QUERIES + 6):
+    with tracing.QueryRange() as q:
+        pass
+    ids.append(q.query_id)
+rec["ring"] = {"held": len(tracing._ring), "limit": tracing.RING_QUERIES,
+               "first_gone": tracing.profile(ids[0]) == {},
+               "seventh_held": tracing.profile(ids[6]) != {},
+               "last_held": tracing.profile(ids[-1]) != {}}
+
+# -- three eager dynamic_slice on a device array, outside any jit
+__EAGER__
+rec["eager"] = eager
+
+# -- a q1-shaped query over a cached frame, one task thread so that the
+#    metrics' child times are taken on the thread that spent them
+s = Session({"rapids.tpu.sql.taskThreads": 1})
+rng = np.random.default_rng(0)
+n = 20000
+pdf = pd.DataFrame({"k": rng.integers(0, 4, n), "v": rng.random(n),
+                    "w": rng.random(n)})
+base = s.create_dataframe(pdf).repartition(3).cache()
+base.count()
+q1 = (base.filter(col("v") > 0.1).group_by("k")
+          .agg(F.sum(col("v")).alias("sv"), F.count("*").alias("n"))
+          .order_by("k"))
+q1.collect()
+pre, stages = disp.snapshot(), disp.stage_snapshot()
+q1.collect()
+rec["q1_delta"] = disp.delta(pre)
+rec["q1_stages"] = disp.stage_delta(stages)
+rec["q1_tree"] = q1.last_profile()
+rec["q1_metrics"] = q1.last_metrics()
+print(json.dumps(rec))
+"""
+
+_AFTER_JAX_SCRIPT = r"""
+import json, sys
+sys.path.insert(0, __ROOT__)
+import jax, jax.numpy as jnp          # jax FIRST, then install()
+from jax import lax
+from spark_rapids_tpu.utils import dispatch as disp
+disp.install()
+__EAGER__
+print(json.dumps(eager))
+"""
+
+# lax.dynamic_slice clamps its start indices before it binds the
+# primitive, so a call is the primitive and, by the index's type, a
+# convert_element_type and a select_n: each its own eager launch, each
+# counted once and named in the per-stage programs
+_EAGER = r"""
+x = jax.block_until_ready(jnp.arange(100.0))
+pre, progs = disp.snapshot(), disp.stage_programs_snapshot()
+for i in range(3):
+    lax.dynamic_slice(x, (i,), (10,))
+eager = dict(disp.delta(pre), programs=disp.stage_program_delta(progs))
+"""
+
+
+def _run(script: str) -> dict:
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c", script.replace("__ROOT__", repr(ROOT))
+         .replace("__EAGER__", _EAGER)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.fixture(scope="module")
+def on():
+    return _run(_ON_SCRIPT)
+
+
+def _walk(node):
+    yield node
+    for c in node["children"]:
+        yield from _walk(c)
+
+
+def _named(tree, name):
+    return [n for n in _walk(tree) if n["name"] == name]
+
+
+# -- off: the pytest process never ran dispatch.install() -------------------
+
+
+def test_off_a_query_leaves_no_record():
+    from spark_rapids_tpu.api import Session, col
+    from spark_rapids_tpu.utils import dispatch, tracing
+
+    assert not dispatch.installed() and not tracing.recording()
+    before, roots = tracing.table(), tracing.queries()
+    df = Session().create_dataframe(
+        pd.DataFrame({"k": np.arange(50) % 5, "v": np.arange(50.0)}))
+    pipe = df.filter(col("v") > 3).group_by("k").count()
+    assert pipe.last_profile() == {}
+    assert len(pipe.collect()) == 5
+    assert pipe.last_profile() == {}
+    assert df.count() == 50 and df.last_profile() == {}
+    assert tracing.table() == before == {}
+    assert tracing.queries() == roots == 0
+    assert tracing.current() is None
+
+
+def test_off_trace_range_reads_no_clock(monkeypatch):
+    from spark_rapids_tpu.utils import tracing
+
+    def no_clock():
+        raise AssertionError("a clock was read with recording off")
+
+    monkeypatch.setattr(tracing.time, "perf_counter_ns", no_clock)
+    with tracing.TraceRange("off.site"), tracing.QueryRange() as q:
+        pass
+    assert q.query_id is None
+
+
+# -- on ---------------------------------------------------------------------
+
+
+def test_hand_tree_nesting_and_parent(on):
+    tree = on["hand_tree"]
+    assert tree["name"] == "query" and tree["query"] is not None
+    (a,) = tree["children"]
+    assert (a["name"], a["start_ns"], a["end_ns"]) == ("hand.a", 0, 100)
+    (b,) = a["children"]          # hand.c was abandoned: no record
+    assert (b["name"], b["start_ns"], b["end_ns"]) == ("hand.b", 10, 30)
+    (launch,) = b["children"]     # the two timers: one node a name
+    assert launch["name"] == "hand.launch" and launch["children"] == []
+    assert (launch["count"], launch["total_ns"]) == (2, 5)
+    assert launch["start_ns"] is None and a["count"] == 1
+    assert {n["query"] for n in _walk(tree)} == {tree["query"]}
+    assert {n["thread"] for n in _walk(tree)} == {tree["thread"]}
+
+
+def test_hand_tree_self_time(on):
+    (a,) = on["hand_tree"]["children"]
+    (b,) = a["children"]
+    # a launch timer counts as a child: b's 20 less the launches' 3 + 2
+    assert a["self_ns"] == 100 - 20
+    assert b["self_ns"] == 20 - 5
+    assert b["children"][0]["self_ns"] == 5
+    t = on["hand_table"]
+    assert "hand.c" not in t
+    assert t["hand.a"] == {"count": 1, "total_s": 100e-9, "self_s": 80e-9}
+    assert t["hand.b"]["self_s"] == pytest.approx(15e-9)
+    assert t["hand.launch"]["count"] == 2
+    assert t["hand.launch"]["total_s"] == pytest.approx(5e-9)
+    assert t["query"]["count"] == 1
+
+
+def test_one_query_across_pool_threads(on):
+    tree = on["pool_tree"]
+    (wait,) = tree["children"]
+    assert wait["name"] == "run_partitions.wait"
+    tasks = wait["children"]
+    assert [t["name"] for t in tasks] == ["hand.task"] * 4
+    assert {t["query"] for t in tasks} == {on["pool_query"]}
+    assert sorted(t["thread"] for t in tasks) == sorted(on["pool_idents"])
+    assert on["main_ident"] not in on["pool_idents"]
+    # tasks run on other threads: they take nothing off the wait's self time
+    assert wait["self_ns"] == wait["end_ns"] - wait["start_ns"]
+
+
+def test_launches_of_pool_threads_all_counted(on):
+    """A launch inside a stage takes no lock (it waits in the thread's own
+    table until the stage is left); none is lost and each keeps its stage."""
+    assert on["pool_stages"] == {"hand.stage": 1000, "<unstaged>": 4}
+    assert on["pool_programs"] == {"hand.stage": {"hand.inside": 1000},
+                                   "<unstaged>": {"hand.outside": 4}}
+
+
+def test_ring_holds_64_queries(on):
+    assert on["ring"] == {"held": 64, "limit": 64, "first_gone": True,
+                          "seventh_held": True, "last_held": True}
+
+
+def test_q1_has_one_root_with_plan_and_fetch(on):
+    tree = on["q1_tree"]
+    assert tree["name"] == "query"
+    assert on["q1_delta"]["queries"] == 1
+    assert on["q1_delta"]["spans"]["query"]["count"] == 1
+    assert len(_named(tree, "query")) == 1
+    kids = [c["name"] for c in tree["children"]]
+    assert "plan.physical" in kids and "collect.fetch" in kids
+    (plan,) = _named(tree, "plan.physical")
+    assert [c["name"] for c in plan["children"]] == [
+        "plan.optimize", "plan.tag", "plan.convert", "plan.stages"]
+    for name in ("CachedExec.acquire", "ShuffleExchangeExec.partitionKernel",
+                 "ShuffleExchangeExec.slice", "ShuffleExchangeExec.register",
+                 "collect.concat", "launch.jit", "launch.eager",
+                 "launch.device_get"):
+        assert _named(tree, name), name
+    # the root's own time, what no child covers, is a small part of it
+    assert tree["self_ns"] < 0.1 * (tree["end_ns"] - tree["start_ns"])
+
+
+def test_q1_table_is_the_tree(on):
+    """The window's table and the query's tree are one set of records."""
+    tree, spans = on["q1_tree"], on["q1_delta"]["spans"]
+    for name, row in spans.items():
+        nodes = _named(tree, name)
+        assert sum(n["count"] for n in nodes) == row["count"], name
+        assert sum(n["self_ns"] for n in nodes) / 1e9 == \
+            pytest.approx(row["self_s"], abs=1e-9), name
+
+
+def test_q1_next_self_times_equal_last_metrics(on):
+    """An exec's op_time is its pulls less its children's pulls; both come
+    from the clock reads of timed(), so the tree says the same."""
+    def pulls_below(node):
+        for c in node["children"]:
+            if c["name"].endswith(".next"):
+                yield c
+            else:
+                yield from pulls_below(c)
+
+    tree_ms = {}
+    for n in _walk(on["q1_tree"]):
+        if n["name"].endswith(".next"):
+            own = (n["end_ns"] - n["start_ns"]) - sum(
+                c["end_ns"] - c["start_ns"] for c in pulls_below(n))
+            cls = n["name"][:-len(".next")]
+            tree_ms[cls] = tree_ms.get(cls, 0.0) + own / 1e6
+    metric_ms = {}
+    for key, m in on["q1_metrics"].items():
+        cls = key.split("#")[0]
+        metric_ms[cls] = metric_ms.get(cls, 0.0) + m["op_time_ms"]
+    assert {k for k, v in metric_ms.items() if v} == set(tree_ms)
+    for cls, ms in tree_ms.items():
+        assert ms == pytest.approx(metric_ms[cls], abs=0.005), cls
+
+
+def _three_dynamic_slices_counted(d):
+    (programs,) = d["programs"].values()
+    assert programs["eager:dynamic_slice"] == 3
+    assert all(p.startswith("eager:") for p in programs)
+    assert d["eager_op_calls"] == sum(programs.values())
+    assert (d["jit_calls"], d["transfers"]) == (0, 0)
+    assert d["dispatch_count"] == d["eager_op_calls"]
+    assert d["spans"]["launch.eager"]["count"] == d["eager_op_calls"]
+    assert d["spans"]["launch.eager"]["total_s"] > 0
+
+
+def test_three_eager_dynamic_slices_count_three(on):
+    _three_dynamic_slices_counted(on["eager"])
+
+
+def test_eager_count_when_install_follows_import_jax():
+    _three_dynamic_slices_counted(_run(_AFTER_JAX_SCRIPT))
+
+
+def test_delta_has_spans_and_queries(on):
+    d = on["q1_delta"]
+    assert set(d) == {"jit_calls", "eager_op_calls", "transfers",
+                      "dispatch_count", "spans", "queries"}
+    assert d["dispatch_count"] == \
+        d["jit_calls"] + d["eager_op_calls"] + d["transfers"]
+    assert d["eager_op_calls"] > 0
+    assert sum(on["q1_stages"].values()) == d["dispatch_count"]
+    # the launch rows are the launch counts, timed where they are counted
+    assert d["spans"]["launch.jit"]["count"] == d["jit_calls"]
+    assert d["spans"]["launch.eager"]["count"] == d["eager_op_calls"]
+    assert d["spans"]["launch.device_get"]["count"] == d["transfers"]
+    for row in d["spans"].values():
+        assert set(row) == {"count", "total_s", "self_s"}
+        assert 0 <= row["self_s"] <= row["total_s"] + 1e-12
+
+
+def test_install_fails_loudly_when_jax_moved_the_hook():
+    """A JAX without the call-time lookup is an error at install(), not an
+    eager counter that reads 0 (as it did before PR 25)."""
+    script = (
+        "import sys; sys.path.insert(0, %r)\n"
+        "from jax._src import dispatch as jd\n"
+        "del jd.xla_primitive_callable\n"
+        "from spark_rapids_tpu.utils import dispatch as disp\n"
+        "import jax\n"
+        "jit = jax.jit\n"
+        "try:\n"
+        "    disp.install()\n"
+        "except RuntimeError as e:\n"
+        "    assert 'eager' in str(e), e\n"
+        "    assert jax.jit is jit and not disp.installed()\n"
+        "    print('refused')\n" % ROOT)
+    out = subprocess.run([sys.executable, "-c", script],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().splitlines()[-1] == "refused"
