@@ -13,6 +13,7 @@ TPU-native analogue of Spark's ``ColumnarBatch`` carrying ``GpuColumnVector``s
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import List, Optional, Sequence, Union
 
 import jax
@@ -23,6 +24,33 @@ from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.column import Column, StringColumn
 
 RowCount = Union[int, jax.Array]
+
+#: most row ranges one launch of ``_slice_rows`` cuts: a program's size
+#: (and its compile time) grows with ranges x arrays, so a batch cut into
+#: more ranges of one capacity takes several launches of 16
+_MAX_SLICE_CHUNK = 16
+
+
+@partial(jax.jit, static_argnames=("cap",))
+def _slice_rows(datas, validities, starts, cap: int):
+    """Rows ``[starts[i], starts[i] + cap)`` of every array, each read as
+    if zero-padded past its capacity: ``[(datas_i, validities_i), ...]``,
+    one pair a start. The pad to ``capacity + cap`` is static, so no
+    ``dynamic_slice`` is left to clamp a start that lies within the
+    capacity. Keyed on the arrays' shapes and dtypes, ``starts.shape`` and
+    ``cap``, never on the starts themselves."""
+    k = starts.shape[0]
+
+    def windows(x):
+        if x is None:
+            return [None] * k
+        padded = jnp.concatenate([x, jnp.zeros(cap, dtype=x.dtype)])
+        return [jax.lax.dynamic_slice_in_dim(padded, starts[i], cap)
+                for i in range(k)]
+
+    d = [windows(x) for x in datas]
+    v = [windows(x) for x in validities]
+    return [([c[i] for c in d], [c[i] for c in v]) for i in range(k)]
 
 
 class Schema:
@@ -145,23 +173,43 @@ class ColumnarBatch:
         return ColumnarBatch(columns, self._num_rows)
 
     def slice(self, start: int, length: int) -> "ColumnarBatch":
-        """Zero-copy-ish row range view (SlicedGpuColumnVector analogue).
-        Result is re-bucketed to the smallest capacity holding ``length``."""
+        """Row range view (SlicedGpuColumnVector analogue). Result is
+        re-bucketed to the smallest capacity holding ``length``."""
+        return self.slices([start], [length])[0]
+
+    def slices(self, starts: Sequence[int], lengths: Sequence[int]
+               ) -> "List[ColumnarBatch]":
+        """Many row ranges of this batch at once, each re-bucketed to the
+        smallest capacity holding its length; rows past the batch's own
+        capacity read as zeros. The ranges that share an output capacity
+        go through ``_slice_rows`` in power-of-two chunks (13 = 8 + 4 +
+        1), so the programs compiled follow the ladder's rungs and not
+        the lengths."""
         from spark_rapids_tpu.ops.buckets import bucket_capacity
         n = self.realized_num_rows()
-        start = max(0, min(start, n))
-        length = max(0, min(length, n - start))
-        cap = bucket_capacity(length)
-        cols = []
-        for c in self.columns:
-            grown = c.with_capacity(max(cap + start, c.capacity))
-            data = jax.lax.dynamic_slice_in_dim(grown.data, start, cap)
-            validity = None
-            if grown.validity is not None:
-                validity = jax.lax.dynamic_slice_in_dim(
-                    grown.validity, start, cap)
-            cols.append(c._like(data, validity))
-        return ColumnarBatch(cols, length)
+        starts = np.clip(starts, 0, n).astype(np.int64)
+        lengths = np.clip(lengths, 0, n - starts).astype(np.int64)
+        if not self.columns:
+            return [ColumnarBatch([], int(length)) for length in lengths]
+        by_cap = {}
+        for i, length in enumerate(lengths):
+            by_cap.setdefault(bucket_capacity(int(length)), []).append(i)
+        datas = [c.data for c in self.columns]
+        validities = [c.validity for c in self.columns]
+        out: List[Optional[ColumnarBatch]] = [None] * len(lengths)
+        for cap, members in by_cap.items():
+            while members:
+                k = min(1 << (len(members).bit_length() - 1),
+                        _MAX_SLICE_CHUNK)
+                chunk, members = members[:k], members[k:]
+                cut = _slice_rows(datas, validities,
+                                  starts[chunk].astype(np.int32), cap=cap)
+                for i, (d, v) in zip(chunk, cut):
+                    out[i] = ColumnarBatch(
+                        [c._like(cd, cv)
+                         for c, cd, cv in zip(self.columns, d, v)],
+                        int(lengths[i]))
+        return out
 
     # -- host materialization --------------------------------------------
 

@@ -433,8 +433,8 @@ class ShuffleExchangeExec(TpuExec):
             with TraceRange("ShuffleExchangeExec.partition"):
                 with TraceRange("ShuffleExchangeExec.partitionKernel"):
                     sorted_b, counts = self._partition_batch(b)
-                # the eager dynamic_slice/concatenate/broadcast_in_dim
-                # launches of an exchange come from here
+                # one compiled program a batch and output capacity
+                # (ColumnarBatch.slices): no eager launch under this span
                 with TraceRange("ShuffleExchangeExec.slice"):
                     subs = part_ops.slice_partitions(sorted_b, counts)
                 with TraceRange("ShuffleExchangeExec.register"):

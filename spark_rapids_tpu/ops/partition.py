@@ -348,12 +348,10 @@ def slice_partitions(batch: ColumnarBatch, counts: np.ndarray
     """Materialize each contiguous partition as its own (re-bucketed) batch;
     empty partitions yield None (the caching writer skips them,
     RapidsShuffleInternalManager.scala:120)."""
-    offsets = np.concatenate([[0], np.cumsum(counts)])
-    out: List[Optional[ColumnarBatch]] = []
-    for p in range(len(counts)):
-        n = int(counts[p])
-        if n == 0:
-            out.append(None)
-            continue
-        out.append(batch.slice(int(offsets[p]), n))
+    counts = np.asarray(counts, dtype=np.int64)
+    kept = np.nonzero(counts)[0]
+    starts = np.cumsum(counts) - counts
+    out: List[Optional[ColumnarBatch]] = [None] * len(counts)
+    for p, sub in zip(kept, batch.slices(starts[kept], counts[kept])):
+        out[p] = sub
     return out

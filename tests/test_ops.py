@@ -215,6 +215,117 @@ def test_round_robin_partition():
     assert sorted(counts.tolist(), reverse=True)[0] == 4
 
 
+def _slice_case_batch(kinds, capacity, n, seed=0):
+    """A batch of ``n`` live rows at ``capacity`` whose padding rows are
+    NOT zero (a slice must carry them as they are), and its host copy."""
+    rng = np.random.default_rng(seed)
+    cols, host = [], []
+    for kind in kinds:
+        validity = None
+        if kind.endswith("?"):
+            validity = rng.random(capacity) < 0.7
+        if kind.startswith("str"):
+            data = rng.integers(0, 3, capacity).astype(np.int32)
+            col = StringColumn(
+                jnp.asarray(data), np.array(["a", "b", "c"], dtype=object),
+                None if validity is None else jnp.asarray(validity))
+        else:
+            t = {"i64": dt.INT64, "f64": dt.FLOAT64, "i32": dt.INT32,
+                 "bool": dt.BOOLEAN}[kind.rstrip("?")]
+            data = (rng.random(capacity) * 1000 + 1).astype(t.np_dtype)
+            col = Column(t, jnp.asarray(data),
+                         None if validity is None else jnp.asarray(validity))
+        cols.append(col)
+        host.append((data, validity))
+    return ColumnarBatch(cols, n), host
+
+
+_MIXED = ("i64?", "f64", "str?", "i32", "bool?")
+
+_SLICE_CASES = {
+    # name: (column kinds, input capacity, counts)
+    "empty_partition": (_MIXED, 512, [3, 0, 5]),
+    "single_at_start_0": (_MIXED, 512, [7]),
+    "all_empty": (_MIXED, 512, [0, 0]),
+    "start_plus_cap_past_capacity": (_MIXED, 512, [450, 50]),
+    "larger_than_128_rows": (_MIXED, 512, [200, 300]),
+    "whole_batch": (_MIXED, 512, [512]),
+    "thirteen_mixed_capacities": (
+        _MIXED, 2048,
+        [100, 0, 130, 5, 128, 129, 257, 1, 90, 300, 0, 64, 700]),
+    "string_column": (("str", "str?"), 512, [40, 2, 300]),
+    "no_validity": (("i64", "f64", "i32"), 512, [40, 2, 300]),
+    "all_validity": (("i64?", "f64?", "i32?"), 512, [40, 2, 300]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SLICE_CASES))
+def test_slice_partitions_against_numpy(case):
+    """``slice_partitions`` against "zero-pad, then take [start, start +
+    cap)": data and validity bit for bit (padding rows too), num_rows,
+    capacity, dictionary; an empty partition stays None."""
+    from spark_rapids_tpu.ops.buckets import bucket_capacity
+
+    kinds, capacity, counts = _SLICE_CASES[case]
+    batch, host = _slice_case_batch(kinds, capacity, sum(counts))
+    parts = partition.slice_partitions(batch, np.asarray(counts))
+    assert len(parts) == len(counts)
+    start = 0
+    for n, part in zip(counts, parts):
+        if n == 0:
+            assert part is None
+            continue
+        cap = bucket_capacity(n)
+        assert part.num_rows == n and part.capacity == cap
+        for col, src, (data, validity) in zip(part.columns, batch.columns,
+                                              host):
+            assert type(col) is type(src) and col.dtype is src.dtype
+            want = np.concatenate([data, np.zeros(cap, data.dtype)])
+            got = np.asarray(col.data)
+            assert got.dtype == data.dtype
+            np.testing.assert_array_equal(got, want[start:start + cap])
+            if validity is None:
+                assert col.validity is None
+            else:
+                want_v = np.concatenate([validity, np.zeros(cap, bool)])
+                np.testing.assert_array_equal(
+                    np.asarray(col.validity), want_v[start:start + cap])
+            if isinstance(src, StringColumn):
+                assert col.dictionary is src.dictionary
+        start += n
+    # ColumnarBatch.slice is the one-range case of the same program
+    if len(counts) > 1 and counts[1]:
+        one = batch.slice(counts[0], counts[1])
+        assert one.num_rows == counts[1]
+        for a, b in zip(one.columns, parts[1].columns):
+            np.testing.assert_array_equal(np.asarray(a.data),
+                                          np.asarray(b.data))
+
+
+def test_slice_programs_follow_shapes_not_counts():
+    """50 random count vectors over 16 partitions at one input capacity
+    compile no more programs than output rungs x chunk sizes: the program
+    is keyed on (schema, capacity, output capacity, chunk), never on the
+    counts."""
+    from spark_rapids_tpu.columnar.batch import _slice_rows
+    from spark_rapids_tpu.ops.buckets import ladder_rungs
+
+    capacity = 2048
+    batch, _ = _slice_case_batch(("i64?", "f64"), capacity, capacity)
+    rng = np.random.default_rng(7)
+    before = _slice_rows._cache_size()
+    for _ in range(50):
+        # skewed: a few large partitions, many small, some empty
+        w = rng.random(16) ** 4
+        counts = np.floor(w / w.sum() * rng.integers(1, capacity + 1))
+        parts = partition.slice_partitions(batch, counts.astype(np.int64))
+        assert [0 if p is None else p.num_rows for p in parts] == \
+            counts.tolist()
+    rungs = len(ladder_rungs(capacity))
+    chunk_sizes = int(np.log2(16)) + 1
+    assert _slice_rows._cache_size() - before <= rungs * chunk_sizes
+
+
 # ---------------------------------------------------------------- concat
 
 def test_concat_batches():

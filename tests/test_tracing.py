@@ -83,6 +83,26 @@ rec["ring"] = {"held": len(tracing._ring), "limit": tracing.RING_QUERIES,
 __EAGER__
 rec["eager"] = eager
 
+# -- one batch of q1's partial-aggregate schema (two string keys, sums
+#    and averages with validity, counts without) cut into 2 partitions
+from spark_rapids_tpu.columnar import dtypes as dt
+from spark_rapids_tpu.columnar.batch import ColumnarBatch
+from spark_rapids_tpu.columnar.column import Column, StringColumn
+from spark_rapids_tpu.ops import partition
+live = jnp.arange(128) < 4
+keys = [StringColumn(jnp.zeros(128, jnp.int32), np.array(["A"], dtype=object))
+        for _ in range(2)]
+sums = [Column(dt.FLOAT64, jnp.ones(128, jnp.float64), live) for _ in range(7)]
+counts = [Column(dt.INT64, jnp.ones(128, jnp.int64)) for _ in range(4)]
+partial = ColumnarBatch(keys + sums + counts, 4)
+jax.block_until_ready(partition.slice_partitions(partial, np.array([3, 1]))[1]
+                      .columns[0].data)
+pre = disp.snapshot()
+with tracing.QueryRange():
+    with tracing.TraceRange("hand.slice"):
+        subs = partition.slice_partitions(partial, np.array([1, 3]))
+rec["slice"] = dict(disp.delta(pre), rows=[b.num_rows for b in subs])
+
 # -- a q1-shaped query over a cached frame, one task thread so that the
 #    metrics' child times are taken on the thread that spent them
 s = Session({"rapids.tpu.sql.taskThreads": 1})
@@ -265,6 +285,24 @@ def test_q1_has_one_root_with_plan_and_fetch(on):
         assert _named(tree, name), name
     # the root's own time, what no child covers, is a small part of it
     assert tree["self_ns"] < 0.1 * (tree["end_ns"] - tree["start_ns"])
+
+
+def test_slicing_an_exchanged_batch_is_one_launch(on):
+    """The launch fence: a 2-partition batch of q1's partial aggregate
+    costs no eager primitive and at most 2 compiled programs (one when
+    both partitions share a capacity), in the hand-made call and under
+    every ``ShuffleExchangeExec.slice`` of the q1-shaped query."""
+    d = on["slice"]
+    assert d["rows"] == [1, 3]
+    assert d["eager_op_calls"] == 0 and d["transfers"] == 0
+    assert 1 <= d["jit_calls"] <= 2
+    assert "launch.eager" not in d["spans"]
+    assert d["spans"]["launch.jit"]["count"] == d["jit_calls"]
+    slices = _named(on["q1_tree"], "ShuffleExchangeExec.slice")
+    assert slices
+    for node in slices:
+        kids = {c["name"]: c["count"] for c in node["children"]}
+        assert set(kids) == {"launch.jit"} and kids["launch.jit"] <= 2, kids
 
 
 def test_q1_table_is_the_tree(on):
