@@ -1,11 +1,13 @@
 """First-run proof on the chip: TPC-H q6 and q1 (and, with
 ``--with-join``, q3) at sf 1 through ``Session.sql`` on one TPU chip,
-every answer checked against the ``cpu/`` engine on the same files.
+every answer checked against the ``cpu/`` engine on the same files (q3:
+against a pandas merge).
 
     python chip_smoke.py              # one chip (what the driver runs)
-    python chip_smoke.py --with-join  # q3 too: its x64 sort programs cost
-                                      # the chip's compiler more than the
-                                      # 1200 s a cold default run is given
+    python chip_smoke.py --with-join  # q3 too, from files: minutes of
+                                      # compilation when cold (1,143 s
+                                      # before PR 27; over cached tables
+                                      # 104 s since)
     python chip_smoke.py --chips 4    # ONLY the mesh phase: q1 (and q3 with
                                       # --with-join) with rapids.tpu.mesh
                                       # over four chips vs a single-device
@@ -171,12 +173,52 @@ def frames_equal(want, got) -> None:
     assert_frames_equal(want, got, sort=False, approx_float=1e-6)
 
 
-def check_answers(name: str, session_df, *frames) -> None:
-    """The same statement on the cpu/ engine over the same files, run
-    once, against every frame the device path returned."""
+def q3_by_pandas(data_dir: str):
+    """Q3 as a pandas merge in float64 over the same files (the cpu/
+    engine takes 1,440 s for it at sf 1, PR 23: too long to hold a chip
+    for). DATE comes back as days since 1970, as the engine returns it."""
+    import numpy as np
+    import pandas as pd
+    import pyarrow.parquet as pq
+
+    def read(table, cols):
+        return pq.read_table(os.path.join(data_dir, table),
+                             columns=cols).to_pandas()
+
+    def days(col):
+        return pd.to_datetime(col).values.astype("datetime64[D]").astype(
+            np.int32)
+
+    c = read("customer", ["c_custkey", "c_mktsegment"])
+    o = read("orders", ["o_orderkey", "o_custkey", "o_orderdate",
+                        "o_shippriority"])
+    li = read("lineitem", ["l_orderkey", "l_extendedprice", "l_discount",
+                           "l_shipdate"])
+    cut = (np.datetime64("1995-03-15") - np.datetime64("1970-01-01")
+           ).astype(np.int32)
+    o["o_orderdate"] = days(o.o_orderdate)
+    m = c[c.c_mktsegment == "BUILDING"] \
+        .merge(o[o.o_orderdate < cut], left_on="c_custkey",
+               right_on="o_custkey") \
+        .merge(li[days(li.l_shipdate) > cut], left_on="o_orderkey",
+               right_on="l_orderkey")
+    m["revenue"] = m.l_extendedprice * (1.0 - m.l_discount)
+    g = m.groupby(["l_orderkey", "o_orderdate", "o_shippriority"],
+                  as_index=False)["revenue"].sum()
+    g = g.sort_values(["revenue", "o_orderdate"], ascending=[False, True],
+                      kind="stable").head(10)
+    return g[["l_orderkey", "revenue", "o_orderdate",
+              "o_shippriority"]].reset_index(drop=True)
+
+
+def check_answers(name: str, session_df, data_dir: str, *frames) -> None:
+    """The same statement on the cpu/ engine over the same files (q3: a
+    pandas merge), run once, against every frame the device path
+    returned."""
     from spark_rapids_tpu.cpu.engine import execute_cpu
 
-    want = execute_cpu(session_df._plan).to_pandas()
+    want = q3_by_pandas(data_dir) if name == "q3" \
+        else execute_cpu(session_df._plan).to_pandas()
     assert len(want) > 0, f"{name}: the reference answer is empty"
     for got in frames:
         frames_equal(want, got)
@@ -243,8 +285,8 @@ def one_chip(sf: float, names) -> None:
     try:
         for name in names:
             rec, cold, warm, df = run_query(session, name, meter)
-            check_answers(name, df, cold, warm)
-            rec["matches_cpu_engine"] = True
+            check_answers(name, df, data_dir, cold, warm)
+            rec["matches_reference"] = True
             print(json.dumps(rec), flush=True)
     finally:
         session.stop()
@@ -282,7 +324,7 @@ def four_chips(sf: float, names) -> None:
     try:
         for name in names:
             rec, cold, warm, df = run_query(session, name, meter)
-            check_answers(name, df, warm)
+            check_answers(name, df, data_dir, warm)
             rec["session"] = "single_device"
             single[name] = warm
             print(json.dumps(rec), flush=True)
@@ -302,7 +344,7 @@ def four_chips(sf: float, names) -> None:
             fallbacks = pmesh.mesh_fallback_delta(fb0)
             assert not fallbacks, f"{name}: mesh fallbacks {fallbacks}"
             frames_equal(single[name], warm)
-            check_answers(name, df, cold)
+            check_answers(name, df, data_dir, cold)
             rec["session"] = "mesh_4"
             rec["mesh_execs"] = mesh_execs
             rec["matches_single_device"] = True

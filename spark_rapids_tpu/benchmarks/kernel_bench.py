@@ -1,12 +1,9 @@
 """Native-kernel microbench: each Pallas kernel vs the jnp (or host)
 implementation it replaces, op by op (KERNEL_r01 record).
 
-Four ops, matching the three gated kernel kinds plus the fused-chain
-compaction the sort kernel also serves:
+Two ops, matching the two gated kernel kinds:
 
-- ``compact``     partition_order + takes  vs  stable argsort(~mask) + takes
 - ``join_probe``  device hash-table probe  vs  two searchsorted passes
-- ``lexsort``     LSD radix lexsort        vs  jnp.lexsort over key arrays
 - ``string_contains``  char-table kernel   vs  the host dictionary map
 
 Every op asserts bit-equality between the two paths before timing —
@@ -36,39 +33,6 @@ def _time(fn, iterations: int, warmup: int = 1) -> float:
         jax.block_until_ready(fn())
         best = min(best, time.perf_counter() - t0)
     return best
-
-
-def bench_compact(rows: int, iterations: int, seed: int = 3) -> dict:
-    """Fused-chain row compaction: permutation-from-liveness + payload
-    gathers. The baseline is what execs/fused.run_steps does with the
-    gate off; the kernel path is what it does with the gate on."""
-    import jax
-    import jax.numpy as jnp
-
-    from spark_rapids_tpu.native.kernels import sort as nsort
-
-    r = np.random.default_rng(seed)
-    mask = jnp.asarray(r.random(rows) > 0.5)
-    pays = [jnp.asarray(r.integers(0, 10**9, rows)) for _ in range(3)]
-
-    @jax.jit
-    def base(m, ps):
-        order = jnp.argsort(~m, stable=True)
-        return [jnp.take(p, order) for p in ps]
-
-    @jax.jit
-    def kern(m, ps):
-        order = nsort.partition_order(m)
-        return [jnp.take(p, order) for p in ps]
-
-    b = jax.device_get(base(mask, pays))
-    k = jax.device_get(kern(mask, pays))
-    equal = all(np.array_equal(x, y) for x, y in zip(b, k))
-    base_s = _time(lambda: base(mask, pays), iterations)
-    kern_s = _time(lambda: kern(mask, pays), iterations)
-    return {"n": rows, "jnp_s": round(base_s, 4),
-            "kernel_s": round(kern_s, 4),
-            "ratio": round(base_s / kern_s, 3), "equal": bool(equal)}
 
 
 def bench_join_probe(build_rows: int, probe_rows: int, iterations: int,
@@ -106,48 +70,6 @@ def bench_join_probe(build_rows: int, probe_rows: int, iterations: int,
     base_s = _time(lambda: base(h_b, h_p), iterations)
     kern_s = _time(lambda: kern(table, h_p), iterations)
     return {"n": probe_rows, "jnp_s": round(base_s, 4),
-            "kernel_s": round(kern_s, 4),
-            "ratio": round(base_s / kern_s, 3), "equal": bool(equal)}
-
-
-def bench_lexsort(rows: int, iterations: int, seed: int = 7) -> dict:
-    """Permutation-producing lexsort over a composite radixable key
-    (null-rank + int64 + int32), the ops/sortkeys routing pair."""
-    import jax
-    import jax.numpy as jnp
-
-    from spark_rapids_tpu.columnar import dtypes as dt
-    from spark_rapids_tpu.native.kernels import sort as nsort
-    from spark_rapids_tpu.ops import sortkeys
-    from spark_rapids_tpu.ops.sortkeys import SortKeySpec
-
-    r = np.random.default_rng(seed)
-    k1 = jnp.asarray(r.integers(-10**12, 10**12, rows))
-    v1 = jnp.asarray(r.random(rows) > 0.1)
-    k2 = jnp.asarray(r.integers(0, 100, rows).astype(np.int32))
-    cols = [(k1, v1), (k2, None)]
-    dtypes = [dt.INT64, dt.INT32]
-    specs = [SortKeySpec(0, ascending=False, nulls_first=False),
-             SortKeySpec(1)]
-    num_rows = jnp.asarray(rows)
-
-    @jax.jit
-    def base(c0, c0v, c1, n):
-        keys = sortkeys.order_key_arrays(
-            [(c0, c0v), (c1, None)], dtypes, specs, n)
-        return jnp.lexsort(list(reversed(keys)))
-
-    @jax.jit
-    def kern(c0, c0v, c1, n):
-        return nsort.lexsort_order(
-            [(c0, c0v), (c1, None)], dtypes, specs, n)
-
-    b = np.asarray(jax.device_get(base(k1, v1, k2, num_rows)))
-    k = np.asarray(jax.device_get(kern(k1, v1, k2, num_rows)))
-    equal = np.array_equal(b, k)
-    base_s = _time(lambda: base(k1, v1, k2, num_rows), iterations)
-    kern_s = _time(lambda: kern(k1, v1, k2, num_rows), iterations)
-    return {"n": rows, "jnp_s": round(base_s, 4),
             "kernel_s": round(kern_s, 4),
             "ratio": round(base_s / kern_s, 3), "equal": bool(equal)}
 
@@ -194,10 +116,8 @@ def run(rows: int = 2_000_000, iterations: int = 3) -> dict:
     from spark_rapids_tpu.native import kernels as nk
 
     ops = {
-        "compact": bench_compact(rows, iterations),
         "join_probe": bench_join_probe(
             max(rows // 8, 1024), rows, iterations),
-        "lexsort": bench_lexsort(max(rows // 4, 1024), iterations),
         "string_contains": bench_string_contains(20_000, iterations),
     }
     return {

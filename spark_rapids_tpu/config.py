@@ -424,7 +424,7 @@ FUSION_ENABLED = conf("rapids.tpu.sql.fusion.enabled").doc(
 
 FUSION_SORT_TAIL = conf("rapids.tpu.sql.fusion.sortTail").doc(
     "Absorb a global ORDER BY into the post-aggregate chain program "
-    "(SortStep): final projection + HAVING + project + variadic sort "
+    "(SortStep): final projection + HAVING + project + sort "
     "run as ONE dispatch over the aggregate's raw partials, and the "
     "aggregate skips its own final-project dispatch and rebucket host "
     "sync. Disable if the fused sort module misbehaves on a backend "
@@ -479,14 +479,13 @@ NATIVE_KERNELS_ENABLED = conf("rapids.tpu.native.kernels.enabled").doc(
     "Master switch for the native Pallas kernel layer "
     "(spark_rapids_tpu/native/kernels): hand-written device kernels "
     "replacing the jnp graphs where XLA's lowering is the measured "
-    "floor — the open-addressing hash-join probe, the prefix-scan "
-    "partition/segmented sort, and the dictionary-string predicate "
-    "kernels. INTERPRETER ONLY; refused by the v5e compiler as of "
-    "PR 23: the kernels have only ever run through Pallas interpret "
-    "mode on CPU backends, and the TPU (Mosaic) lowering refuses them "
-    "(sort: cumsum and non-2D gather are unimplemented; join and "
-    "strings are of the same make), so enabling this on a chip fails "
-    "at compile time. Off by default: the jnp implementations are the "
+    "floor — the open-addressing hash-join probe and the "
+    "dictionary-string predicate "
+    "kernels. INTERPRETER ONLY: the kernels have only ever run through "
+    "Pallas interpret mode on CPU backends, and the TPU (Mosaic) "
+    "lowering refuses data-dependent whole-ref gathers of their make "
+    "(seen with the sort kernels, PR 23, deleted in PR 27), so "
+    "enabling this on a chip fails at compile time. Off by default: the jnp implementations are the "
     "reference semantics and every kernel is differentially fenced "
     "against them on the CPU."
 ).boolean_conf.create_with_default(False)
@@ -498,15 +497,6 @@ NATIVE_KERNELS_JOIN = conf("rapids.tpu.native.kernels.join").doc(
     "probe is one gather-scan kernel — replacing both the dense "
     "inverse-table and the hash+searchsorted probe dichotomy. "
     "Requires rapids.tpu.native.kernels.enabled."
-).boolean_conf.create_with_default(True)
-
-NATIVE_KERNELS_SORT = conf("rapids.tpu.native.kernels.sort").doc(
-    "Route row compaction and multi-column (segmented) sorts through "
-    "the native prefix-scan kernels: live-mask compaction becomes one "
-    "O(n) scan+scatter instead of a stable argsort, and ORDER BY "
-    "permutations run as binary-radix passes over order keys instead "
-    "of the variadic sort network whose payload carry blows up past "
-    "6 lanes. Requires rapids.tpu.native.kernels.enabled."
 ).boolean_conf.create_with_default(True)
 
 NATIVE_KERNELS_STRINGS = conf("rapids.tpu.native.kernels.strings").doc(

@@ -41,7 +41,7 @@ class HashAggregateExec(TpuExec):
     #: exec's final-project dispatch and rebucket sync disappear
     defer_final = False
     #: deferred-final outputs above this capacity rebucket anyway: the
-    #: consuming chain's in-program sort is a full-capacity variadic
+    #: consuming chain's in-program sort is a full-capacity
     #: sort network, so the dispatch saving must not buy a multi-
     #: million-lane sort (group counts overwhelmingly fit far below)
     _DEFER_FINAL_MAX_CAP = 1 << 20
@@ -358,7 +358,7 @@ class HashAggregateExec(TpuExec):
                 # projection, HAVING and compaction in ITS program;
                 # the count stays a lazy device scalar. Above the
                 # capacity bound, rebucket anyway (one sync + shrink):
-                # the chain's variadic SORT runs at this batch's
+                # the chain's SORT runs at this batch's
                 # capacity, and a multi-million-lane sort network to
                 # save two round trips is a net loss at large scale
                 # factors

@@ -245,8 +245,9 @@ def make_project_step(exprs: Sequence[Expression]) -> ProjectStep:
 
 @dataclasses.dataclass
 class SortStep:
-    """Terminal ORDER BY inside a chain program: one variadic
-    ``lax.sort`` carries every column through the sort network, dead
+    """Terminal ORDER BY inside a chain program: the ORDER BY lanes
+    and a row index are sorted (``sortkeys.stable_order``), every column
+    follows with a gather, dead
     lanes (filtered rows, padding) sink to the end, and the live count
     comes out as a lazy device scalar — so a post-aggregate
     HAVING/project/sort tail runs as ONE compiled program instead of
@@ -359,10 +360,8 @@ def _prep_build_arrays(datas, vals, num_rows, key_ords, types, hash_types,
     cap = h.shape[0]
     live = jnp.arange(cap, dtype=jnp.int32) < num_rows
     h_l = jnp.where(live & (h != _BUILD_NULL), h, _MAXH)
-    order = jnp.argsort(h_l, stable=True)
-    sh = jnp.take(h_l, order)
-    sdatas = [jnp.take(d, order) for d in datas]
-    svals = [None if v is None else jnp.take(v, order) for v in vals]
+    order, (sh,) = sortkeys.stable_order([h_l])
+    sdatas, svals = sortkeys.take_rows(order, datas, vals)
     if cap > 1:
         dup = jnp.any((sh[1:] == sh[:-1]) & (sh[:-1] != _MAXH))
     else:
@@ -740,46 +739,21 @@ class FusedChain:
                 else:
                     cols, live = _apply_join(step, cols, live,
                                              builds[step.build_index])
-            if sort_step is not None:
-                # ONE variadic sort carries every column; dead lanes
-                # (padding + filtered rows) sink last via the live mask
-                pairs = [(c.data, c.validity) for c in cols]
-                dts = [c.dtype for c in cols]
-                payloads = []
-                layout = []
-                for c in cols:
-                    di = len(payloads)
-                    payloads.append(c.data)
-                    vi = -1
-                    if c.validity is not None:
-                        vi = len(payloads)
-                        payloads.append(c.validity)
-                    layout.append((di, vi))
-                sorted_pl = sortkeys.sort_with_payloads(
-                    pairs, dts, list(sort_step.specs), num_rows,
-                    payloads, live_mask=live)
-                outs = [(sorted_pl[di],
-                         None if vi < 0 else sorted_pl[vi])
-                        for di, vi in layout]
-                return outs, jnp.sum(live).astype(jnp.int32)
             outs = [(c.data, c.validity) for c in cols]
-            if not compact_out:
-                return outs, live
-            if nkr.enabled("sort"):
-                # O(n) prefix-scan partition kernel: bit-equal to the
-                # stable argsort but skips the O(n log n) sort network
-                # — the measured end-of-chain cost at sf1 widths
-                from spark_rapids_tpu.native.kernels import \
-                    sort as nsort
-
-                order = nsort.partition_order(live)
+            if sort_step is not None:
+                # dead lanes (padding + filtered rows) sink last via
+                # the live mask
+                order = sortkeys.lexsort_indices(
+                    outs, [c.dtype for c in cols],
+                    list(sort_step.specs), num_rows, live_mask=live)
+            elif compact_out:
+                order, _ = sortkeys.stable_order([~live])
             else:
-                order = jnp.argsort(~live, stable=True)
-            n = jnp.sum(live).astype(jnp.int32)
-            outs = [(jnp.take(d, order),
-                     None if v is None else jnp.take(v, order))
-                    for d, v in outs]
-            return outs, n
+                return outs, live
+            datas, vals = sortkeys.take_rows(
+                order, [d for d, _ in outs], [v for _, v in outs])
+            return list(zip(datas, vals)), \
+                jnp.sum(live).astype(jnp.int32)
 
         def inline_build_ops(raw_builds):
             # in-program build: trace the build-side prep (hash sort,
@@ -1696,7 +1670,7 @@ def _maybe_defer_scan(out, new_source, shared, conf) -> None:
 def _fuse_sort_tail(node, conf, memo: dict, shared: set):
     """Absorb a global ORDER BY into the post-aggregate chain below it:
     Sort(Project(Filter(Agg))) becomes ONE chain program (final-project
-    + HAVING + project + in-program variadic sort) over the aggregate's
+    + HAVING + project + in-program sort) over the aggregate's
     raw partials. Valid only when the source emits exactly one batch on
     one partition — a hash aggregate — because a per-batch sort of a
     multi-batch stream is not a global sort."""

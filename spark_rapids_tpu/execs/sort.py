@@ -16,7 +16,7 @@ mandate not to replicate the RequireSingleBatch cliff):
      bound order — the output stream is globally ordered without any
      single resident batch exceeding the budget.
 
-TPU note: buckets are sorted independently (one variadic-sort HLO per
+TPU note: buckets are sorted independently (one sort program per
 bucket at a bounded shape) — there is no k-way merge kernel to keep
 resident; order across buckets comes from the range partitioning.
 """

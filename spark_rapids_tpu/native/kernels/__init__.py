@@ -4,12 +4,11 @@ one sanctioned ``pallas_call`` entry point.
 The reference accelerator's entire win lives in its native kernel layer
 (cuDF's JNI surface); this package is the TPU analogue — hand-written
 Pallas kernels for the ops where jit-of-jnp is the measured floor
-(BENCH_r08's per-stage program attribution): the hash-join probe, row
-compaction / segmented sort, and dictionary-string predicates. Three
-rules hold the layer together:
+(BENCH_r08's per-stage program attribution): the hash-join probe and
+dictionary-string predicates. Three rules hold the layer together:
 
 1. **Gated, default-off.** Every kernel routes through ``enabled(kind)``
-   reading the ``rapids.tpu.native.kernels.{enabled,join,sort,strings}``
+   reading the ``rapids.tpu.native.kernels.{enabled,join,strings}``
    knobs (applied process-wide by ``runtime.device.initialize``, same
    contract as memory/retry). With the gate off, callers run the
    existing jnp implementations unchanged — the differential fences in
@@ -35,18 +34,16 @@ from spark_rapids_tpu.utils import lockorder
 
 _LOCK = lockorder.make_lock("native.kernels.config")
 
-_DEFAULTS = {"enabled": False, "join": True, "sort": True,
-             "strings": True}
+_DEFAULTS = {"enabled": False, "join": True, "strings": True}
 _state = dict(_DEFAULTS)
 
 
 def configure(enabled: Optional[bool] = None, join: Optional[bool] = None,
-              sort: Optional[bool] = None,
               strings: Optional[bool] = None) -> None:
     """Set the process-wide kernel gates (None = leave unchanged)."""
     with _LOCK:
         for key, val in (("enabled", enabled), ("join", join),
-                         ("sort", sort), ("strings", strings)):
+                         ("strings", strings)):
             if val is not None:
                 _state[key] = bool(val)
 
@@ -56,7 +53,6 @@ def configure_from_conf(conf) -> None:
 
     configure(enabled=conf.get(cfg.NATIVE_KERNELS_ENABLED),
               join=conf.get(cfg.NATIVE_KERNELS_JOIN),
-              sort=conf.get(cfg.NATIVE_KERNELS_SORT),
               strings=conf.get(cfg.NATIVE_KERNELS_STRINGS))
 
 
@@ -67,7 +63,7 @@ def reset_config() -> None:
 
 
 def enabled(kind: str) -> bool:
-    """Is the ``kind`` kernel ('join' | 'sort' | 'strings') active?"""
+    """Is the ``kind`` kernel ('join' | 'strings') active?"""
     with _LOCK:
         return _state["enabled"] and _state[kind]
 
@@ -77,8 +73,7 @@ def cache_token() -> tuple:
     program whose trace read a gate must key on this, or a mid-process
     knob flip would serve the stale routing."""
     with _LOCK:
-        return (_state["enabled"], _state["join"], _state["sort"],
-                _state["strings"])
+        return (_state["enabled"], _state["join"], _state["strings"])
 
 
 def interpret_mode() -> bool:

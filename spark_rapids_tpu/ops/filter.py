@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column
+from spark_rapids_tpu.ops import sortkeys
 
 ColPair = Tuple[jax.Array, Optional[jax.Array]]
 
@@ -26,11 +27,9 @@ def _compact(datas, validities, keep: jax.Array, num_rows: jax.Array):
     live = jnp.arange(capacity, dtype=jnp.int32) < num_rows
     keep = keep & live
     # stable: kept rows first, original order preserved
-    order = jnp.argsort(~keep, stable=True)
+    order, _ = sortkeys.stable_order([~keep])
     new_count = jnp.sum(keep).astype(jnp.int32)
-    out_datas = [jnp.take(d, order) for d in datas]
-    out_validities = [None if v is None else jnp.take(v, order)
-                      for v in validities]
+    out_datas, out_validities = sortkeys.take_rows(order, datas, validities)
     return out_datas, out_validities, new_count
 
 

@@ -1,11 +1,13 @@
-"""Group-by aggregation: sort-based segmented reduction, gather-free.
+"""Group-by aggregation: sort-based segmented reduction.
 
 cuDF gives the reference a hash-based ``groupBy.aggregate``
 (aggregate.scala:810-890). TPUs have no device hash tables, but XLA's sort
 is fast, so the TPU-native plan is:
 
-  1. ONE stable variadic sort clusters equal keys (nulls group; NaN==NaN
-     and -0.0==0.0 per Spark grouping semantics). When every key's value
+  1. ONE stable sort of the key lanes and a row index
+     (``sortkeys.stable_order``) clusters equal keys (nulls group; NaN==NaN
+     and -0.0==0.0 per Spark grouping semantics); the aggregate inputs
+     follow with one gather each. When every key's value
      range is host-known (string dictionaries always are; numeric columns
      via footer/upload stats) all keys PACK into a single int32/int64 sort
      lane — measured 37 ms vs 52 ms for the multi-lane layout at 4M rows
@@ -14,11 +16,11 @@ is fast, so the TPU-native plan is:
   3. per-aggregate ROW-SPACE lanes: prefix sums for sum/count (cumsum
      diffs at segment edges — exact for ints even across wrap), segmented
      scans for min/max, shifted lanes for first/last,
-  4. ONE more stable sort keyed on ~boundary compacts every per-group
-     output lane to a group prefix. This replaces the per-output
-     ``jnp.take`` gathers of the round-1 kernel — a single 4M-row f64
-     gather measured ~100 ms on a v5e while a whole extra sort pass is
-     ~25-35 ms, and ALL outputs ride one pass,
+  4. ONE more stable order, keyed on ~boundary, says where each group
+     starts; every per-group output lane is gathered to a group prefix.
+     No lane rides either sort: carrying them cost the chip's compiler
+     435 s at 65,536 rows for TPC-H Q3's int64-key group-by (PR 23) against
+     what a gather costs at run time (PERF.md section 6, PR 27),
   5. segment aggregates become roll/subtract arithmetic on the compacted
      lanes; the group count stays a device scalar (no host sync).
 
@@ -53,6 +55,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column, StringColumn
+from spark_rapids_tpu.ops import sortkeys
 
 # Aggregate op names understood by the kernel. ``m2`` is the exact
 # per-group centered second moment sum((x - group_mean)^2) — computed
@@ -239,7 +242,7 @@ def _pack_plan(dtypes, key_ordinals, key_ranges):
 
 # Above this slot count the masked-reduction sweep (total x capacity work
 # per aggregate lane) loses to the sort kernel; below it the sweep wins
-# by a wide margin — it deletes BOTH variadic sorts and every cumsum.
+# by a wide margin — it deletes BOTH sorts and every cumsum.
 _DENSE_MAX_GROUPS = 128
 
 
@@ -272,7 +275,7 @@ def _dense_groupby(cols, dtypes, key_ordinals, aggs, live, layout):
     """Sort-free groupby for tiny host-known key spaces: rows map to a
     packed slot code, and each aggregate is ONE masked reduction over a
     [slots, capacity] broadcast compare that XLA fuses into a single
-    sweep — no variadic sort, no cumsum, and no AOT-segfault chunking
+    sweep — no sort, no cumsum, and no AOT-segfault chunking
     (the >= 7-agg boundary above applies to the fused sort module, which
     this path never builds). The slot axis compacts with an argsort over
     <= 128 elements. Matches the semantics of the sort path exactly:
@@ -412,7 +415,7 @@ def _dense_groupby(cols, dtypes, key_ordinals, aggs, live, layout):
         key_d.append(kd)
         key_v_arr.append(kv)
 
-    order = jnp.argsort(~exists, stable=True)
+    order, _ = sortkeys.stable_order([~exists])
     num_groups = jnp.sum(exists).astype(jnp.int32)
 
     def take(x):
@@ -525,6 +528,8 @@ def _groupby(cols, dtypes, key_ordinals, aggs, num_rows,
         sentinel = lane_dt(total)
         packed = jnp.where(live, pack, sentinel)
         sort_keys = [packed]
+        # an int32 lane holds [0, total]; an int64 lane sorts as two words
+        key_bits = [total.bit_length() if lane_dt is jnp.int32 else None]
     else:
         rank = (~live).astype(jnp.int32)
         for o, has_v in zip(key_ordinals, key_has_v):
@@ -533,38 +538,24 @@ def _groupby(cols, dtypes, key_ordinals, aggs, num_rows,
                 # packed path's reserved 0 slot and Spark's ASC default)
                 rank = (rank << 1) | cols[o][1].astype(jnp.int32)
         sort_keys = [rank]
+        key_bits = [1 + sum(key_has_v)]
         for o in key_ordinals:
             d, v = cols[o]
             lanes = _equality_lanes(d, v, dtypes[o])
             key_lane_slices.append((len(sort_keys), len(lanes)))
             sort_keys.extend(lanes)
+            key_bits.extend([None] * len(lanes))
 
-    # ---- 2. payload lanes: agg-input columns not derivable from keys ------
-    key_set = set(key_ordinals)
-    needed = []
-    for spec in aggs:
-        if spec.ordinal >= 0 and spec.ordinal not in key_set and \
-                spec.ordinal not in needed:
-            needed.append(spec.ordinal)
-    payloads = []
-    for o in needed:
-        d, v = cols[o]
-        payloads.append(d)
-        if v is not None:
-            payloads.append(v)
-
-    out = jax.lax.sort(tuple(sort_keys) + tuple(payloads),
-                       num_keys=len(sort_keys), is_stable=True)
-    s_keys = out[:len(sort_keys)]
-    rest = list(out[len(sort_keys):])
-    sorted_cols = {}
-    for o in needed:
-        d = rest.pop(0)
-        v = rest.pop(0) if cols[o][1] is not None else None
-        sorted_cols[o] = (d, v)
+    # ---- 2. agg-input columns not derivable from keys follow the order ----
+    order, s_keys = sortkeys.stable_order(sort_keys, key_bits)
+    needed = [o for o in dict.fromkeys(spec.ordinal for spec in aggs)
+              if o >= 0 and o not in key_ordinals]
+    datas, vals = sortkeys.take_rows(order, [cols[o][0] for o in needed],
+                                     [cols[o][1] for o in needed])
+    sorted_cols = {o: (d, v) for o, d, v in zip(needed, datas, vals)}
 
     # reconstruct key columns (data, validity) in sorted order from the
-    # sort lanes themselves — key columns never ride as payloads
+    # sorted key lanes themselves: no gather
     if ranges is not None:
         sp = s_keys[0]
         for ki, o in enumerate(key_ordinals):
@@ -636,7 +627,7 @@ def _groupby(cols, dtypes, key_ordinals, aggs, num_rows,
 
 def _segments_tail(sorted_cols, dtypes, key_ordinals, aggs, boundary,
                    live_sorted, num_rows, num_groups, capacity):
-    """Row-space lanes -> ONE compaction sort -> group-space arithmetic.
+    """Row-space lanes -> ONE compaction order -> group-space arithmetic.
     Returns (key_d, key_v_arrays, agg_d, agg_v_arrays) with validity as
     plain bool arrays (the caller maps Nones back)."""
     iota = jnp.arange(capacity, dtype=jnp.int32)
@@ -644,7 +635,7 @@ def _segments_tail(sorted_cols, dtypes, key_ordinals, aggs, boundary,
     # ---- row-space lanes per aggregate
     # each entry: (kind, lanes...) consumed positionally after compaction
     lane_specs = []   # static description
-    lanes = []        # arrays riding the compaction sort
+    lanes = []        # arrays gathered to the group prefix
 
     def add_lane(x):
         lanes.append(x)
@@ -798,12 +789,10 @@ def _segments_tail(sorted_cols, dtypes, key_ordinals, aggs, boundary,
         vi = add_lane(v) if v is not None else None
         key_lane_idx.append((di, vi))
 
-    # ---- ONE compaction sort: boundary rows to a group prefix
-    packed = jax.lax.sort(
-        ((~boundary),) + (iota,) + tuple(lanes), num_keys=1,
-        is_stable=True)
-    first_idx = packed[1]
-    c = list(packed[2:])  # compacted lanes, group g at row g
+    # ---- ONE compaction order: boundary rows to a group prefix
+    first_idx, _ = sortkeys.stable_order([~boundary])
+    c, _ = sortkeys.take_rows(first_idx, lanes, [None] * len(lanes))
+    # group g at row g
 
     giota = iota
     glive = giota < num_groups

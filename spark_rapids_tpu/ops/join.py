@@ -221,10 +221,9 @@ def _build_dense(b_datas, b_vals, b_rows, kmin, key_ord: int,
     # nulls/padding park at table_span: past every probed slot, so they
     # can never enter a run ([start[s], start[s+1]) with s < table_span)
     slot = jnp.where(ok, slot64, jnp.int64(table_span)).astype(jnp.int32)
-    order = jnp.argsort(slot, stable=True)
-    s_slot = jnp.take(slot, order)
-    sb_datas = [jnp.take(d, order) for d in b_datas]
-    sb_vals = [None if v is None else jnp.take(v, order) for v in b_vals]
+    order, (s_slot,) = sortkeys.stable_order(
+        [slot], bits=[table_span.bit_length()])
+    sb_datas, sb_vals = sortkeys.take_rows(order, b_datas, b_vals)
     start = jnp.searchsorted(
         s_slot,
         jnp.arange(table_span + 1, dtype=jnp.int32)).astype(jnp.int32)
@@ -341,10 +340,8 @@ def _sort_build(b_datas, b_vals, h_b, b_rows):
     # stable argsort still orders it first (pads have the highest indices),
     # and the exact-key verification kills any pad candidate pairs.
     h_b_l = jnp.where(live_b, h_b, jnp.iinfo(jnp.int64).max)
-    order = jnp.argsort(h_b_l, stable=True)
-    sb_h = jnp.take(h_b_l, order)
-    sb_datas = [jnp.take(d, order) for d in b_datas]
-    sb_vals = [None if v is None else jnp.take(v, order) for v in b_vals]
+    order, (sb_h,) = sortkeys.stable_order([h_b_l])
+    sb_datas, sb_vals = sortkeys.take_rows(order, b_datas, b_vals)
     return sb_h, sb_datas, sb_vals
 
 
@@ -406,12 +403,25 @@ def _probe_sorted(sb_h, table, h_p, s_rows, use_kernel: bool = False):
     return _hash_probe(sb_h, table, h_p, s_rows, use_kernel)
 
 
+def _prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum (exact: integers), in rows of 1,024: a sum
+    along each row, then the rows' totals. One ``jnp.cumsum`` over
+    1,048,576 int64 values took the chip's compiler 65 s, this takes
+    2 s (a described v5e, PR 27)."""
+    n = x.shape[0]
+    if n <= 1024 or n % 1024:
+        return jnp.cumsum(x)
+    rows = jnp.cumsum(x.reshape(-1, 1024), axis=1)
+    totals = rows[:, -1]
+    return (rows + (jnp.cumsum(totals) - totals)[:, None]).reshape(n)
+
+
 @partial(jax.jit, static_argnames=("key_types", "out_cap"))
 def _expand_verify(lo, hi, counts, total, key_pairs, key_types,
                    out_cap: int):
     """pair k in [0,out_cap): probe row pi[k], build row bi[k], and whether
     the pair is live and exactly key-equal."""
-    offsets = jnp.cumsum(counts)  # inclusive
+    offsets = _prefix_sum(counts)  # inclusive
     k = jnp.arange(out_cap, dtype=jnp.int64)
     pi = jnp.searchsorted(offsets, k, side="right").astype(jnp.int32)
     pi_c = jnp.clip(pi, 0, lo.shape[0] - 1)
@@ -444,17 +454,8 @@ def _emit(stream: ColumnarBatch, build: ColumnarBatch,
         out = compact_batch(stream, keep)
         return out, list(stream_types)
 
-    # matched pairs, compacted (the partition kernel computes the same
-    # stable permutation with one prefix scan instead of a sort network)
-    if nkr.enabled("sort"):
-        from spark_rapids_tpu.native.kernels import sort as nsort
-
-        order = nsort.partition_order(match)
-    else:
-        order = jnp.argsort(~match, stable=True)
-    n_match = jnp.sum(match).astype(jnp.int32)
-    pi_s = jnp.take(pi, order)
-    bi_s = jnp.take(bi, order)
+    # matched pairs, compacted
+    pi_s, bi_s, n_match = _compact_pairs(pi, bi, match)
     pair_live = jnp.arange(out_cap, dtype=jnp.int32) < n_match
 
     cols: List[Column] = []
@@ -573,8 +574,7 @@ def nested_loop_join(stream: ColumnarBatch, build: ColumnarBatch,
                          else Column.all_null(t, pair_cap))
     keep = cond_mask(ColumnarBatch(pair_cols, total))
 
-    pi_s, bi_s, n_match = _compact_pairs(pi, bi, keep & live,
-                                         use_kernel=nkr.enabled("sort"))
+    pi_s, bi_s, n_match = _compact_pairs(pi, bi, keep & live)
     n_match_i = int(jax.device_get(n_match))  # the one host sync
     out_cap = bucket_capacity(max(n_match_i, 1))
     pi_s, bi_s = pi_s[:out_cap], bi_s[:out_cap]
@@ -593,13 +593,8 @@ def _pair_grid(pair_cap: int, n_b, total):
     return pi, bi, k < total
 
 
-@partial(jax.jit, static_argnames=("use_kernel",))
-def _compact_pairs(pi, bi, match, use_kernel: bool = False):
-    if use_kernel:
-        from spark_rapids_tpu.native.kernels import sort as nsort
-
-        order = nsort.partition_order(match)
-    else:
-        order = jnp.argsort(~match, stable=True)
+@jax.jit
+def _compact_pairs(pi, bi, match):
+    order, _ = sortkeys.stable_order([~match])
     return (jnp.take(pi, order), jnp.take(bi, order),
             jnp.sum(match).astype(jnp.int32))

@@ -4,8 +4,10 @@ Replaces the cuDF ``Table.partition``/``contiguousSplit`` pair driven by the
 reference's partitioners (GpuPartitioning.scala:44-70, GpuHashPartitioning,
 GpuRoundRobinPartitioning, GpuRangePartitioning, GpuSinglePartitioning).
 
-The kernel: compute a partition id per row, stable-sort rows by it (one XLA
-sort), and compute per-partition counts with one segment_sum. The sorted
+The kernel: compute a partition id per row, put the rows in the stable order
+of their ids (``sortkeys.stable_order``: one sort of one packed lane, every
+column follows with a gather), and read per-partition counts off the sorted
+ids by binary search. The sorted
 batch plus host-realized offsets is the analogue of a contiguous split —
 each partition is a contiguous row range ready for slicing/serialization.
 Range partitioning samples bounds host-side exactly like the reference
@@ -320,26 +322,22 @@ def _split_by_pid(batch: ColumnarBatch, pid: jax.Array, num_partitions: int
 
 @partial(jax.jit, static_argnames=("num_partitions",))
 def _partition_kernel(datas, validities, pid, num_rows, num_partitions: int):
-    """Contiguous-split by partition id: ONE variadic sort carries every
-    column (no per-column permutation gathers), per-partition counts come
-    from binary searches over the sorted ids (no segment_sum scatter)."""
+    """Contiguous-split by partition id: the id is the one key lane
+    (with the row index it is ONE sort operand up to 2,047 partitions of
+    2,097,152 rows), the columns are gathered into its order, and
+    per-partition counts come from binary searches over the sorted ids
+    (no segment_sum scatter). A sort that carried the columns took the
+    chip's compiler 352 s at 32,768 rows (PR 23; PERF.md section 6)."""
     capacity = pid.shape[0]
     live = jnp.arange(capacity, dtype=jnp.int32) < num_rows
     # padding rows to a virtual partition that sorts last
     pid_l = jnp.where(live, pid, num_partitions)
-    payloads = tuple(datas) + tuple(v for v in validities if v is not None)
-    sorted_all = jax.lax.sort((pid_l,) + payloads, num_keys=1,
-                              is_stable=True)
-    pid_s = sorted_all[0]
+    order, (pid_s,) = sortkeys.stable_order(
+        [pid_l], bits=[num_partitions.bit_length()])
     bounds = jnp.searchsorted(
         pid_s, jnp.arange(num_partitions + 1, dtype=pid_s.dtype))
     counts = (bounds[1:] - bounds[:-1]).astype(jnp.int64)
-    rest = list(sorted_all[1:])
-    out_d = rest[:len(datas)]
-    vrest = rest[len(datas):]
-    out_v = []
-    for v in validities:
-        out_v.append(vrest.pop(0) if v is not None else None)
+    out_d, out_v = sortkeys.take_rows(order, datas, validities)
     return out_d, out_v, counts
 
 

@@ -1,11 +1,11 @@
 """Sort kernels (cuDF ``Table.orderBy`` analogue, GpuSortExec.scala:104).
 
-Payload columns ride THROUGH the variadic sort (``lax.sort`` operands
-past ``num_keys``): the TPU sort network moves key and payload lanes
-together, so no per-column permutation gathers happen afterwards — the
-measured gather cost is ~75-150 ms/column at 4M rows vs a single variadic
-sort pass. ``sort_indices`` keeps the permutation-producing path for
-callers that need the order itself.
+The ORDER BY terms become key lanes (``sortkeys.order_key_arrays``),
+``sortkeys.stable_order`` sorts the lanes and a row index, and every
+column follows with one gather. No column rides the sort: a sort that
+carried this module's payload columns took the chip's compiler 757 s at
+65,536 rows for TPC-H Q3 (PR 23), and minutes more a carried column
+(PERF.md section 6, PR 27).
 """
 from __future__ import annotations
 
@@ -13,77 +13,25 @@ from functools import partial
 from typing import List
 
 import jax
-import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.ops import sortkeys
 from spark_rapids_tpu.ops.sortkeys import SortKeySpec
 
 
-# Above this many payload lanes the variadic sort switches to
-# argsort + per-column gathers: XLA's compile time for a sort network
-# carrying many 64-bit (= emulated 32-bit-pair) operands explodes —
-# measured: TPCx-BB q26's ORDER BY at 131k rows with 9 int64 + 8 bool
-# payload lanes sat in XLA for >20 MINUTES, while the gathers it avoids
-# cost ~75-150 ms/column only at multi-million-row widths.
-_CARRY_MAX_LANES = 6
-
-
-@partial(jax.jit, static_argnames=("dtypes", "specs", "kernel_token"))
-def _sort_carry(datas, validities, dtypes, specs, num_rows,
-                kernel_token=()):
-    # kernel_token: native-kernel gate state — the trace routes through
-    # the radix kernel or lax.sort at trace time, so a knob flip must
-    # miss this cache
-    """One stable variadic sort: [pad_rank, spec keys..., payloads...].
-    Wide payload sets sort an iota lane instead and gather."""
-    payloads = list(datas) + [v for v in validities if v is not None]
-    if len(payloads) > _CARRY_MAX_LANES:
-        cap = datas[0].shape[0] if datas else 0
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        (order,) = sortkeys.sort_with_payloads(
-            list(zip(datas, validities)), list(dtypes), list(specs),
-            num_rows, [iota])
-        out_d = [jnp.take(d, order) for d in datas]
-        out_v = [None if v is None else jnp.take(v, order)
-                 for v in validities]
-        return out_d, out_v
-    out = sortkeys.sort_with_payloads(
-        list(zip(datas, validities)), list(dtypes), list(specs),
-        num_rows, payloads)
-    out_d = list(out[:len(datas)])
-    rest = list(out[len(datas):])
-    out_v = []
-    for v in validities:
-        out_v.append(None if v is None else rest.pop(0))
-    return out_d, out_v
+@partial(jax.jit, static_argnames=("dtypes", "specs"))
+def _sort_batch(datas, validities, dtypes, specs, num_rows):
+    order = sortkeys.lexsort_indices(
+        list(zip(datas, validities)), list(dtypes), list(specs), num_rows)
+    return sortkeys.take_rows(order, datas, validities)
 
 
 def sort_batch(batch: ColumnarBatch, specs: List[SortKeySpec],
                dtypes) -> ColumnarBatch:
     datas = [c.data for c in batch.columns]
     validities = [c.validity for c in batch.columns]
-    from spark_rapids_tpu.native import kernels as nkr
-
-    out_d, out_v = _sort_carry(datas, validities, tuple(dtypes),
-                               tuple(specs), batch.num_rows_device(),
-                               kernel_token=nkr.cache_token())
+    out_d, out_v = _sort_batch(datas, validities, tuple(dtypes),
+                               tuple(specs), batch.num_rows_device())
     out_cols = [c._like(d, v)
                 for c, d, v in zip(batch.columns, out_d, out_v)]
     return ColumnarBatch(out_cols, batch.num_rows)
-
-
-@partial(jax.jit, static_argnames=("dtypes", "specs", "kernel_token"))
-def _sort_indices(cols, dtypes, specs, num_rows, kernel_token=()):
-    return sortkeys.lexsort_indices(list(cols), list(dtypes), list(specs),
-                                    num_rows)
-
-
-def sort_indices(batch: ColumnarBatch, specs: List[SortKeySpec],
-                 dtypes) -> jax.Array:
-    from spark_rapids_tpu.native import kernels as nkr
-
-    cols = [(c.data, c.validity) for c in batch.columns]
-    return _sort_indices(cols, tuple(dtypes), tuple(specs),
-                         batch.num_rows_device(),
-                         kernel_token=nkr.cache_token())

@@ -5,7 +5,8 @@ hand-off (the chain gathers to host, filters, re-shards — exactly the
 round trip the hand-off design removes). Filters are embarrassingly
 parallel: the condition evaluates per chip with the SAME expression
 evaluator the single-device compiled filter uses (expressions/compiler
-EvalContext), then one variadic sort per chip compacts kept rows to the
+EvalContext), then one stable order of the keep flag per chip
+(ops/sortkeys.stable_order) and a gather a column compact kept rows to the
 live prefix (the scatter-free compaction idiom of parallel/shuffle.py).
 No collectives at all — rows never change chips.
 
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from spark_rapids_tpu.columnar import dtypes as dt
+from spark_rapids_tpu.ops import sortkeys
 from spark_rapids_tpu.parallel.mesh import DATA_AXIS
 from spark_rapids_tpu.shims import get_shims
 
@@ -58,13 +60,10 @@ class DistributedFilterStep:
             keep = v.data if v.validity is None else (v.data & v.validity)
             iota = jnp.arange(cap, dtype=jnp.int32)
             keep = keep & (iota < n_rows[0])
-            payload = tuple(datas) + tuple(valids)
-            packed = jax.lax.sort(
-                ((~keep).astype(jnp.int32),) + payload, num_keys=1,
-                is_stable=True)[1:]
+            order, _ = sortkeys.stable_order([~keep])
             new_n = jnp.sum(keep).astype(jnp.int32)
-            out_d = list(packed[:len(datas)])
-            out_v = [vv & (iota < new_n) for vv in packed[len(datas):]]
+            out_d, out_v = sortkeys.take_rows(order, datas, valids)
+            out_v = [vv & (iota < new_n) for vv in out_v]
             return out_d, out_v, new_n.reshape(1)
 
         n_cols = len(self.dtypes)

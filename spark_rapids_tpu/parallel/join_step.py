@@ -24,6 +24,7 @@ from spark_rapids_tpu.shims import get_shims
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_tpu.columnar import dtypes as dt
+from spark_rapids_tpu.ops import sortkeys
 from spark_rapids_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -61,9 +62,9 @@ class DistributedDimJoinStep:
             dvalid = d_valids[dim_key]
             # sort the dim by key (per device, tiny) for binary search;
             # invalid keys to the back
-            order = jnp.lexsort((jnp.arange(dcap), ~dvalid, dkey))
-            dkey_s = jnp.take(dkey, order)
-            dvalid_s = jnp.take(dvalid, order)
+            order, (dkey_s, dinvalid_s) = sortkeys.stable_order(
+                [dkey, ~dvalid])
+            dvalid_s = ~dinvalid_s
             skey = f_datas[fact_key]
             svalid = f_valids[fact_key]
             pos = jnp.searchsorted(
@@ -217,16 +218,11 @@ class DistributedShuffledJoinStep:
             # +inf and carry a usable=False lane so they can never match
             q_use = q_live & ~q_nul
             q_key = jnp.where(q_use, h_q, I64MAX)
-            sorted_b = jax.lax.sort(
-                (q_key,) + tuple(q_imgs) + tuple(ex_b_d) + tuple(ex_b_v) +
-                (q_use,), num_keys=1, is_stable=True)
-            bq_key = sorted_b[0]
-            nq = len(q_imgs)
-            bq_imgs = sorted_b[1:1 + nq]
+            b_order, (bq_key,) = sortkeys.stable_order([q_key])
+            bq_imgs = [jnp.take(q, b_order) for q in q_imgs]
             nb = len(ex_b_d)
-            bq_d = sorted_b[1 + nq:1 + nq + nb]
-            bq_v = sorted_b[1 + nq + nb:1 + nq + 2 * nb]
-            bq_use = sorted_b[-1]
+            bq_d, bq_v = sortkeys.take_rows(b_order, ex_b_d, ex_b_v)
+            bq_use = jnp.take(q_use, b_order)
 
             p_use = p_live & ~p_nul
             lo = jnp.searchsorted(bq_key, h_p, side="left").astype(jnp.int32)
@@ -264,14 +260,11 @@ class DistributedShuffledJoinStep:
                     out_d.append(jnp.take(bq_d[j], first_src))
                     out_v.append(jnp.take(bq_v[j], first_src) & hit &
                                  live_out)
-            # compact live rows to a prefix (scatter-free liveness sort)
+            # compact live rows to a prefix (scatter-free liveness order)
             total = jnp.sum(live_out).astype(jnp.int32)
-            packed = jax.lax.sort(
-                ((~live_out).astype(jnp.int32),) + tuple(out_d) +
-                tuple(out_v), num_keys=1, is_stable=True)[1:]
-            ncols = len(out_d)
-            res_d = list(packed[:ncols])
-            res_v = [v & (p_iota < total) for v in packed[ncols:]]
+            c_order, _ = sortkeys.stable_order([~live_out])
+            res_d, res_v = sortkeys.take_rows(c_order, out_d, out_v)
+            res_v = [v & (p_iota < total) for v in res_v]
             return res_d, res_v, total.reshape(1), dup.reshape(1)
 
         ax = self.axis
@@ -415,15 +408,10 @@ class DistributedExpandJoinStep:
             # clamping [lo, hi) to it makes sentinel collisions
             # impossible — a live key equal to I64MAX can never match a
             # dead row (r3 review finding)
-            use_rank = (~q_use).astype(jnp.int32)
             q_key = jnp.where(q_use, q_img, I64MAX)
-            sorted_b = jax.lax.sort(
-                (use_rank, q_key) + tuple(ex_b_d) + tuple(ex_b_v),
-                num_keys=2, is_stable=True)
-            bq_key = sorted_b[1]
+            b_order, (_, bq_key) = sortkeys.stable_order([~q_use, q_key])
             nb = len(ex_b_d)
-            bq_d = sorted_b[2:2 + nb]
-            bq_v = sorted_b[2 + nb:]
+            bq_d, bq_v = sortkeys.take_rows(b_order, ex_b_d, ex_b_v)
             n_usable = jnp.sum(q_use).astype(jnp.int32)
 
             probe = jnp.where(p_use, p_img, I64MAX)
@@ -440,12 +428,9 @@ class DistributedExpandJoinStep:
                 live_out = (hit if kind == "leftsemi"
                             else p_live & ~hit)
                 total = jnp.sum(live_out).astype(jnp.int32)
-                packed = jax.lax.sort(
-                    ((~live_out).astype(jnp.int32),) + tuple(ex_s_d) +
-                    tuple(ex_s_v), num_keys=1, is_stable=True)[1:]
-                ns = len(ex_s_d)
-                res_d = list(packed[:ns])
-                res_v = [v & (p_iota < total) for v in packed[ns:]]
+                c_order, _ = sortkeys.stable_order([~live_out])
+                res_d, res_v = sortkeys.take_rows(c_order, ex_s_d, ex_s_v)
+                res_v = [v & (p_iota < total) for v in res_v]
                 return (res_d, res_v, total.reshape(1),
                         total.astype(jnp.int64).reshape(1))
 

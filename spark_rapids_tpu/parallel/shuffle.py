@@ -39,7 +39,7 @@ from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column, StringColumn
 from spark_rapids_tpu.ops import groupby as gb
-from spark_rapids_tpu.ops import hashing
+from spark_rapids_tpu.ops import hashing, sortkeys
 from spark_rapids_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -61,19 +61,19 @@ def _exchange(datas: List[jax.Array], valids: List[jax.Array],
     """All-to-all rows by per-row destination device. Returns compacted
     (datas, valids, total_rows) with capacity n_dev * local_capacity.
 
-    Scatter-free: ONE variadic sort carries every column to
-    destination-sorted order (padding to a sentinel bucket), per-dest
+    Scatter-free: the destination is the one sort key
+    (``sortkeys.stable_order``; padding to a sentinel bucket), per-dest
     counts come from binary searches over the sorted destinations, and
-    the (n_dev, cap) send blocks are a plain gather from the contiguous
-    runs — TPU scatters measured ~30x a cumsum, so none appear here."""
+    the (n_dev, cap) send blocks are ONE gather a column through the
+    order, from the contiguous runs — TPU scatters measured ~30x a
+    cumsum, so none appear here. No column rides a sort: with the
+    columns carried this step took the chip's compiler 191 s at 65,536
+    rows a device and q1's DistributedGroupByStep did not compile in
+    3,000 s (PR 23; PERF.md section 6, PR 27)."""
     cap = dest.shape[0]
     dest_l = jnp.where(live, dest, n_dev)  # padding → sentinel bucket
-    payloads = tuple(datas) + tuple(valids)
-    sorted_all = jax.lax.sort((dest_l,) + payloads, num_keys=1,
-                              is_stable=True)
-    dest_s = sorted_all[0]
-    datas_s = sorted_all[1:1 + len(datas)]
-    valids_s = sorted_all[1 + len(datas):]
+    order, (dest_s,) = sortkeys.stable_order([dest_l],
+                                             bits=[n_dev.bit_length()])
 
     bounds = jnp.searchsorted(
         dest_s, jnp.arange(n_dev + 1, dtype=dest_s.dtype)).astype(jnp.int32)
@@ -83,7 +83,8 @@ def _exchange(datas: List[jax.Array], valids: List[jax.Array],
     k = jnp.arange(n_dev * cap, dtype=jnp.int32)
     d_of = k // cap
     j_of = k % cap
-    src = jnp.clip(jnp.take(start, d_of) + j_of, 0, cap - 1)
+    src = jnp.take(order,
+                   jnp.clip(jnp.take(start, d_of) + j_of, 0, cap - 1))
     sel = j_of < jnp.take(counts, d_of)
 
     def to_blocks(x):
@@ -91,25 +92,23 @@ def _exchange(datas: List[jax.Array], valids: List[jax.Array],
         return vals.reshape(n_dev, cap)
 
     recv_d = [jax.lax.all_to_all(to_blocks(d), axis, 0, 0)
-              for d in datas_s]
+              for d in datas]
     recv_v = [jax.lax.all_to_all(to_blocks(v), axis, 0, 0)
-              for v in valids_s]
+              for v in valids]
     counts_recv = jax.lax.all_to_all(
         counts.reshape(n_dev, 1), axis, 0, 0).reshape(n_dev)
 
-    # compact received rows to a live prefix: one more variadic sort
-    # keyed on liveness, carrying every received column
+    # compact received rows to a live prefix: one more stable order,
+    # keyed on liveness
     rcap = n_dev * cap
     riota = jnp.arange(rcap, dtype=jnp.int32)
     live_r = (riota % cap) < jnp.take(counts_recv, riota // cap)
     total = jnp.sum(counts_recv).astype(jnp.int32)
-    flat = tuple(r.reshape(rcap) for r in recv_d) + \
-        tuple(r.reshape(rcap) for r in recv_v)
-    packed = jax.lax.sort(((~live_r).astype(jnp.int32),) + flat,
-                          num_keys=1, is_stable=True)[1:]
-    out_d = list(packed[:len(recv_d)])
-    out_v = [v & (riota < total) for v in packed[len(recv_d):]]
-    return out_d, out_v, total
+    order_r, _ = sortkeys.stable_order([~live_r])
+    out_d, out_v = sortkeys.take_rows(
+        order_r, [r.reshape(rcap) for r in recv_d],
+        [r.reshape(rcap) for r in recv_v])
+    return out_d, [v & (riota < total) for v in out_v], total
 
 
 class DistributedGroupByStep:

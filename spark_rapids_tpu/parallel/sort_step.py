@@ -13,7 +13,8 @@ pipeline is ONE compiled program per chip —
      the samples; all chips derive IDENTICAL quantile bounds,
   3. rows route via lax.all_to_all (parallel/shuffle._exchange),
   4. each chip runs the full lexicographic local sort
-     (ops/sortkeys.sort_with_payloads) on its range.
+     (ops/sortkeys.lexsort_indices, then one gather a column) on its
+     range.
 
 Chip order == global order: concatenating shard prefixes in device order
 yields the sorted relation, with primary-key ties wholly inside one chip
@@ -106,16 +107,12 @@ class DistributedSortStep:
             ex_d, ex_v, total = _exchange(list(datas), list(valids),
                                           dest, live, n_dev, axis)
             # local full lexicographic sort on this chip's range
-            cols = list(zip(ex_d, ex_v))
-            payloads = list(ex_d) + list(ex_v)
-            out = sortkeys.sort_with_payloads(cols, list(dtypes),
-                                              list(specs), total,
-                                              payloads)
-            nc = len(ex_d)
-            out_d = list(out[:nc])
+            order = sortkeys.lexsort_indices(
+                list(zip(ex_d, ex_v)), list(dtypes), list(specs), total)
+            out_d, out_v = sortkeys.take_rows(order, ex_d, ex_v)
             rcap = ex_d[0].shape[0]
             riota = jnp.arange(rcap, dtype=jnp.int32)
-            out_v = [v & (riota < total) for v in out[nc:]]
+            out_v = [v & (riota < total) for v in out_v]
             return out_d, out_v, total.reshape(1)
 
         n_cols = len(dtypes)

@@ -7,9 +7,9 @@ those two stages into ONE compiled program per chip, exactly like the
 distributed groupby (parallel/shuffle.py):
 
   1. hash the PARTITION BY columns -> destination chip per row,
-  2. ``lax.all_to_all`` the rows (scatter-free: one variadic sort into
-     send blocks),
-  3. per chip: one variadic sort by (partition keys, order keys), then
+  2. ``lax.all_to_all`` the rows (scatter-free: one stable order by
+     destination, a gather a column into send blocks),
+  3. per chip: one stable order by (partition keys, order keys), then
      the same segmented-scan ``WindowKernel`` the single-device exec
      runs (execs/window.py) — row_number/rank/lead/lag/frames all ride
      segment arithmetic, so the per-chip math is identical.
@@ -86,13 +86,11 @@ class DistributedWindowStep:
                     % jnp.int64(n_dev)).astype(jnp.int32)
             ex_d, ex_v, total = _exchange(list(datas), list(valids), dest,
                                           live, n_dev, axis)
-            sorted_all = sortkeys.sort_with_payloads(
+            order = sortkeys.lexsort_indices(
                 list(zip(ex_d, ex_v)), list(pre_types), list(sort_specs),
-                total, list(ex_d) + list(ex_v))
-            ncols = len(ex_d)
+                total)
             cols = [Column(t, d, v) for t, d, v in
-                    zip(pre_types, sorted_all[:ncols],
-                        sorted_all[ncols:])]
+                    zip(pre_types, *sortkeys.take_rows(order, ex_d, ex_v))]
             call_cols = kernel(cols, total)
             out_cols = cols[:n_child] + call_cols
             rcap = n_dev * cap

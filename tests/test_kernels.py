@@ -22,8 +22,6 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
 from spark_rapids_tpu.columnar.column import Column, StringColumn
 from spark_rapids_tpu.native import kernels as nk
 from spark_rapids_tpu.ops import join as J
-from spark_rapids_tpu.ops import sort as osort
-from spark_rapids_tpu.ops.sortkeys import SortKeySpec
 
 from tests.compare import assert_frames_equal
 
@@ -43,15 +41,14 @@ def _gates_reset():
 
 def test_gates_default_off_and_conf_routing():
     assert not nk.enabled("join")
-    assert not nk.enabled("sort")
     assert not nk.enabled("strings")
     from spark_rapids_tpu.config import RapidsConf
 
     conf = RapidsConf({"rapids.tpu.native.kernels.enabled": True,
-                       "rapids.tpu.native.kernels.sort": False})
+                       "rapids.tpu.native.kernels.strings": False})
     nk.configure_from_conf(conf)
-    assert nk.enabled("join") and nk.enabled("strings")
-    assert not nk.enabled("sort")      # sub-gate wins under the master
+    assert nk.enabled("join")
+    assert not nk.enabled("strings")   # sub-gate wins under the master
     tok_on = nk.cache_token()
     nk.reset_config()
     assert nk.cache_token() != tok_on  # knob flips must miss jit caches
@@ -167,87 +164,6 @@ def test_probe_table_matches_searchsorted():
                         np.asarray(jax.device_get(h_p)), side="right") -
         np.searchsorted(np.asarray(jax.device_get(sh)),
                         np.asarray(jax.device_get(h_p)), side="left"))
-
-
-# ---------------------------------------------------------------------------
-# segmented sort / partition kernels
-# ---------------------------------------------------------------------------
-
-
-def test_partition_order_matches_stable_argsort():
-    from spark_rapids_tpu.native.kernels import sort as nsort
-
-    nk.configure(enabled=True)
-    r = np.random.default_rng(11)
-    for mask in [r.random(257) > 0.5, np.ones(64, bool),
-                 np.zeros(64, bool), np.array([True])]:
-        m = jnp.asarray(mask)
-        got = np.asarray(jax.device_get(nsort.partition_order(m)))
-        want = np.asarray(jax.device_get(
-            jnp.argsort(~m, stable=True)))
-        np.testing.assert_array_equal(got, want)
-
-
-def _sort_batch(cap, n, seed, float_key=False):
-    r = np.random.default_rng(seed)
-    k1 = r.integers(-50, 50, size=cap).astype(np.int64)
-    v1 = r.random(cap) > 0.2
-    k2 = r.random(cap) if float_key else \
-        r.integers(0, 5, size=cap).astype(np.int32)
-    pay = r.integers(0, 10**6, size=cap).astype(np.int64)
-    cols = [Column(dt.INT64, jnp.asarray(k1), jnp.asarray(v1)),
-            Column(dt.FLOAT64 if float_key else dt.INT32,
-                   jnp.asarray(k2), None),
-            Column(dt.INT64, jnp.asarray(pay), None)]
-    types = [dt.INT64, dt.FLOAT64 if float_key else dt.INT32, dt.INT64]
-    return ColumnarBatch(cols, n), types
-
-
-@pytest.mark.parametrize("float_key", [False, True])
-def test_sort_batch_differential(float_key):
-    """kernel == jnp through ops/sort.sort_batch: composite keys with
-    nulls, asc/desc and NULLS FIRST/LAST; a float key exercises the
-    kernel's fallback (no f64 bitcast on TPU) which must STILL agree."""
-    specs = (SortKeySpec(0, ascending=False, nulls_first=False),
-             SortKeySpec(1, ascending=True, nulls_first=True))
-
-    def run(on):
-        nk.configure(enabled=on)
-        batch, types = _sort_batch(160, 117, seed=5,
-                                   float_key=float_key)
-        out = osort.sort_batch(batch, list(specs), types)
-        return [np.asarray(jax.device_get(c.data))[:117]
-                for c in out.columns] + \
-               [None if c.validity is None else
-                np.asarray(jax.device_get(c.validity))[:117]
-                for c in out.columns]
-
-    for a, b in zip(run(False), run(True)):
-        if a is None:
-            assert b is None
-        else:
-            np.testing.assert_array_equal(a, b)
-
-
-def test_sort_indices_differential():
-    specs = (SortKeySpec(0, ascending=True, nulls_first=False),)
-
-    def run(on):
-        nk.configure(enabled=on)
-        batch, types = _sort_batch(96, 96, seed=9)
-        return np.asarray(jax.device_get(
-            osort.sort_indices(batch, list(specs), types)))
-
-    np.testing.assert_array_equal(run(False), run(True))
-
-
-def test_sort_empty_partition():
-    """Zero live rows: every row is padding; kernel and jnp must agree
-    on the (vacuous) permutation head."""
-    nk.configure(enabled=True)
-    batch, types = _sort_batch(64, 0, seed=13)
-    out = osort.sort_batch(batch, [SortKeySpec(0)], types)
-    assert int(jax.device_get(out.num_rows_device())) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -12,8 +12,12 @@ described only inside a fixture, never at import, in a ``skipif`` or in
 a ``parametrize`` argument; compiles happen in the test's own process
 (only one process may hold the TPU library); the persistent compilation
 cache is off around them (an AOT entry cannot be read back without a
-chip); everything lives in this one file. At most three compiles: each
-x64 sort program costs the chip's compiler tens of seconds.
+chip); everything lives in this one file. Few compiles: since PR 27 a
+sort of one packed lane costs the chip's compiler about 5 s at any
+capacity and a sort of a key word with its index 13-25 s (one of those
+for any number of key lanes), but a segmented group-by at 65,536 rows
+still costs 18 s, and at 2,097,152 rows minutes (its scans, not its
+sorts).
 """
 import os
 
@@ -163,16 +167,17 @@ def test_mesh_exchange_step_compiles_for_four_v5e_chips(
         topo, no_compile_cache):
     """One four-device ``shard_map`` exchange step (the in-program
     all_to_all shuffle of ``parallel/shuffle.py``) over a ``Mesh`` of
-    the described 2x2 topology. Kept at 1,024 rows per device: the
-    chip's compiler takes 2 s for this, 75 s at 8,192 and 191 s at
-    65,536 (measured here, PR 23) — what is checked is that the
-    sharded program and its collective lower and compile at all."""
+    the described 2x2 topology, at 65,536 rows per device: 6.7 s of the
+    chip's compiler since no column rides the exchange's sorts (PR 27;
+    7.1 s at 8,192), where it took 191 s, and 75 s at 8,192 (PR 23) —
+    what is checked is that the sharded program and its collective
+    lower and compile."""
     from spark_rapids_tpu.columnar import dtypes as dt
     from spark_rapids_tpu.parallel.mesh import DATA_AXIS
     from spark_rapids_tpu.parallel.shuffle import (_run_shuffle_step,
                                                    shuffle_step)
 
-    n_dev, cap = 4, 1024
+    n_dev, cap = 4, 65536
     assert len(topo.devices) == n_dev
     mesh = Mesh(np.array(topo.devices), (DATA_AXIS,))
     dtypes = [dt.INT64, dt.FLOAT64]
@@ -190,29 +195,33 @@ def test_mesh_exchange_step_compiles_for_four_v5e_chips(
     assert compiled.memory_analysis().generated_code_size_in_bytes > 0
 
 
-@pytest.mark.parametrize("kernel", ["partition_order", "radix_order"])
-@pytest.mark.xfail(strict=True, reason=(
-    "the default-off Pallas sort kernels have only ever run interpreted: "
-    "the TPU (Mosaic) lowering refuses partition_order (cumsum is "
-    "unimplemented) and radix_order (only 2D gather). A rewrite for "
-    "Mosaic must turn this green; see ROADMAP.md."))
-def test_pallas_sort_kernels_lower_for_v5e(kernel, one_chip,
-                                           no_compile_cache, monkeypatch):
-    """Cheap: the lowering refuses within two seconds, before any
-    compile."""
-    from spark_rapids_tpu.native import kernels as nk
-    from spark_rapids_tpu.native.kernels import sort as ksort
+def test_q3_partition_kernel_compiles_for_v5e_at_sf1_batch_shape(
+        one_chip, no_compile_cache):
+    """TPC-H Q3's widest sort program over the cached tables: the hash
+    exchange's split of one lineitem batch, 15 columns with their
+    validities at 2,097,152 rows into 16 partitions
+    (``ops/partition._partition_kernel``). With the columns carried
+    through the sort this took the chip's compiler 107 s at 65,536 rows
+    and 352 s at 32,768 (PR 23); as one packed sort lane and a gather a
+    column it took 5.8 s in this sandbox, 10.0 s beside two other
+    compiles (PR 27). The limit is three times the latter: a sort that
+    carries a column again, or an index of 64 bits, breaks it."""
+    import time
 
-    # interpretation forced off: this asks the TPU lowering, which the
-    # CPU tests of these kernels never reach
-    monkeypatch.setattr(nk, "_interpret", False)
-    n = 4096
-    if kernel == "partition_order":
-        arg = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
-        fn = ksort.partition_order
-    else:
-        arg = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    from spark_rapids_tpu.ops import partition as part
 
-        def fn(k):
-            return ksort.radix_order([k], [8])
-    jax.jit(fn).lower(arg).compile()
+    cap = 2097152
+    lineitem = [jnp.int64] * 4 + [jnp.float64] * 4 + [jnp.int32] * 7
+
+    def rows(t):
+        return jax.ShapeDtypeStruct((cap,), t, sharding=one_chip)
+
+    t0 = time.perf_counter()
+    compiled = part._partition_kernel.lower(
+        [rows(t) for t in lineitem], [rows(jnp.bool_)] * len(lineitem),
+        rows(jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        num_partitions=16).compile()
+    seconds = time.perf_counter() - t0
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+    assert seconds < 30.0, f"_partition_kernel compiled in {seconds:.1f} s"
