@@ -362,7 +362,12 @@ SHUFFLE_PARTITIONS = conf("rapids.tpu.sql.shuffle.partitions").doc(
     "2 x attached device count. Spark's 200-partition default exists to "
     "feed many cheap CPU tasks; here every partition costs device "
     "dispatches (and, behind a remote attachment, ~100 ms round trips "
-    "each), so fewer, larger partitions win until data exceeds HBM."
+    "each), so fewer, larger partitions win until data exceeds HBM. "
+    "Governs the hash exchanges of joins, windows and the pandas execs, "
+    "the range exchange of a global sort, and an aggregate's exchange in "
+    "a cluster or mesh session; without those an aggregate's partials are "
+    "gathered into one final partition whatever this says (one process "
+    "holds every partition on its device)."
 ).int_conf.create_with_default(0)
 
 

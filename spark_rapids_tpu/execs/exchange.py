@@ -21,6 +21,7 @@ import numpy as np
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.execs.base import TpuExec, timed
 from spark_rapids_tpu.memory import priorities
+from spark_rapids_tpu.memory.catalog import get_catalog
 from spark_rapids_tpu.memory.spillable import SpillableBatch
 from spark_rapids_tpu.ops import partition as part_ops
 from spark_rapids_tpu.ops.concat import concat_batches
@@ -53,7 +54,19 @@ def partition_batch(b: ColumnarBatch, partitioning: Tuple, types,
 
 class ShuffleExchangeExec(TpuExec):
     """partitioning: ('hash', key_ordinals) | ('range', specs) |
-    ('round_robin',) | ('single',)."""
+    ('round_robin',) | ('single',).
+
+    ``('single',)`` is a gather, not a shuffle: every map task's batches
+    become the one output partition's blocks as they are, in partition
+    order (``_gather``; the span ``ShuffleExchangeExec.gather``, one a
+    batch). The planner puts it under a global limit, a global sort or an
+    unpartitioned window over several partitions, and (since PR 28)
+    between the partial and the final aggregate of every session without
+    mesh or cluster: one process then holds all partitions on its device,
+    so routing partials by key would only hash, sort, cut and copy them.
+    The other kinds move rows: partition kernel, one ``_slice_rows``
+    launch a batch, a block a destination (``.partition`` >
+    ``.partitionKernel``, ``.slice``, ``.register``)."""
 
     def __init__(self, partitioning: Tuple, num_out_partitions: int,
                  child: TpuExec, task_threads: int = 1,
@@ -429,6 +442,9 @@ class ShuffleExchangeExec(TpuExec):
                       ) -> Dict[int, List[SpillableBatch]]:
         blocks: Dict[int, List[SpillableBatch]] = into if into is not None \
             else {p: [] for p in range(self.num_out_partitions)}
+        if self.partitioning[0] == "single":
+            self._gather(source, blocks[0])
+            return blocks
         for b in source:
             with TraceRange("ShuffleExchangeExec.partition"):
                 with TraceRange("ShuffleExchangeExec.partitionKernel"):
@@ -444,6 +460,28 @@ class ShuffleExchangeExec(TpuExec):
                         blocks[p].append(SpillableBatch(
                             sub, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY))
         return blocks
+
+    @staticmethod
+    def _gather(source, block: List[SpillableBatch]) -> None:
+        """``("single",)`` moves no rows: the batch a map task is handed
+        IS its block, with no partition kernel, no slice, no launch and
+        no transfer. Who owns the arrays: a batch fresh from its producer
+        (an aggregate's partials, a limit's cut, a projection's output)
+        is handed over, and the block's registration is its only one. A
+        batch that a catalog entry already owns (a ``CachedExec``'s or an
+        upstream exchange's block, pulled straight through by a global
+        sort or limit) stays its owner's to spill and to close: a second
+        registration would count its bytes twice and spill it as if that
+        freed memory, so such a batch keeps the copy that every batch
+        got before (one ``_slice_rows`` launch), and the copy is the
+        block's alone."""
+        catalog = get_catalog()
+        for b in source:
+            with TraceRange("ShuffleExchangeExec.gather"):
+                if catalog.owns(b):
+                    b = b.slice(0, b.realized_num_rows())
+                block.append(SpillableBatch(
+                    b, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY, catalog))
 
     def map_output_sizes(self) -> List[int]:
         """Per-reduce-partition byte sizes of the materialized map output

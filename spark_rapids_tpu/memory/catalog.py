@@ -231,6 +231,10 @@ class BufferCatalog:
         # victim selection instead of full scans (HashedPriorityQueue.java
         # analogue). Entries are queued only while refcount == 0.
         self._queues = {t: HashedPriorityQueue() for t in StorageTier}
+        # id(device batch) -> its entry, while the entry holds it on the
+        # device: what ``owns`` asks (the entry keeps the batch alive, so
+        # the id is its own for as long as it is a key here)
+        self._by_device_batch: Dict[int, _Entry] = {}
         # owner tag -> live entries: the query service biases/removes a
         # query's buffers once per stage slice, which must not scan the
         # whole catalog
@@ -254,6 +258,7 @@ class BufferCatalog:
             e = _Entry(bid, priority, batch, size, next(self._seq),
                        owner=current_buffer_owner())
             self._entries[bid] = e
+            self._by_device_batch[id(batch)] = e
             if e.owner is not None:
                 self._owners.setdefault(e.owner, set()).add(e)
                 e.bias = self._owner_bias.get(e.owner, 0)
@@ -292,7 +297,7 @@ class BufferCatalog:
             assert e.refcount >= 0
             if e.pending_remove and e.refcount == 0:
                 self._entries.pop(buffer_id, None)
-                self._drop_owner_index(e)
+                self._drop_indexes(e)
                 self._drop_tier_bytes(e)
                 path = e.disk_path
             elif e.refcount == 0:
@@ -313,7 +318,7 @@ class BufferCatalog:
                 e.pending_remove = True
                 return
             self._entries.pop(buffer_id, None)
-            self._drop_owner_index(e)
+            self._drop_indexes(e)
             self._queues[e.tier].remove(e)
             self._drop_tier_bytes(e)
             path = e.disk_path
@@ -330,14 +335,23 @@ class BufferCatalog:
 
     # -- per-owner control (query service hooks) --------------------------
 
-    def _drop_owner_index(self, e: "_Entry") -> None:
+    def _drop_indexes(self, e: "_Entry") -> None:
         """Called under lock when an entry leaves ``_entries``."""
+        self._unindex_device_batch(e)
         if e.owner is not None:
             peers = self._owners.get(e.owner)
             if peers is not None:
                 peers.discard(e)
                 if not peers:
                     self._owners.pop(e.owner, None)
+
+    def _unindex_device_batch(self, e: "_Entry") -> None:
+        """Called under lock before ``e`` lets go of its device batch. Two
+        entries may hold one batch (a cache filled from an exchange's
+        blocks): the key is the later one's, and only it takes it away."""
+        b = e.device_batch
+        if b is not None and self._by_device_batch.get(id(b)) is e:
+            del self._by_device_batch[id(b)]
 
     def set_owner_bias(self, owner, bias: int) -> int:
         """Re-bias the spill priority of every buffer registered under
@@ -392,6 +406,13 @@ class BufferCatalog:
     def size_of(self, buffer_id: int) -> int:
         with self._lock:
             return self._entries[buffer_id].size
+
+    def owns(self, batch: ColumnarBatch) -> bool:
+        """Whether ``batch`` (by identity) is the device batch of an
+        entry: its bytes are counted already, and its owner may spill or
+        remove it."""
+        with self._lock:
+            return id(batch) in self._by_device_batch
 
     @property
     def device_bytes(self) -> int:
@@ -518,6 +539,7 @@ class BufferCatalog:
                     e.tier is not StorageTier.DEVICE or e.refcount > 0:
                 return 0  # raced with remove/acquire
             e.host_batch = hb
+            self._unindex_device_batch(e)
             e.device_batch = None
             e.tier = StorageTier.HOST
             self._device_bytes -= e.size
@@ -588,6 +610,7 @@ class BufferCatalog:
                 if e.tier is StorageTier.HOST:
                     self._host_bytes -= e.size
                 e.device_batch = batch
+                self._by_device_batch[id(batch)] = e
                 e.host_batch = None
                 e.tier = StorageTier.DEVICE
                 self._device_bytes += e.size

@@ -483,6 +483,16 @@ class _AggregateRule(NodeRule):
         return child, None
 
     def convert(self, meta, children):
+        """One exec over one partition, ``MeshGroupByExec`` where the
+        mesh takes the boundary, else partial aggregate, exchange, final
+        aggregate. That exchange is by key hash only where the partials
+        live apart or the mesh arms it (cluster mode, the mesh conf).
+        Everywhere else one process holds every partition on its device,
+        where hashing, cutting and copying partials buys nothing: the
+        exchange is a gather (``("single",)``, one final partition), as
+        an unkeyed aggregate's always was, and what is planned above the
+        final aggregate sees one partition. ``shuffle.partitions`` has no
+        say here; it governs joins, windows and ``repartition()``."""
         node: pn.AggregateNode = meta.node
         child = children[0]
         out_schema = node.output_schema()
@@ -521,7 +531,11 @@ class _AggregateRule(NodeRule):
             node.grouping, node.aggs, child, partial_schema,
             mode="partial", conf=meta.conf, fused_filter=ff)
         nkeys = len(node.grouping)
-        if nkeys:
+        if nkeys and (_cluster_mode(meta.conf) or
+                      meta.conf.get(cfg.MESH_ENABLED)):
+            # the partials live apart (a cluster's workers read
+            # co-partitioned blocks) or the mesh arms this boundary
+            # (_enable_in_program_exchanges): exchange by key
             ex = _adaptive_read(exchange.ShuffleExchangeExec(
                 ("hash", list(range(nkeys))),
                 min(cfg.resolve_shuffle_partitions(meta.conf),
@@ -530,6 +544,7 @@ class _AggregateRule(NodeRule):
                 task_threads=meta.conf.get(cfg.TASK_THREADS)),
                 meta.conf)
         else:
+            # one process holds every partition on its device: a gather
             ex = exchange.ShuffleExchangeExec(
                 ("single",), 1, partial,
                 task_threads=meta.conf.get(cfg.TASK_THREADS))

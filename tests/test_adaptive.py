@@ -117,19 +117,34 @@ def _find(exec_, klass):
     return out
 
 
+def _window_plan(scan):
+    """A hash exchange by ``k`` under one reader. (Since PR 28 a keyed
+    aggregate without mesh or cluster plans a gather and no reader:
+    tests/test_agg_gather.py; a PARTITION BY window still exchanges by
+    hash.)"""
+    from spark_rapids_tpu.ops.sortkeys import SortKeySpec
+
+    return pn.WindowNode(
+        [0], [SortKeySpec.spark_default(1)],
+        [pn.WindowCall(A.Sum(BoundReference(1, dt.FLOAT64)), "sv"),
+         pn.WindowCall("row_number", "rn")], scan)
+
+
 def test_adaptive_agg_coalesces_and_matches(multifile_scan):
-    plan = _agg_plan(multifile_scan)
+    plan = _agg_plan(_window_plan(multifile_scan))
     conf = RapidsConf({"rapids.tpu.sql.test.enabled": True})
     exec_ = assert_cpu_and_tpu_equal(plan, conf=conf, approx_float=1e-6)
     readers = _find(exec_, AdaptiveShuffleReaderExec)
-    assert readers, "adaptive reader must wrap the hash exchange"
+    assert len(readers) == 1, \
+        "adaptive reader must wrap the window's hash exchange, and only it"
     r = readers[0]
+    assert r.exchange.partitioning[0] == "hash"
     # tiny data -> far fewer coalesced groups than shuffle partitions
     assert r.num_partitions < r.exchange.num_out_partitions
 
 
 def test_adaptive_disabled_no_reader(multifile_scan):
-    plan = _agg_plan(multifile_scan)
+    plan = _agg_plan(_window_plan(multifile_scan))
     conf = RapidsConf({"rapids.tpu.sql.adaptive.enabled": False})
     exec_ = apply_overrides(plan, conf)
     assert not _find(exec_, AdaptiveShuffleReaderExec)

@@ -122,6 +122,35 @@ rec["q1_delta"] = disp.delta(pre)
 rec["q1_stages"] = disp.stage_delta(stages)
 rec["q1_tree"] = q1.last_profile()
 rec["q1_metrics"] = q1.last_metrics()
+
+# -- the same aggregate over a hash repartition by its key: the exchange
+#    that still partitions, slices and registers
+by_hash = (base.repartition(2, "k").group_by("k")
+               .agg(F.sum(col("v")).alias("sv")))
+by_hash.collect()
+by_hash.collect()
+rec["hash_tree"] = by_hash.last_profile()
+
+# -- TPC-H q1 and q6 through Session.sql over lineitem cached in 2, 4 and
+#    8 partitions: the gather's launch fence
+import tempfile
+from spark_rapids_tpu.benchmarks import datagen
+tmp = tempfile.mkdtemp()
+datagen.write_tables(tmp, 0.002, tables=["lineitem"])
+rec["tpch"] = {}
+for parts in (2, 4, 8):
+    s = Session()
+    s.read.parquet(tmp + "/lineitem").repartition(parts).cache() \
+        .create_or_replace_temp_view("lineitem")
+    for name in ("q1", "q6"):
+        text = open(__ROOT__ + "/benchmark/queries/" + name + ".sql").read()
+        s.sql(text).collect()
+        df = s.sql(text)
+        pre = disp.snapshot()
+        df.collect()
+        rec["tpch"]["%s.%d" % (name, parts)] = {
+            "delta": disp.delta(pre), "tree": df.last_profile()}
+    s.stop()
 print(json.dumps(rec))
 """
 
@@ -172,6 +201,12 @@ def _walk(node):
 
 def _named(tree, name):
     return [n for n in _walk(tree) if n["name"] == name]
+
+
+#: what an exchange that moves rows leaves in a tree; a gather none of them
+_MOVING_SPANS = ("ShuffleExchangeExec.partition",
+                 "ShuffleExchangeExec.partitionKernel",
+                 "ShuffleExchangeExec.slice", "ShuffleExchangeExec.register")
 
 
 # -- off: the pytest process never ran dispatch.install() -------------------
@@ -278,11 +313,16 @@ def test_q1_has_one_root_with_plan_and_fetch(on):
     (plan,) = _named(tree, "plan.physical")
     assert [c["name"] for c in plan["children"]] == [
         "plan.optimize", "plan.tag", "plan.convert", "plan.stages"]
-    for name in ("CachedExec.acquire", "ShuffleExchangeExec.partitionKernel",
-                 "ShuffleExchangeExec.slice", "ShuffleExchangeExec.register",
+    for name in ("CachedExec.acquire", "ShuffleExchangeExec.gather",
                  "collect.concat", "launch.jit", "launch.eager",
                  "launch.device_get"):
         assert _named(tree, name), name
+    # the spans of an exchange that moves rows: under the same aggregate
+    # over a hash repartition, and nowhere under the gather's tree
+    for name in _MOVING_SPANS:
+        assert _named(on["hash_tree"], name), name
+        assert not _named(tree, name), name
+    assert _named(on["hash_tree"], "ShuffleExchangeExec.gather")
     # the root's own time, what no child covers, is a small part of it
     assert tree["self_ns"] < 0.1 * (tree["end_ns"] - tree["start_ns"])
 
@@ -291,18 +331,41 @@ def test_slicing_an_exchanged_batch_is_one_launch(on):
     """The launch fence: a 2-partition batch of q1's partial aggregate
     costs no eager primitive and at most 2 compiled programs (one when
     both partitions share a capacity), in the hand-made call and under
-    every ``ShuffleExchangeExec.slice`` of the q1-shaped query."""
+    every ``ShuffleExchangeExec.slice`` of the query that repartitions by
+    hash (an aggregate's own exchange is a gather: no slice at all)."""
     d = on["slice"]
     assert d["rows"] == [1, 3]
     assert d["eager_op_calls"] == 0 and d["transfers"] == 0
     assert 1 <= d["jit_calls"] <= 2
     assert "launch.eager" not in d["spans"]
     assert d["spans"]["launch.jit"]["count"] == d["jit_calls"]
-    slices = _named(on["q1_tree"], "ShuffleExchangeExec.slice")
+    slices = _named(on["hash_tree"], "ShuffleExchangeExec.slice")
     assert slices
     for node in slices:
         kids = {c["name"]: c["count"] for c in node["children"]}
         assert set(kids) == {"launch.jit"} and kids["launch.jit"] <= 2, kids
+
+
+@pytest.mark.parametrize("parts", [2, 4, 8])
+@pytest.mark.parametrize("stmt,per_partition,per_query",
+                         [("q1", 5, 5), ("q6", 6, 14)])
+def test_gather_launch_fence(on, stmt, per_partition, per_query, parts):
+    """TPC-H q1 and q6 over ``parts`` cached partitions: no span of an
+    exchange that moves rows, one ``ShuffleExchangeExec.gather`` a partition
+    with no launch beneath it, and launches a query within the law PR 28
+    measured (q1 15, 25, 45; q6 26, 38, 62)."""
+    run = on["tpch"]["%s.%d" % (stmt, parts)]
+    tree, d = run["tree"], run["delta"]
+    for name in _MOVING_SPANS + ("AdaptiveShuffleReaderExec.next",):
+        assert not _named(tree, name), name
+        assert name not in d["spans"], name
+    gathers = _named(tree, "ShuffleExchangeExec.gather")
+    assert len(gathers) == parts
+    assert d["spans"]["ShuffleExchangeExec.gather"]["count"] == parts
+    for g in gathers:
+        assert g["children"] == [], g["children"]
+    assert d["dispatch_count"] <= per_partition * parts + per_query, d
+    assert d["queries"] == 1
 
 
 def test_q1_table_is_the_tree(on):
