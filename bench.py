@@ -241,7 +241,6 @@ def _scale_main():
     budget = arg("--device-budget", 0, int)
     iters = arg("--iterations", 2, int)
     skew = arg("--skew", 0.0, float)
-    kernels = "--kernels" in sys.argv
 
     def _conf_value(v: str):
         if v.lower() in ("true", "false"):
@@ -261,7 +260,7 @@ def _scale_main():
             k, _, v = sys.argv[i + 1].partition("=")
             overrides[k] = _conf_value(v)
     conf = None
-    if budget or kernels or overrides:
+    if budget or overrides:
         from spark_rapids_tpu import config as cfg
         from spark_rapids_tpu.config import RapidsConf
         from spark_rapids_tpu.runtime import device as rt
@@ -269,13 +268,9 @@ def _scale_main():
         conf_d = dict(overrides)
         if budget:
             conf_d[cfg.DEVICE_BUDGET.key] = budget
-        if kernels:
-            # native Pallas kernel gates are process-wide (same
-            # contract as memory/retry): initialize applies them
-            conf_d[cfg.NATIVE_KERNELS_ENABLED.key] = True
         conf = RapidsConf(conf_d)
-        if budget or kernels:
-            rt.initialize(conf)  # budgeted spill catalog + kernel gates
+        if budget:
+            rt.initialize(conf)  # budgeted spill catalog
     full = bench_full_query(benchmark, sf=sf,
                             warmup_service="--no-warmup" not in sys.argv,
                             conf=conf, iterations=iters,

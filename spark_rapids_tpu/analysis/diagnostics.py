@@ -25,9 +25,8 @@ CODES = {
               "function that never quantizes through ops/buckets",
     "TPU203": "jnp scalar/array literal without an explicit dtype "
               "(weak-type promotion drifts program signatures)",
-    "TPU204": "pallas_call outside the native/kernels registry wrapper "
-              "(bypasses the interpret-mode gate: dead code on CPU CI "
-              "or a crash off-TPU)",
+    "TPU204": "pallas_call (the package keeps no Pallas kernel: none "
+              "has compiled for the v5e)",
     # -- TPU3xx: concurrency --------------------------------------------
     "TPU301": "lock acquisition order inverts the declared hierarchy "
               "(utils/lockorder.py)",

@@ -19,18 +19,17 @@ program, so the hazards are flagged statically:
   no ``dtype``: weak-type promotion makes the operand's signature
   depend on surrounding arithmetic, so structurally identical programs
   stop sharing executables (x64 drift doubles the damage).
-- TPU204 ``pallas_call`` not routed through the
-  ``native/kernels`` registry wrapper: the registry pins
-  ``interpret=True`` off-TPU so CPU CI executes the same kernel bodies
-  that compile for TPU. A direct ``pl.pallas_call`` site either
-  dead-codes its CPU leg or crashes on a non-TPU backend — and its
-  interpret decision can drift from the process-wide policy.
+- TPU204 any ``pallas_call``: the package has one kernel layer, the
+  jitted jnp programs of ``ops/``, which is what the chip ran. The
+  hand-written Pallas layer only ever ran through the interpreter on a
+  CPU, the v5e's Mosaic lowering refused its kernels (PR 23), and it
+  was deleted (PR 29). A kernel that comes back arrives with a
+  compile case for the chip and a benchmark cell it wins.
 """
 from __future__ import annotations
 
 import ast
-import os
-from typing import List, Set, Tuple
+from typing import List, Set
 
 from spark_rapids_tpu.analysis import astutil
 from spark_rapids_tpu.analysis.diagnostics import Finding
@@ -40,36 +39,6 @@ _CONSTRUCTORS = {"jnp.zeros", "jnp.ones", "jnp.full", "jnp.empty",
                  "jax.numpy.empty"}
 _LITERAL_WRAP = {"jnp.asarray", "jnp.array",
                  "jax.numpy.asarray", "jax.numpy.array"}
-
-#: the one module allowed to touch pl.pallas_call directly (it IS the
-#: interpret-mode gate); everything else must call its wrapper
-_KERNEL_REGISTRY_MOD = "spark_rapids_tpu.native.kernels"
-_KERNEL_REGISTRY_FILE = os.path.join(
-    "spark_rapids_tpu", "native", "kernels", "__init__.py")
-
-
-def _registry_aliases(tree: ast.Module) -> Tuple[Set[str], Set[str]]:
-    """(module aliases, function aliases) this module binds to the
-    native-kernel registry / its ``pallas_call`` wrapper — receivers a
-    ``pallas_call`` site may legitimately go through."""
-    mods: Set[str] = set()
-    fns: Set[str] = set()
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Import):
-            for a in node.names:
-                if a.name == _KERNEL_REGISTRY_MOD and a.asname:
-                    mods.add(a.asname)
-        elif isinstance(node, ast.ImportFrom):
-            if node.module == "spark_rapids_tpu.native":
-                for a in node.names:
-                    if a.name == "kernels":
-                        mods.add(a.asname or "kernels")
-            elif node.module == _KERNEL_REGISTRY_MOD:
-                for a in node.names:
-                    if a.name == "pallas_call":
-                        fns.add(a.asname or "pallas_call")
-    return mods, fns
-
 
 def _decorator_nodes(tree: ast.Module) -> Set[int]:
     """ids of every node inside a decorator list: ``@partial(jax.jit,
@@ -100,9 +69,6 @@ def run(root: str) -> List[Finding]:
 
     for rel, tree, _src in astutil.iter_modules(root):
         in_decorator = _decorator_nodes(tree)
-        is_registry = rel.replace(os.sep, "/").endswith(
-            "spark_rapids_tpu/native/kernels/__init__.py")
-        registry_mods, registry_fns = _registry_aliases(tree)
         functions = astutil.collect_functions(tree)
         # functions that (transitively locally) reach bucket_capacity
         quantizers = {
@@ -145,22 +111,14 @@ def run(root: str) -> List[Finding]:
                         f"{name}({node.args[0].value!r}) without dtype "
                         f"is weakly typed; the promoted signature "
                         f"drifts with surrounding arithmetic")
-                elif name and not is_registry and \
-                        (name == "pallas_call" or
-                         name.endswith(".pallas_call")):
-                    receiver = name.rsplit(".", 1)[0] if "." in name \
-                        else None
-                    sanctioned = (
-                        receiver in registry_mods or
-                        receiver == _KERNEL_REGISTRY_MOD or
-                        (receiver is None and name in registry_fns))
-                    if not sanctioned:
-                        self._emit(
-                            "TPU204", node,
-                            f"{name} bypasses the native/kernels "
-                            f"registry wrapper — its interpret-mode "
-                            f"gate is what keeps the kernel body live "
-                            f"(and correct) on non-TPU backends")
+                elif name and (name == "pallas_call" or
+                               name.endswith(".pallas_call")):
+                    self._emit(
+                        "TPU204", node,
+                        f"{name}: the package keeps no Pallas kernel — "
+                        f"none has compiled for the v5e; bring one "
+                        f"back with a chip compile case and a "
+                        f"benchmark cell it wins")
                 self.generic_visit(node)
 
         V().visit(tree)

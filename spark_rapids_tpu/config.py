@@ -174,14 +174,6 @@ INCOMPATIBLE_OPS = conf("rapids.tpu.sql.incompatibleOps.enabled").doc(
     "from Spark CPU semantics."
 ).boolean_conf.create_with_default(False)
 
-HAS_NANS = conf("rapids.tpu.sql.hasNans").doc(
-    "Assume floating point data may contain NaNs (affects agg/join planning)."
-).boolean_conf.create_with_default(True)
-
-VARIABLE_FLOAT_AGG = conf("rapids.tpu.sql.variableFloatAgg.enabled").doc(
-    "Allow float aggregations whose result may vary with evaluation order."
-).boolean_conf.create_with_default(False)
-
 CONCURRENT_TPU_TASKS = conf("rapids.tpu.sql.concurrentTpuTasks").doc(
     "Number of tasks that can execute concurrently per TPU chip "
     "(admission control; GpuSemaphore analogue, RapidsConf.scala:340)."
@@ -343,10 +335,6 @@ FAULT_INJECTION_MAX = conf(
     "terminate."
 ).int_conf.create_with_default(0)
 
-MEMORY_DEBUG = conf("rapids.tpu.memory.debug").doc(
-    "Log every allocation/free (RMM debug-mode analogue, RapidsConf.scala:277)."
-).boolean_conf.create_with_default(False)
-
 DEBUG_LOCK_ORDER = conf("rapids.tpu.debug.lockOrder.enabled").doc(
     "Wrap every framework lock in a tracking proxy that asserts the "
     "declared hierarchy (utils/lockorder.py) on each acquire. Read at "
@@ -478,52 +466,6 @@ FUSION_IN_PROGRAM_BUILD = conf(
     "output — a duplicate-keyed build discards that output and falls "
     "back to the unfused join, exactly like the host path. Disable to "
     "restore the standalone host-side prepare_builds launch."
-).boolean_conf.create_with_default(True)
-
-NATIVE_KERNELS_ENABLED = conf("rapids.tpu.native.kernels.enabled").doc(
-    "Master switch for the native Pallas kernel layer "
-    "(spark_rapids_tpu/native/kernels): hand-written device kernels "
-    "replacing the jnp graphs where XLA's lowering is the measured "
-    "floor — the open-addressing hash-join probe and the "
-    "dictionary-string predicate "
-    "kernels. INTERPRETER ONLY: the kernels have only ever run through "
-    "Pallas interpret mode on CPU backends, and the TPU (Mosaic) "
-    "lowering refuses data-dependent whole-ref gathers of their make "
-    "(seen with the sort kernels, PR 23, deleted in PR 27), so "
-    "enabling this on a chip fails at compile time. Off by default: the jnp implementations are the "
-    "reference semantics and every kernel is differentially fenced "
-    "against them on the CPU."
-).boolean_conf.create_with_default(False)
-
-NATIVE_KERNELS_JOIN = conf("rapids.tpu.native.kernels.join").doc(
-    "Route equi-join probes through the native open-addressing hash "
-    "table kernel: the build side becomes a device-resident bucketed "
-    "table (built once, probed across every stream batch) and the "
-    "probe is one gather-scan kernel — replacing both the dense "
-    "inverse-table and the hash+searchsorted probe dichotomy. "
-    "Requires rapids.tpu.native.kernels.enabled."
-).boolean_conf.create_with_default(True)
-
-NATIVE_KERNELS_STRINGS = conf("rapids.tpu.native.kernels.strings").doc(
-    "Evaluate dictionary-string predicates (LIKE / contains / "
-    "startswith / endswith / substring) with the native char-table "
-    "kernels: the dictionary's code+offset char matrix is scanned on "
-    "device instead of transforming every dictionary entry through a "
-    "host Python loop. Patterns outside the kernel's LIKE subset "
-    "(custom ESCAPE) fall back to the host path automatically. "
-    "Requires rapids.tpu.native.kernels.enabled."
-).boolean_conf.create_with_default(True)
-
-GROUPBY_SINGLE_PASS = conf(
-    "rapids.tpu.sql.groupby.singlePass.enabled").doc(
-    "Emit wide group-bys (more than 6 aggregate columns) as ONE "
-    "segmented-aggregation launch instead of the chunked two-dispatch "
-    "loop. The chunk loop was a workaround for an older libtpu's "
-    "compile crash on >= 7-agg fused sort modules at capacity >= "
-    "32768; with libtpu 0.0.34 the single pass compiles and runs on "
-    "the v5e (TPC-H q1 at sf 1, PR 23). The "
-    "compact-wide pre-pass (_COMPACT_WIDE_MIN_CAP) applies to both "
-    "paths unchanged."
 ).boolean_conf.create_with_default(True)
 
 CLUSTER_ENABLED = conf("rapids.tpu.cluster.enabled").doc(
@@ -799,11 +741,6 @@ SHUFFLE_COMPRESSION_CODEC = conf("rapids.tpu.shuffle.compression.codec").doc(
     "codec; the nvcomp-LZ4 analogue, RapidsConf.scala:685) or zlib."
 ).string_conf.create_with_default("lz4")
 
-SHUFFLE_MAX_INFLIGHT = conf(
-    "rapids.tpu.shuffle.transport.maxReceiveInflightBytes").doc(
-    "Inflight-bytes throttle for shuffle fetches (RapidsConf.scala:603-685)."
-).bytes_conf.create_with_default(1 << 30)
-
 SHUFFLE_RETRY_JITTER_MS = conf(
     "rapids.tpu.shuffle.retry.jitterMs").doc(
     "Uniform random jitter (0..jitterMs) added to each transport "
@@ -845,23 +782,6 @@ CAST_STRING_TO_TIMESTAMP = conf(
     "rapids.tpu.sql.castStringToTimestamp.enabled").doc(
     "Enable string->timestamp cast."
 ).boolean_conf.create_with_default(False)
-
-ENABLE_REPLACE_SORT_MERGE_JOIN = conf(
-    "rapids.tpu.sql.replaceSortMergeJoin.enabled").doc(
-    "Replace sort-merge joins with TPU hash joins (RapidsConf.scala:439). "
-    "On TPU the join itself is sort-based, so this controls removing the "
-    "upstream CPU sorts."
-).boolean_conf.create_with_default(True)
-
-IMPROVED_FLOAT_OPS = conf("rapids.tpu.sql.improvedFloatOps.enabled").doc(
-    "Enable float ops that use TPU transcendental approximations."
-).boolean_conf.create_with_default(False)
-
-MAX_CAPACITY_BUCKETS = conf("rapids.tpu.sql.shape.bucketWaste").doc(
-    "Capacity bucketing growth factor numerator/denominator packed as "
-    "percent waste allowed; buckets bound XLA recompilation (TPU-specific; "
-    "the reference never needed this because cuDF allocates dynamically)."
-).int_conf.create_with_default(100)
 
 MULTIFILE_READ_THREADS = conf("rapids.tpu.sql.multiFile.numThreads").doc(
     "Thread pool size for multi-file reads "

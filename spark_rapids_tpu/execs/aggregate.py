@@ -64,7 +64,6 @@ class HashAggregateExec(TpuExec):
         # swap it for a shuffle-read stub (runtime/cluster.py), and the
         # pickled exec must carry the already-resolved flag
         self._dense_ok()
-        self._single_pass()
         self._build()
 
     def _build(self):
@@ -180,21 +179,6 @@ class HashAggregateExec(TpuExec):
             self._dense_ok_cached = ok
         return ok
 
-    def _single_pass(self) -> bool:
-        """Wide aggregates launch as ONE segmented pass (default) vs the
-        chunked two-launch AOT workaround loop — see ops/groupby.py's
-        _AOT_MAX_AGGS note. Resolved once and cached on the exec so a
-        cluster-shipped pickle keeps the submitting session's choice."""
-        sp = getattr(self, "_single_pass_cached", None)
-        if sp is None:
-            from spark_rapids_tpu import config as cfg
-
-            sp = bool(self.conf.get(cfg.GROUPBY_SINGLE_PASS)
-                      if self.conf is not None
-                      else cfg.GROUPBY_SINGLE_PASS.default)
-            self._single_pass_cached = sp
-        return sp
-
     def _agg_batch(self, batch: ColumnarBatch, specs: List[AggSpec],
                    types: List[dt.DType], live_mask=None,
                    site: str = "aggregate.update") -> ColumnarBatch:
@@ -214,8 +198,7 @@ class HashAggregateExec(TpuExec):
                 return reduce_aggregate(b, specs, types, m)[0]
             return groupby_aggregate(b, list(range(nkeys)), specs,
                                      types, m,
-                                     dense_ok=self._dense_ok(),
-                                     single_pass=self._single_pass())[0]
+                                     dense_ok=self._dense_ok())[0]
 
         def split(item):
             b, m = item
@@ -299,20 +282,22 @@ class HashAggregateExec(TpuExec):
             b = self.input_proj(b)
         return b, mask
 
-    # above this capacity a WIDE (chunked) sort-path aggregate over a
-    # filtered batch first compacts the survivors: the 2^23-capacity
-    # 9-agg chunked groupby shape costs a multi-ten-minute remote XLA
-    # compile (TPCx-BB q26 @ sf 1), while compact + count-sync +
-    # re-bucket turns it into an already-cached small-capacity shape.
-    # Dense-eligible aggregates skip this (no sort module to blow up).
+    # from this capacity a WIDE (seven aggregate columns or more)
+    # sort-path aggregate over a filtered batch first compacts the
+    # survivors: the 2^23-capacity 9-agg groupby shape costs a
+    # multi-ten-minute remote XLA compile (TPCx-BB q26 @ sf 1), while
+    # compact + count-sync + re-bucket turns it into an already-cached
+    # small-capacity shape. Dense-eligible aggregates skip this (no
+    # sort module to blow up).
     _COMPACT_WIDE_MIN_CAP = 1 << 22
+    _COMPACT_WIDE_MIN_AGGS = 7
 
     def _maybe_compact_wide(self, b: ColumnarBatch, mask):
         from spark_rapids_tpu.ops import filter as filt
         from spark_rapids_tpu.ops import groupby as gb
 
         if mask is None or b.capacity < self._COMPACT_WIDE_MIN_CAP or \
-                len(self.first_specs) <= gb._AOT_MAX_AGGS or \
+                len(self.first_specs) < self._COMPACT_WIDE_MIN_AGGS or \
                 not self.grouping:
             return b, mask
         key_ords = list(range(len(self.grouping)))

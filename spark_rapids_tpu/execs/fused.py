@@ -60,7 +60,6 @@ from spark_rapids_tpu.expressions.base import (Alias, BoundReference, ColV,
                                                Literal, broadcast)
 from spark_rapids_tpu.expressions.compiler import (
     _unwrap_alias, derive_stats, fused_cache_get_or_build)
-from spark_rapids_tpu.native import kernels as nkr
 from spark_rapids_tpu.ops import hashing, sortkeys
 from spark_rapids_tpu.ops import join as join_ops
 from spark_rapids_tpu.ops.join import _BUILD_NULL, _PROBE_NULL
@@ -307,9 +306,6 @@ class PreparedBuild:
     ghosts: Optional[list] = None         # host wrap info per column
     table: Optional[jax.Array] = None     # dense inverse index
     dense_lo: int = 0
-    #: native.kernels.join.ProbeTable — the device-resident bucket
-    #: table (join kernel on), probed across every stream batch
-    ptable: Optional[object] = None
 
 
 def _hash_keys(key_cols: Sequence[ColV], types: Sequence[dt.DType],
@@ -337,8 +333,7 @@ def _hash_keys(key_cols: Sequence[ColV], types: Sequence[dt.DType],
 
 
 def _prep_build_arrays(datas, vals, num_rows, key_ords, types, hash_types,
-                       key_range=False, dense_span=0, dense_lo=0,
-                       kernel_table=False):
+                       key_range=False, dense_span=0, dense_lo=0):
     """Traceable build-side preparation — the body of ``_prep_build``,
     shared verbatim by the chain engine's build-inlined program variant
     (the in-program build traces this INSIDE the consuming chain, so
@@ -382,32 +377,19 @@ def _prep_build_arrays(datas, vals, num_rows, key_ords, types, hash_types,
                                     dense_lo, dense_span)
     else:
         table = jnp.zeros(0, dtype=jnp.int32)
-    if kernel_table and dense_span <= 0:
-        # join kernel: bucket-offset table over the hash-sorted build,
-        # HBM-resident for every later probe batch (dense mode keeps
-        # its one-gather inverse table — strictly cheaper when legal)
-        from spark_rapids_tpu.native.kernels import join as njoin
-
-        ptable = njoin.build_table(sh, n_valid,
-                                   njoin.table_bits_for(cap))
-    else:
-        ptable = None
-    return sh, sdatas, svals, dup, n_valid, kmin, kmax, table, ptable
+    return sh, sdatas, svals, dup, n_valid, kmin, kmax, table
 
 
 @partial(jax.jit, static_argnames=("key_ords", "types", "hash_types",
-                                   "key_range", "dense_span",
-                                   "kernel_table"))
+                                   "key_range", "dense_span"))
 def _prep_build(datas, vals, num_rows, key_ords, types, hash_types,
-                key_range=False, dense_span=0, dense_lo=0,
-                kernel_table=False):
+                key_range=False, dense_span=0, dense_lo=0):
     """Standalone (host-path) build prep: one dispatch per build. The
     in-program-build default inlines _prep_build_arrays into the chain
     instead; this program remains for the knob-off / fallback path."""
     return _prep_build_arrays(datas, vals, num_rows, key_ords, types,
                               hash_types, key_range=key_range,
-                              dense_span=dense_span, dense_lo=dense_lo,
-                              kernel_table=kernel_table)
+                              dense_span=dense_span, dense_lo=dense_lo)
 
 
 def _dense_table_arrays(keys_sorted, n_valid, lo, span):
@@ -477,7 +459,7 @@ def _finalize_entries_locked(entries) -> None:
             e["done"].set()
         raise
     for e, (dup_h, kmin_h, kmax_h) in zip(todo, flags):
-        (sh, sdatas, svals, _d, n_valid, _kn, _kx, table, ptable), \
+        (sh, sdatas, svals, _d, n_valid, _kn, _kx, table), \
             ghosts, want_range, build_keys, span_max, dense_span, \
             dense_lo = e.pop("pending")
         if bool(dup_h):
@@ -485,8 +467,7 @@ def _finalize_entries_locked(entries) -> None:
         else:
             prep = PreparedBuild(
                 ok=True, h_sorted=sh, datas=tuple(sdatas),
-                vals=tuple(svals), n_valid=n_valid, ghosts=ghosts,
-                ptable=ptable)
+                vals=tuple(svals), n_valid=n_valid, ghosts=ghosts)
             if dense_span > 0:
                 # stats-known range: the table came out of _prep_build
                 prep.table = table
@@ -572,8 +553,6 @@ def prepare_builds(specs) -> List[PreparedBuild]:
                         if qhi - qlo + 1 <= span_max:
                             dense_span = qhi - qlo + 1
                             dense_lo = qlo
-                from spark_rapids_tpu.native import kernels as nkr
-
                 with TraceRange("FusedChain.prepareBuild"):
                     out = _prep_build(
                         [c.data for c in b.columns],
@@ -582,8 +561,7 @@ def prepare_builds(specs) -> List[PreparedBuild]:
                         tuple(build_types), tuple(hash_types),
                         key_range=want_range and not dense_span,
                         dense_span=dense_span,
-                        dense_lo=np.int64(dense_lo),
-                        kernel_table=nkr.enabled("join"))
+                        dense_lo=np.int64(dense_lo))
                 ghosts = [_ghost_of(c) for c in b.columns]
             with _PREP_LOCK:
                 entry["pending"] = (out, ghosts, want_range,
@@ -689,15 +667,12 @@ class FusedChain:
         ks = tuple(s.key() for s in self.steps)
         if any(k is None for k in ks):
             return None
-        # the native-kernel gate state routes ops at TRACE time, so it
-        # is part of the program's structural identity — a knob flip
-        # must miss every cache, never serve the stale routing
         return ("fused_chain", ks, tuple(self.source_types), compact_out,
-                modes, decode, inline, nkr.cache_token())
+                modes, decode, inline)
 
     def _program(self, compact_out: bool, modes: tuple = (),
                  decode: tuple = (), inline: tuple = ()):
-        ckey = (compact_out, modes, decode, inline, nkr.cache_token())
+        ckey = (compact_out, modes, decode, inline)
         prog = self._programs.get(ckey)
         if prog is not None:
             return prog
@@ -763,21 +738,18 @@ class FusedChain:
             # batches reuse them via the probe-only variant — the
             # standalone _prep_build dispatch and its flag-sync
             # device_get both disappear from the stage.
-            from spark_rapids_tpu.native import kernels as nkr
-
             ops, prepared = [], []
             for spec, (bdatas, bvals, bnum) in zip(inline, raw_builds):
                 bkeys, btypes, htypes, dspan, dlo = spec
-                (sh, sdatas, svals, dup, n_valid, _kn, _kx, table,
-                 ptable) = _prep_build_arrays(
+                (sh, sdatas, svals, dup, n_valid, _kn, _kx,
+                 table) = _prep_build_arrays(
                     list(bdatas), list(bvals), bnum, bkeys, btypes,
-                    htypes, dense_span=dspan, dense_lo=dlo,
-                    kernel_table=nkr.enabled("join"))
+                    htypes, dense_span=dspan, dense_lo=dlo)
                 ops.append((sh, tuple(sdatas), tuple(svals), n_valid,
                             table if dspan > 0 else None,
-                            dlo if dspan > 0 else None, ptable))
+                            dlo if dspan > 0 else None))
                 prepared.append((sh, tuple(sdatas), tuple(svals), dup,
-                                 n_valid, table, ptable))
+                                 n_valid, table))
             return ops, tuple(prepared)
 
         if decode:
@@ -867,7 +839,7 @@ class FusedChain:
         states, final_ghosts = self._ghost_states(batch, preps)
         build_ops = tuple(
             (p.h_sorted, p.datas, p.vals, p.n_valid, p.table,
-             None if p.table is None else p.dense_lo, p.ptable)
+             None if p.table is None else p.dense_lo)
             for p in preps)
         # dense/hash probe mode is per-build runtime information (key
         # stats), so it keys the compiled program separately
@@ -1013,11 +985,8 @@ def _apply_join(step: JoinStep, cols: List[ColV], live,
     hashing, no verification. Hash mode: searchsorted into the
     hash-sorted build + exact key verification. Either way each probe
     row has at most one candidate; matches fold into the live-mask
-    (inner/semi/anti) or gathered validity (left). With the join kernel
-    on, hash mode probes the prep-time bucket table (one short in-HBM
-    scan) instead of the ~17-step searchsorted binary search — same
-    leftmost-match contract, same exact-key verification."""
-    sh, datas, vals, n_valid, table, dense_lo, ptable = b
+    (inner/semi/anti) or gathered validity (left)."""
+    sh, datas, vals, n_valid, table, dense_lo = b
     b_cap = sh.shape[0]
     if table is not None:
         span = table.shape[0]
@@ -1034,13 +1003,7 @@ def _apply_join(step: JoinStep, cols: List[ColV], live,
         key_cols = [cols[o] for o in step.stream_keys]
         h_p = _hash_keys(key_cols, [c.dtype for c in key_cols],
                          step.key_common, _PROBE_NULL)
-        if ptable is not None:
-            from spark_rapids_tpu.native.kernels import join as njoin
-
-            lo, _cnt = njoin.probe(ptable, h_p)
-        else:
-            lo = jnp.searchsorted(sh, h_p,
-                                  side="left").astype(jnp.int32)
+        lo = jnp.searchsorted(sh, h_p, side="left").astype(jnp.int32)
         lo_c = jnp.clip(lo, 0, b_cap - 1)
         found = (jnp.take(sh, lo_c) == h_p) & (lo < n_valid)
         for so, bo, ct in zip(step.stream_keys, step.build_keys,
@@ -1265,10 +1228,9 @@ class FusedChainExec(TpuExec):
         preps = []
         for (bkeys, _bt, _cm, dspan, dlo), p, g in zip(descs, prepared,
                                                        ghosts_l):
-            sh, sdatas, svals, _dup, n_valid, table, ptable = p
+            sh, sdatas, svals, _dup, n_valid, table = p
             prep = PreparedBuild(ok=True, h_sorted=sh, datas=sdatas,
-                                 vals=svals, n_valid=n_valid, ghosts=g,
-                                 ptable=ptable)
+                                 vals=svals, n_valid=n_valid, ghosts=g)
             if dspan > 0:
                 prep.table = table
                 prep.dense_lo = dlo

@@ -172,9 +172,9 @@ class HashJoinExec(TpuExec):
                     stream_staged, self.children[0].schema)]
             else:
                 stream_batches = self.children[0].execute(partition)
-            # build-once/probe-many: hash + sort (+ bucket table with
-            # the join kernel on) a single time, reused by every stream
-            # batch below (None when a join key is a string column).
+            # build-once/probe-many: hash + sort a single time, reused
+            # by every stream batch below (None when a join key is a
+            # string column).
             # With the AQE dense hint armed, a measured-narrow key range
             # upgrades the probe to a direct slot lookup instead.
             prepared = self._dense_prepared(build, left_types,

@@ -169,12 +169,6 @@ class Substring(Expression):
 
     def eval(self, ctx):
         v = self.children[0].eval(ctx)
-        if not isinstance(v, Scalar):
-            from spark_rapids_tpu.native.kernels import strings as nks
-
-            out = nks.substring_colv(v, self.pos, self.length)
-            if out is not None:
-                return out
         if isinstance(v, Scalar):
             if v.is_null:
                 return Scalar(dt.STRING, None)
@@ -321,10 +315,6 @@ class _StrPredicate(Expression):
     def device_only(self):
         return False
 
-    # native-kernel route for this predicate ('starts'/'ends'/
-    # 'contains'/'like'); None keeps the host path unconditionally
-    _kernel_kind: Optional[str] = None
-
     def test(self, s: str) -> bool:  # pragma: no cover - abstract
         raise NotImplementedError
 
@@ -334,41 +324,26 @@ class _StrPredicate(Expression):
             if v.is_null:
                 return Scalar(dt.BOOLEAN, None)
             return Scalar(dt.BOOLEAN, self.test(str(v.value)))
-        if self._kernel_kind is not None:
-            from spark_rapids_tpu.native.kernels import strings as nks
-
-            out = nks.predicate_colv(v, self._kernel_kind, self.needle,
-                                     getattr(self, "escape", None))
-            if out is not None:
-                return out
         return _dict_map_val(v, self.test, dt.BOOLEAN)
 
 
 class StartsWith(_StrPredicate):
-    _kernel_kind = "starts"
-
     def test(self, s):
         return s.startswith(self.needle)
 
 
 class EndsWith(_StrPredicate):
-    _kernel_kind = "ends"
-
     def test(self, s):
         return s.endswith(self.needle)
 
 
 class Contains(_StrPredicate):
-    _kernel_kind = "contains"
-
     def test(self, s):
         return self.needle in s
 
 
 class Like(_StrPredicate):
     """SQL LIKE: % any-seq, _ any-char, escape supported."""
-
-    _kernel_kind = "like"
 
     def __init__(self, child: Expression, pattern: str, escape: str = "\\"):
         super().__init__(child, pattern)

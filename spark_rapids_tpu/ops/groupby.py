@@ -107,27 +107,9 @@ def key_range_of(col: Column, dtype: dt.DType) -> Optional[Tuple[int, int]]:
     return None
 
 
-# Chunked wide aggregates: an older libtpu (2026-07) crashed its compile
-# helper on the composite groupby program when it carried >= 7 aggregate
-# columns at capacities >= 32768, so wide aggregate lists could split
-# into chunks of <= 6 below this shape boundary; chunks re-sort but are
-# deterministic, so every chunk produces identical group order and the
-# outputs zip. ``single_pass=True`` (the default, knob
-# rapids.tpu.sql.groupby.singlePass.enabled) bypasses the chunk loop.
-# SEEN WITH libtpu 0.0.34 / jax 0.9.0 (PR 23): the single-pass program
-# with TPC-H q1's eight aggregate columns at capacity 2,097,152 compiles
-# for the v5e in about 2.4 s and ran on a v5e chip at sf 1 with every
-# knob at its default, answers equal to the cpu/ engine's. The crash did
-# not show, so the code does not pick the chunked path from the shape.
-# Which of the two paths stays is a later simplicity PR's to decide.
-_AOT_MAX_AGGS = 6
-_AOT_CHUNK_MIN_CAP = 1 << 15
-
-
 def groupby_aggregate(batch: ColumnarBatch, key_ordinals: List[int],
                       aggs: List[AggSpec], dtypes: List[dt.DType],
-                      live_mask=None, dense_ok: bool = True,
-                      single_pass: bool = True
+                      live_mask=None, dense_ok: bool = True
                       ) -> Tuple[ColumnarBatch, List[dt.DType]]:
     """Returns (result batch [keys..., agg results...], result dtypes).
     ``live_mask`` fuses an upstream filter into the sort pass.
@@ -137,13 +119,12 @@ def groupby_aggregate(batch: ColumnarBatch, key_ordinals: List[int],
     positions and the dense sweep's reduction tree is position-
     dependent — levels summing the SAME value set would differ in the
     last ulp, splitting rank()-over-sum ties the sort path (segment-
-    relative scan order) keeps exact. ``single_pass`` False restores
-    the chunked AOT-workaround loop for wide aggregate lists."""
+    relative scan order) keeps exact. Any number of aggregates is ONE
+    ``_groupby`` launch (``tests/test_tpu_compile.py`` keeps the wide
+    sort-path program compiling for the v5e)."""
     cols = [(c.data, c.validity) for c in batch.columns]
     key_ranges = tuple(key_range_of(batch.columns[o], dtypes[o])
                        for o in key_ordinals)
-    key_has_v = tuple(batch.columns[o].validity is not None
-                      for o in key_ordinals)
     # dense_ok=False only needs to suppress ORDER-SENSITIVE float
     # reductions; integer sums/counts/min/max are exact regardless of
     # reduction-tree shape, so a grouping-set aggregate over those
@@ -154,31 +135,10 @@ def groupby_aggregate(batch: ColumnarBatch, key_ordinals: List[int],
              dtypes[spec.ordinal].is_floating)
             for spec in aggs):
         dense_ok = True
-    # the dense path never builds the fused sort module the AOT
-    # segfault workaround guards against — wide agg lists stay whole
-    will_dense = dense_ok and _dense_layout(
-        list(dtypes), key_ordinals, key_ranges, key_has_v) is not None
-    if not single_pass and len(aggs) > _AOT_MAX_AGGS and \
-            not will_dense and batch.capacity >= _AOT_CHUNK_MIN_CAP:
-        agg_d, agg_v = [], []
-        key_d = key_v = num_groups = None
-        for lo in range(0, len(aggs), _AOT_MAX_AGGS):
-            chunk = tuple(aggs[lo:lo + _AOT_MAX_AGGS])
-            out = _groupby(cols, tuple(dtypes), tuple(key_ordinals),
-                           chunk, batch.num_rows_device(),
-                           live_mask=live_mask, key_ranges=key_ranges,
-                           dense_ok=dense_ok)
-            (ck_d, ck_v), (ca_d, ca_v), ng = out
-            if key_d is None:
-                key_d, key_v, num_groups = ck_d, ck_v, ng
-            agg_d.extend(ca_d)
-            agg_v.extend(ca_v)
-    else:
-        out = _groupby(cols, tuple(dtypes), tuple(key_ordinals),
-                       tuple(aggs), batch.num_rows_device(),
-                       live_mask=live_mask, key_ranges=key_ranges,
-                       dense_ok=dense_ok)
-        (key_d, key_v), (agg_d, agg_v), num_groups = out
+    (key_d, key_v), (agg_d, agg_v), num_groups = _groupby(
+        cols, tuple(dtypes), tuple(key_ordinals), tuple(aggs),
+        batch.num_rows_device(), live_mask=live_mask,
+        key_ranges=key_ranges, dense_ok=dense_ok)
     out_cols: List[Column] = []
     out_types: List[dt.DType] = []
     for i, ord_ in enumerate(key_ordinals):
@@ -275,10 +235,8 @@ def _dense_groupby(cols, dtypes, key_ordinals, aggs, live, layout):
     """Sort-free groupby for tiny host-known key spaces: rows map to a
     packed slot code, and each aggregate is ONE masked reduction over a
     [slots, capacity] broadcast compare that XLA fuses into a single
-    sweep — no sort, no cumsum, and no AOT-segfault chunking
-    (the >= 7-agg boundary above applies to the fused sort module, which
-    this path never builds). The slot axis compacts with an argsort over
-    <= 128 elements. Matches the semantics of the sort path exactly:
+    sweep — no sort, no cumsum. The slot axis compacts with an argsort
+    over <= 128 elements. Matches the semantics of the sort path exactly:
     same null-first slot encoding, same validity rules per op.
 
     The reference reaches the same shapes through cuDF's hash groupby

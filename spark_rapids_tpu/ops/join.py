@@ -32,7 +32,6 @@ import numpy as np
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column, StringColumn, unify_dictionaries
-from spark_rapids_tpu.native import kernels as nkr
 from spark_rapids_tpu.ops import hashing, sortkeys
 from spark_rapids_tpu.ops.buckets import bucket_capacity
 
@@ -103,21 +102,19 @@ def unify_join_strings(left: ColumnarBatch, right: ColumnarBatch,
 
 class PreparedBuild(NamedTuple):
     """Build side prepared once and probed across every stream batch:
-    the hash-sorted build plus (join kernel on) the device-resident
-    bucket table. Only valid when no JOIN KEY is a string column —
-    string keys re-unify dictionaries per stream batch, changing the
-    build hashes (non-key string columns are fine)."""
+    the hash-sorted build. Only valid when no JOIN KEY is a string
+    column — string keys re-unify dictionaries per stream batch,
+    changing the build hashes (non-key string columns are fine)."""
 
     sorted_build: ColumnarBatch
     sb_h: jax.Array
-    table: Optional[object]  # native.kernels.join.ProbeTable
 
 
 def prepare_build(build: ColumnarBatch, build_keys: List[int],
                   build_types: List[dt.DType],
                   stream_types_for_keys: List[dt.DType]
                   ) -> Optional[PreparedBuild]:
-    """Hash + sort (+ table-build, kernel on) the build side once for
+    """Hash + sort the build side once for
     reuse across stream batches. Returns None when a join key is a
     string column (per-batch dictionary unification makes the build
     hash stream-dependent)."""
@@ -129,13 +126,13 @@ def prepare_build(build: ColumnarBatch, build_keys: List[int],
         return None
     h_b = _key_hashes(build, build_keys, build_types, _BUILD_NULL,
                       target_types=commons)
-    sb_h, sb_datas, sb_vals, table = _build_sorted(
+    sb_h, sb_datas, sb_vals = _build_sorted(
         [c.data for c in build.columns],
         [c.validity for c in build.columns], h_b,
-        build.num_rows_device(), use_kernel=nkr.enabled("join"))
+        build.num_rows_device())
     cols = [c._like(d, v) for c, d, v in
             zip(build.columns, sb_datas, sb_vals)]
-    return PreparedBuild(ColumnarBatch(cols, build.num_rows), sb_h, table)
+    return PreparedBuild(ColumnarBatch(cols, build.num_rows), sb_h)
 
 
 class DensePreparedBuild(NamedTuple):
@@ -272,7 +269,6 @@ def equi_join(stream: ColumnarBatch, build: ColumnarBatch,
         "no common comparison type for join keys",
         [stream_types[o] for o in stream_keys],
         [build_types[o] for o in build_keys])
-    use_kernel = nkr.enabled("join")
     if isinstance(prepared, DensePreparedBuild):
         # ---- phase 1 (device), dense: direct slot lookup, no hashing
         # of either side at all
@@ -288,9 +284,7 @@ def equi_join(stream: ColumnarBatch, build: ColumnarBatch,
                           target_types=commons)
         sorted_build = prepared.sorted_build
         lo, hi, counts, total = _probe_sorted(
-            prepared.sb_h, prepared.table, h_p,
-            stream.num_rows_device(),
-            use_kernel=use_kernel and prepared.table is not None)
+            prepared.sb_h, h_p, stream.num_rows_device())
     else:
         h_p = _key_hashes(stream, stream_keys, stream_types, _PROBE_NULL,
                           target_types=commons)
@@ -301,8 +295,7 @@ def equi_join(stream: ColumnarBatch, build: ColumnarBatch,
             b_datas, b_vals, h_b := _key_hashes(
                 build, build_keys, build_types, _BUILD_NULL,
                 target_types=commons),
-            build.num_rows_device(), h_p, stream.num_rows_device(),
-            use_kernel=use_kernel)
+            build.num_rows_device(), h_p, stream.num_rows_device())
         sorted_build_cols = [c._like(d, v) for c, d, v in
                              zip(build.columns, sb_datas, sb_vals)]
         sorted_build = ColumnarBatch(sorted_build_cols, build.num_rows)
@@ -345,20 +338,12 @@ def _sort_build(b_datas, b_vals, h_b, b_rows):
     return sb_h, sb_datas, sb_vals
 
 
-def _hash_probe(sb_h, table, h_p, s_rows, use_kernel: bool):
-    """Leftmost hash-match position + run length per probe row: the
-    bucket-table kernel and the two searchsorted calls share this exact
-    contract (tests/test_kernels.py holds them bit-equal)."""
+def _hash_probe(sb_h, h_p, s_rows):
+    """Leftmost hash-match position + run length per probe row."""
     s_cap = h_p.shape[0]
     live_p = jnp.arange(s_cap, dtype=jnp.int32) < s_rows
-    if use_kernel:
-        from spark_rapids_tpu.native.kernels import join as njoin
-
-        lo, cnt = njoin.probe(table, h_p)
-        hi = lo + cnt
-    else:
-        lo = jnp.searchsorted(sb_h, h_p, side="left")
-        hi = jnp.searchsorted(sb_h, h_p, side="right")
+    lo = jnp.searchsorted(sb_h, h_p, side="left")
+    hi = jnp.searchsorted(sb_h, h_p, side="right")
     # clamp hi to live build rows (padding key int64-max never matches a
     # real hash, but belt-and-braces if a hash equals the sentinel)
     counts = jnp.where(live_p, hi - lo, 0).astype(jnp.int64)
@@ -366,41 +351,25 @@ def _hash_probe(sb_h, table, h_p, s_rows, use_kernel: bool):
     return lo, hi, counts, total
 
 
-@partial(jax.jit, static_argnames=("use_kernel",))
-def _probe_counts(b_datas, b_vals, h_b, b_rows, h_p, s_rows,
-                  use_kernel: bool = False):
+@jax.jit
+def _probe_counts(b_datas, b_vals, h_b, b_rows, h_p, s_rows):
     sb_h, sb_datas, sb_vals = _sort_build(b_datas, b_vals, h_b, b_rows)
-    table = None
-    if use_kernel:
-        from spark_rapids_tpu.native.kernels import join as njoin
-
-        table = njoin.build_table(sb_h, b_rows,
-                                  njoin.table_bits_for(sb_h.shape[0]))
-    lo, hi, counts, total = _hash_probe(sb_h, table, h_p, s_rows,
-                                        use_kernel)
+    lo, hi, counts, total = _hash_probe(sb_h, h_p, s_rows)
     return sb_h, sb_datas, sb_vals, lo, hi, counts, total
 
 
-@partial(jax.jit, static_argnames=("use_kernel",))
-def _build_sorted(b_datas, b_vals, h_b, b_rows, use_kernel: bool = False):
-    """Build-once half of the prepared path: one program sorts the build
-    and (kernel on) derives the bucket table that stays HBM-resident
-    across every stream batch."""
-    sb_h, sb_datas, sb_vals = _sort_build(b_datas, b_vals, h_b, b_rows)
-    table = None
-    if use_kernel:
-        from spark_rapids_tpu.native.kernels import join as njoin
-
-        table = njoin.build_table(sb_h, b_rows,
-                                  njoin.table_bits_for(sb_h.shape[0]))
-    return sb_h, sb_datas, sb_vals, table
+@jax.jit
+def _build_sorted(b_datas, b_vals, h_b, b_rows):
+    """Build-once half of the prepared path: one program sorts the
+    build."""
+    return _sort_build(b_datas, b_vals, h_b, b_rows)
 
 
-@partial(jax.jit, static_argnames=("use_kernel",))
-def _probe_sorted(sb_h, table, h_p, s_rows, use_kernel: bool = False):
+@jax.jit
+def _probe_sorted(sb_h, h_p, s_rows):
     """Probe-many half of the prepared path (one program per stream
     batch, no build work)."""
-    return _hash_probe(sb_h, table, h_p, s_rows, use_kernel)
+    return _hash_probe(sb_h, h_p, s_rows)
 
 
 def _prefix_sum(x: jax.Array) -> jax.Array:

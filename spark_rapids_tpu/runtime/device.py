@@ -114,9 +114,6 @@ def initialize(conf: Optional[RapidsConf] = None,
         from spark_rapids_tpu.shuffle import tcp as shuffle_tcp
 
         shuffle_tcp.configure_retry_from_conf(conf)
-        from spark_rapids_tpu.native import kernels
-
-        kernels.configure_from_conf(conf)
         _env = RuntimeEnv(conf, dm, catalog, semaphore,
                           conf.get(cfg.SHUFFLE_COMPRESSION_CODEC))
         return _env
@@ -144,6 +141,3 @@ def shutdown() -> None:
         retry.reset_config()
         fault_injection.get_injector().disarm()
         shuffle_fault_injection.get_injector().disarm()
-        from spark_rapids_tpu.native import kernels
-
-        kernels.reset_config()

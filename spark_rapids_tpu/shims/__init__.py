@@ -50,10 +50,6 @@ class JaxShims:
         """Reset backends so device-count flags re-apply."""
         raise NotImplementedError
 
-    def pallas(self):
-        """The pallas kernel module (None when unavailable)."""
-        return None
-
 
 class JaxShimServiceProvider:
     """SparkShimServiceProvider analogue: version probe + factory."""
@@ -75,28 +71,6 @@ class JaxShimServiceProvider:
         raise NotImplementedError
 
 
-def _kernel_safe_shard_map(sm):
-    """Default ``check_vma=False`` while the native-kernel gate is on:
-    interpret-mode ``pallas_call`` has no shard_map replication rule,
-    so a kernel routed inside a mesh device step would fail to trace
-    otherwise. Replication checking is a trace-time assertion, not a
-    semantics change — the mesh differential fences
-    (tests/test_spmd_shuffle.py, tests/test_kernels.py) still assert
-    bit-equality against the single-device and oracle paths."""
-    import functools
-
-    @functools.wraps(sm)
-    def wrapped(f, **kw):
-        if "check_vma" not in kw:
-            from spark_rapids_tpu.native import kernels as nk
-
-            if nk.cache_token()[0]:
-                kw["check_vma"] = False
-        return sm(f, **kw)
-
-    return wrapped
-
-
 class _ModernJaxShims(JaxShims):
     """jax >= 0.7: public top-level shard_map (``check_vma``),
     jax.extend backend API."""
@@ -104,20 +78,12 @@ class _ModernJaxShims(JaxShims):
     def shard_map(self):
         from jax import shard_map
 
-        return _kernel_safe_shard_map(shard_map)
+        return shard_map
 
     def clear_backends(self):
         from jax.extend import backend
 
         backend.clear_backends()
-
-    def pallas(self):
-        try:
-            from jax.experimental import pallas
-
-            return pallas
-        except ImportError:  # pragma: no cover - platform-dependent
-            return None
 
 
 class ModernJaxShimProvider(JaxShimServiceProvider):

@@ -139,7 +139,6 @@ LOCK_HIERARCHY: Dict[str, int] = {
     "io.scanpipe.stats": 179,        # scan-pipeline telemetry counters
     "runtime.recovery.stats": 178,   # process-global recovery counters
     "service.streaming.stats": 180,  # process-global fold counters
-    "native.kernels.config": 182,    # pallas kernel gate state
     "native.init": 184,
     "shims.init": 188,
     "config.registry": 192,

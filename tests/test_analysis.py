@@ -172,35 +172,35 @@ def test_tpu203_weak_literal_flagged_dtype_exempt(tmp_path):
     assert len(fs) == 1 and fs[0].qualname == "bad"
 
 
-def test_tpu204_direct_pallas_call_flagged_registry_exempt(tmp_path):
+def test_tpu204_every_pallas_call_flagged_and_the_package_has_none(
+        tmp_path):
+    """The package keeps no Pallas kernel (PR 29): a site is a finding
+    wherever it stands and whatever it goes through, the place of the
+    former registry included; over the real package the rule is
+    silent."""
     from spark_rapids_tpu.analysis import recompile
     root = _tree(tmp_path, {
-        # the registry itself: the ONE sanctioned pl.pallas_call site
         "spark_rapids_tpu/native/kernels/__init__.py": """
-            def pallas_call(kernel, *, out_shape, **kw):
-                from spark_rapids_tpu.shims import get_shims
-                pl = get_shims().pallas()
+            def wrapper(kernel, *, out_shape, **kw):
+                from jax.experimental import pallas as pl
                 return pl.pallas_call(kernel, out_shape=out_shape,
                                       interpret=True, **kw)
         """,
-        # kernel module routing through the registry: exempt
-        "spark_rapids_tpu/native/kernels/good.py": """
-            from spark_rapids_tpu.native import kernels as nk
-
-            def fine(kern, shape):
-                return nk.pallas_call(kern, out_shape=shape)
-        """,
-        # direct pl.pallas_call outside the registry: flagged
         "spark_rapids_tpu/execs/bad.py": """
-            from jax.experimental import pallas as pl
+            from jax.experimental.pallas import pallas_call
 
             def bad(kern, shape):
-                return pl.pallas_call(kern, out_shape=shape,
-                                      interpret=False)
+                return pallas_call(kern, out_shape=shape)
+        """,
+        "spark_rapids_tpu/ops/fine.py": """
+            import jax.numpy as jnp
+
+            def fine(sb_h, h_p):
+                return jnp.searchsorted(sb_h, h_p, side="left")
         """})
     fs = [f for f in recompile.run(root) if f.code == "TPU204"]
-    assert len(fs) == 1 and fs[0].qualname == "bad"
-    assert fs[0].path.endswith("bad.py")
+    assert sorted(f.qualname for f in fs) == ["bad", "wrapper"]
+    assert [f for f in recompile.run(ROOT) if f.code == "TPU204"] == []
 
 
 # ---------------------------------------------------------------------------

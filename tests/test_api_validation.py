@@ -95,6 +95,42 @@ def test_aggregate_functions_declare_partial_contract():
         assert len(inst.update_ops()) == len(inst.partial_types())
 
 
+def test_every_registered_knob_is_read_somewhere():
+    """A registered key that no line of the package reads is documented
+    as if it did something (PR 29 took seven such away). Read means:
+    its constant is named anywhere but where it is defined, or its key
+    text stands in a module other than config.py."""
+    import os
+    import re
+
+    from spark_rapids_tpu import config
+
+    pkg = os.path.dirname(os.path.abspath(config.__file__))
+    conf_py = os.path.join(pkg, "config.py")
+    own, rest = "", []
+    for d, _dirs, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(d, f)) as fh:
+                    if os.path.join(d, f) == conf_py:
+                        own = fh.read()
+                    else:
+                        rest.append(fh.read())
+    src = "\n".join(rest)
+    names = {id(v): k for k, v in vars(config).items()
+             if isinstance(v, config.ConfEntry)}
+    # config.py's own constants; the per-expression and per-exec keys
+    # that plan/overrides registers are read by the rule that made them
+    assert len(names) <= 139       # PR 29's count; a new knob argues here
+    unread = []
+    for e in config.registered_entries():
+        name = names.get(id(e))
+        if name and e.key not in src and \
+                len(re.findall(rf"\b{name}\b", own + src)) < 2:
+            unread.append(e.key)
+    assert not unread, f"registered but never read: {unread}"
+
+
 # ---------------------------------------------------------------------------
 # Shim loader (SURVEY.md §2.13: ShimLoader + SparkShimServiceProvider)
 

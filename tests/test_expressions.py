@@ -321,6 +321,82 @@ def test_substring_negative_pos_past_start():
     assert list(v1) == ["bc", "ef"]
 
 
+_WORDS = ["", "a", "apple", "APPLESAUCE", "banana split", "a%b_c",
+          "100%", "under_score", "the quick brown fox", "x", "ab" * 40,
+          "café", "naïve", "日本語", "plain"]
+
+
+def _word_rows(seed, n=64):
+    """The words drawn with repeats, a fifth of the rows NULL."""
+    r = np.random.default_rng(seed)
+    rows = [_WORDS[i] for i in r.integers(0, len(_WORDS), n)]
+    return [None if dead else w
+            for w, dead in zip(rows, r.random(n) < 0.2)]
+
+
+def _like(s, pat, esc="\\"):
+    """SQL LIKE by recursion: % any run, _ one character."""
+    if not pat:
+        return not s
+    if pat[0] == esc and len(pat) > 1:
+        return s[:1] == pat[1] and _like(s[1:], pat[2:], esc)
+    if pat[0] == "%":
+        return any(_like(s[i:], pat[1:], esc) for i in range(len(s) + 1))
+    return bool(s) and pat[0] in ("_", s[0]) and _like(s[1:], pat[1:], esc)
+
+
+@pytest.mark.parametrize("make, want", [
+    (lambda c: sexpr.Like(c, "%apple%"), lambda s: _like(s, "%apple%")),
+    (lambda c: sexpr.Like(c, "a%b\\_c"), lambda s: _like(s, "a%b\\_c")),
+    (lambda c: sexpr.Like(c, "100\\%"), lambda s: s == "100%"),
+    (lambda c: sexpr.Like(c, "_pple"), lambda s: _like(s, "_pple")),
+    (lambda c: sexpr.Like(c, "%quick%fox"),
+     lambda s: _like(s, "%quick%fox")),
+    (lambda c: sexpr.Like(c, "pl_in"), lambda s: s == "plain"),
+    (lambda c: sexpr.Like(c, "caf_"), lambda s: s == "café"),
+    (lambda c: sexpr.Like(c, "__語"), lambda s: s == "日本語"),
+    (lambda c: sexpr.Contains(c, "an"), lambda s: "an" in s),
+    (lambda c: sexpr.Contains(c, "ï"), lambda s: "ï" in s),
+    (lambda c: sexpr.StartsWith(c, "a"), lambda s: s.startswith("a")),
+    (lambda c: sexpr.EndsWith(c, "x"), lambda s: s.endswith("x")),
+], ids=["like-infix", "like-escaped-underscore", "like-escaped-percent",
+        "like-one-char", "like-two-runs", "like-one-char-ascii",
+        "like-one-char-is-a-character-not-a-byte", "like-cjk",
+        "contains", "contains-non-ascii", "startswith", "endswith"])
+def test_string_predicates_differential(make, want):
+    """LIKE / contains / startswith / endswith through the dictionary
+    (one evaluation a distinct string, a gather of the codes) == plain
+    Python applied row by row, over escapes, wildcards, non-ASCII
+    strings and NULLs."""
+    rows = _word_rows(3)
+    out = run_project([make(ref(0, dt.STRING))], make_batch(rows))
+    got, valid = col_out(out)
+    for i, s in enumerate(rows):
+        if s is None:
+            assert valid is not None and not valid[i], i
+        else:
+            assert (valid is None or valid[i]) and \
+                bool(got[i]) == bool(want(s)), (i, s)
+
+
+@pytest.mark.parametrize("pos, length",
+                         [(1, 3), (2, 100), (-3, 2), (0, 2), (5, 0),
+                          (2, None)])
+def test_substring_differential(pos, length):
+    """substring through the dictionary rebuild == Spark's rule applied
+    row by row; positions count characters, not bytes."""
+    def want(s):
+        start = pos - 1 if pos > 0 else len(s) + pos if pos < 0 else 0
+        end = len(s) if length is None else start + length
+        return s[max(start, 0):max(end, 0)]
+
+    rows = _word_rows(17)
+    out = run_project([sexpr.Substring(ref(0, dt.STRING), pos, length)],
+                      make_batch(rows))
+    got, _ = col_out(out)
+    assert list(got) == [None if s is None else want(s) for s in rows]
+
+
 def test_string_scalar_scalar_comparison():
     from spark_rapids_tpu.expressions import predicates as pexpr
     from spark_rapids_tpu.expressions.base import Literal

@@ -1,16 +1,15 @@
-"""Chunked vs single-pass groupby equivalence (PR 13 satellite).
+"""One launch schedule for a group-by (PR 29).
 
-ops/groupby.py historically split wide aggregate lists (> _AOT_MAX_AGGS
-columns at capacity >= _AOT_CHUNK_MIN_CAP on the sort path) into two
-launches — the libtpu v5e AOT-segfault workaround. ``single_pass=True``
-(the default) emits ONE wide launch instead; the chunked loop survives
-as an escape hatch (knob rapids.tpu.sql.groupby.singlePass.enabled).
-This suite pins the contract that the two emissions are the SAME
-aggregate: bit-exact results across the _AOT_MAX_AGGS width boundary
-and the _AOT_CHUNK_MIN_CAP capacity boundary, with and without a fused
-filter mask, and that dense/sort/chunked/single-pass all agree on
-order-insensitive aggregates. It also covers the exec-level
-_COMPACT_WIDE_MIN_CAP pre-pass composing with the knob.
+ops/groupby.py used to split wide aggregate lists (more than six
+columns at capacity >= 32,768 on the sort path) into two launches, a
+workaround for a compile crash of a libtpu from 2026-07, behind a
+user-set switch. The installed compiler builds the whole program
+(tests/test_tpu_compile.py keeps asking it), so one ``_groupby`` launch
+is the only schedule. This suite holds that one path to a numpy oracle
+at the shapes the loop guarded — bit for bit on integer aggregates, to
+the last place on float ones, with and without a fused filter mask —
+keeps dense against sort, and covers the exec-level
+_COMPACT_WIDE_MIN_CAP pre-pass.
 """
 import numpy as np
 import pytest
@@ -21,12 +20,9 @@ from spark_rapids_tpu.columnar.column import Column
 from spark_rapids_tpu.ops import groupby as gb
 from spark_rapids_tpu.ops.groupby import AggSpec
 
-WIDE_CAP = gb._AOT_CHUNK_MIN_CAP          # chunking engages at this cap
+WIDE_CAP = 1 << 15      # the capacity from which the chunk loop ran
 
-# 9 aggregates (> _AOT_MAX_AGGS = 6) over a float and an int column.
-# Chunked and single-pass share the SAME sort kernel and per-column
-# segmented reductions, so even the float sum must be bit-exact between
-# them (unlike dense-vs-sort, where only order-insensitive aggs are).
+# 9 aggregates over a float and an int column
 WIDE_AGGS = [AggSpec("sum", 1), AggSpec("min", 1), AggSpec("max", 1),
              AggSpec("count", 1), AggSpec("sum", 2), AggSpec("min", 2),
              AggSpec("max", 2), AggSpec("count", 2),
@@ -35,8 +31,8 @@ WIDE_AGGS = [AggSpec("sum", 1), AggSpec("min", 1), AggSpec("max", 1),
 ORDER_INSENSITIVE_WIDE = [AggSpec("min", 1), AggSpec("max", 1),
                           AggSpec("count", 1), AggSpec("sum", 2),
                           AggSpec("min", 2), AggSpec("max", 2),
-                          AggSpec("count_star")]       # 7 > 6, exact on
-                                                       # any kernel
+                          AggSpec("count_star")]       # exact on any
+                                                       # kernel
 
 
 def _wide_batch(rng, n, span, with_stats=False):
@@ -78,8 +74,7 @@ def _rows(out, num_aggs):
 
 
 def _count_launches(fn):
-    """Run ``fn`` counting _groupby invocations (the chunk loop calls
-    it once per chunk; single-pass exactly once)."""
+    """Run ``fn`` counting _groupby invocations."""
     calls = []
     real = gb._groupby
 
@@ -95,98 +90,135 @@ def _count_launches(fn):
     return out, len(calls)
 
 
+def _oracle_rows(b, aggs, mask=None):
+    """numpy, a group at a time: (key -> agg tuple) as ``_rows`` gives
+    it. Floats as (bits, valid) where the answer is order-free (min,
+    max), as the float itself for sums."""
+    import jax
+
+    n = b.realized_num_rows()
+    live = np.ones(n, bool) if mask is None else np.asarray(mask)[:n]
+    cols = []
+    for c in b.columns:
+        d = np.asarray(jax.device_get(c.data))[:n]
+        v = np.ones(n, bool) if c.validity is None else \
+            np.asarray(jax.device_get(c.validity))[:n]
+        cols.append((d, v))
+    keys = cols[0][0]
+    rows = {}
+    for k in np.unique(keys[live]):
+        g = live & (keys == k)
+        out = []
+        for spec in aggs:
+            if spec.op == "count_star":
+                out.append((int(g.sum()), True))
+                continue
+            d, v = cols[spec.ordinal]
+            x = d[g & v]
+            if spec.op == "count":
+                out.append((len(x), True))
+            elif len(x) == 0:
+                out.append((None, False))
+            elif spec.op == "sum":
+                # pairwise, as good as any order the engine may take
+                out.append((x.sum().item(), True))
+            elif x.dtype.kind == "f" and np.isnan(x).any():
+                out.append((float("nan"), True))
+            else:
+                out.append(((x.min() if spec.op == "min"
+                             else x.max()).item(), True))
+        rows[(k.item(), True)] = tuple(out)
+    return rows
+
+
+def _assert_rows_match_oracle(got, want, aggs, types):
+    """Integer aggregates and counts bit for bit; float min/max equal
+    as values (NaN == NaN, -0.0 == 0.0: either zero of a group is its
+    least); float sums to the last place of the group's largest
+    partial sum."""
+    assert got.keys() == want.keys()
+    for key, wrow in want.items():
+        grow = got[key]
+        for (gv, gok), (wv, wok), spec in zip(grow, wrow, aggs):
+            assert gok == wok, (key, spec)
+            if not wok:
+                continue
+            is_float = spec.ordinal >= 0 and \
+                types[spec.ordinal] is dt.FLOAT64 and \
+                spec.op in ("sum", "min", "max")
+            if not is_float:
+                assert gv == wv, (key, spec, gv, wv)
+                continue
+            g = np.array(gv, np.uint64).view(np.float64).item()
+            if spec.op == "sum":
+                assert np.isnan(g) if np.isnan(wv) else \
+                    abs(g - wv) <= 64 * np.spacing(max(abs(wv), 1.0)), \
+                    (key, spec, g, wv)
+            else:
+                assert (np.isnan(g) and np.isnan(wv)) or g == wv, \
+                    (key, spec, g, wv)
+
+
 @pytest.mark.parametrize("masked", [False, True])
-def test_single_pass_matches_chunked_bit_exact(masked):
-    """At chunk-eligible shape (9 aggs, cap >= _AOT_CHUNK_MIN_CAP, sort
-    path) the chunked loop issues 2 launches and single-pass 1, and the
-    results — including float sums and NaN/-0.0 bits — are identical.
-    Both with and without a fused filter live_mask."""
+def test_wide_groupby_matches_oracle_bit_exact(masked):
+    """At the shape the chunk loop guarded (9 aggregates, capacity
+    32,768, sort path) the aggregate is ONE launch and equals the numpy
+    oracle, with and without a fused filter live_mask."""
     rng = np.random.default_rng(42 + masked)
-    n = WIDE_CAP
-    b, types = _wide_batch(rng, n, 1000)
+    b, types = _wide_batch(rng, WIDE_CAP, 1000)
     mask = (rng.random(b.capacity) > 0.3) if masked else None
-    out_c, nc = _count_launches(lambda: gb.groupby_aggregate(
-        b, [0], WIDE_AGGS, types, live_mask=mask, single_pass=False))
-    out_s, ns = _count_launches(lambda: gb.groupby_aggregate(
-        b, [0], WIDE_AGGS, types, live_mask=mask, single_pass=True))
-    assert nc == 2 and ns == 1
-    assert _rows(out_c[0], len(WIDE_AGGS)) == \
-        _rows(out_s[0], len(WIDE_AGGS))
+    out, launches = _count_launches(lambda: gb.groupby_aggregate(
+        b, [0], WIDE_AGGS, types, live_mask=mask))
+    assert launches == 1
+    _assert_rows_match_oracle(_rows(out[0], len(WIDE_AGGS)),
+                              _oracle_rows(b, WIDE_AGGS, mask),
+                              WIDE_AGGS, types)
 
 
-def test_agg_width_boundary():
-    """Exactly _AOT_MAX_AGGS aggs never chunk (either mode); one more
-    chunks under single_pass=False and stays whole under True, with
-    bit-identical results either way."""
+@pytest.mark.parametrize("width", [6, 7, 9])
+def test_agg_width_boundary(width):
+    """Six aggregates, seven, nine: ONE ``_groupby`` launch each, and
+    the first six columns of a wider run are the six-wide run's, bit
+    for bit — adding an aggregate must not perturb its neighbours."""
     rng = np.random.default_rng(7)
     b, types = _wide_batch(rng, WIDE_CAP, 500)
-    six = WIDE_AGGS[:gb._AOT_MAX_AGGS]
     out6, n6 = _count_launches(lambda: gb.groupby_aggregate(
-        b, [0], six, types, single_pass=False))
-    assert n6 == 1
-    seven = WIDE_AGGS[:gb._AOT_MAX_AGGS + 1]
-    out_c, n7c = _count_launches(lambda: gb.groupby_aggregate(
-        b, [0], seven, types, single_pass=False))
-    out_s, n7s = _count_launches(lambda: gb.groupby_aggregate(
-        b, [0], seven, types, single_pass=True))
-    assert n7c == 2 and n7s == 1
-    assert _rows(out_c[0], 7) == _rows(out_s[0], 7)
-    # the 6-agg prefix of the 7-agg run matches the 6-agg run: adding
-    # an aggregate must not perturb its neighbours
+        b, [0], WIDE_AGGS[:6], types))
+    out, n = _count_launches(lambda: gb.groupby_aggregate(
+        b, [0], WIDE_AGGS[:width], types))
+    assert n6 == 1 and n == 1
     assert _rows(out6[0], 6) == {
-        k: v[:6] for k, v in _rows(out_c[0], 7).items()}
+        k: v[:6] for k, v in _rows(out[0], width).items()}
 
 
-def test_capacity_boundary_skips_chunking():
-    """One bucket below _AOT_CHUNK_MIN_CAP the chunk loop never engages
-    (the AOT defect is shape-gated), so both modes are one launch and
-    trivially identical."""
-    rng = np.random.default_rng(11)
-    b, types = _wide_batch(rng, WIDE_CAP // 2, 500)
-    assert b.capacity < gb._AOT_CHUNK_MIN_CAP
-    out_c, nc = _count_launches(lambda: gb.groupby_aggregate(
-        b, [0], WIDE_AGGS, types, single_pass=False))
-    out_s, ns = _count_launches(lambda: gb.groupby_aggregate(
-        b, [0], WIDE_AGGS, types, single_pass=True))
-    assert nc == 1 and ns == 1
-    assert _rows(out_c[0], len(WIDE_AGGS)) == \
-        _rows(out_s[0], len(WIDE_AGGS))
-
-
-def test_dense_sort_chunked_single_pass_all_agree():
+def test_dense_and_sort_paths_agree():
     """Order-insensitive wide aggregate, dense-eligible key span: the
-    dense sweep (stats), the sort kernel, the chunked sort loop and the
-    single-pass sort launch all produce the same bits. Dense also never
-    chunks (no sort module to protect), even under single_pass=False."""
+    dense sweep (stats) and the sort kernel (no stats) produce the same
+    bits, each in one launch."""
     rng = np.random.default_rng(13)
     n = WIDE_CAP
     b_stats, types = _wide_batch(rng, n, 100, with_stats=True)
     b_plain = ColumnarBatch(list(b_stats.columns), n)
     b_plain.columns[0] = Column(dt.INT64, b_stats.columns[0].data,
                                 b_stats.columns[0].validity)  # no stats
+    assert gb._dense_layout(types, [0], (gb.key_range_of(
+        b_stats.columns[0], dt.INT64),), (False,)) is not None
     na = len(ORDER_INSENSITIVE_WIDE)
     out_d, nd = _count_launches(lambda: gb.groupby_aggregate(
-        b_stats, [0], ORDER_INSENSITIVE_WIDE, types,
-        single_pass=False))
-    assert nd == 1          # will_dense short-circuits the chunk gate
-    out_s, _ = _count_launches(lambda: gb.groupby_aggregate(
-        b_plain, [0], ORDER_INSENSITIVE_WIDE, types, single_pass=True))
-    out_c, ncc = _count_launches(lambda: gb.groupby_aggregate(
-        b_plain, [0], ORDER_INSENSITIVE_WIDE, types,
-        single_pass=False))
-    assert ncc == 2
-    rows = _rows(out_d[0], na)
-    assert rows == _rows(out_s[0], na) == _rows(out_c[0], na)
+        b_stats, [0], ORDER_INSENSITIVE_WIDE, types))
+    out_s, ns = _count_launches(lambda: gb.groupby_aggregate(
+        b_plain, [0], ORDER_INSENSITIVE_WIDE, types))
+    assert nd == 1 and ns == 1
+    assert _rows(out_d[0], na) == _rows(out_s[0], na)
 
 
-def test_exec_compact_wide_composes_with_single_pass(monkeypatch):
+def test_exec_compact_wide_matches_the_cpu_oracle(monkeypatch):
     """Exec level: the _COMPACT_WIDE_MIN_CAP pre-pass (compact filtered
-    survivors before a wide sort-path aggregate) and the single-pass
-    knob compose — with the boundary lowered into range the compaction
-    engages and both knob settings still match the CPU oracle; at the
-    default boundary (capacity far below 1<<22) it must NOT engage."""
+    survivors before a wide sort-path aggregate) — with the boundary
+    lowered into range the compaction engages and the answer still
+    matches the CPU oracle; at the default boundary (capacity far below
+    1<<22) it must NOT engage."""
     from compare import assert_cpu_and_tpu_equal
-    from spark_rapids_tpu import config as cfg
     from spark_rapids_tpu.config import RapidsConf
     from spark_rapids_tpu.execs.aggregate import HashAggregateExec
     from spark_rapids_tpu.plan import nodes as pn
@@ -217,15 +249,12 @@ def test_exec_compact_wide_composes_with_single_pass(monkeypatch):
     for min_cap in (256, HashAggregateExec._COMPACT_WIDE_MIN_CAP):
         monkeypatch.setattr(HashAggregateExec, "_COMPACT_WIDE_MIN_CAP",
                             min_cap)
-        for sp in (True, False):
-            compacted.clear()
-            conf = RapidsConf().with_overrides(
-                {cfg.GROUPBY_SINGLE_PASS.key: sp})
-            assert_cpu_and_tpu_equal(plan, conf=conf, sort=False,
-                                     approx_float=1e-9)
-            if min_cap == 256:
-                assert compacted, \
-                    "compact-wide pre-pass should engage below the " \
-                    "lowered boundary"
-            else:
-                assert not compacted
+        compacted.clear()
+        assert_cpu_and_tpu_equal(plan, conf=RapidsConf(), sort=False,
+                                 approx_float=1e-9)
+        if min_cap == 256:
+            assert compacted, \
+                "compact-wide pre-pass should engage below the " \
+                "lowered boundary"
+        else:
+            assert not compacted

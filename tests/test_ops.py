@@ -697,11 +697,11 @@ def test_parquet_footer_stats_feed_packed_keys(tmp_path):
     assert got["k"].tolist() == sorted(set(ks.tolist()))
 
 
-def test_groupby_wide_agg_list_chunks():
-    """>=7 aggregate columns at capacity >=32768 split into chunks of 6
-    (the libtpu AOT segfault workaround, ops/groupby.py _AOT_MAX_AGGS):
-    chunked results must be identical to the oracle — every chunk
-    re-sorts deterministically so group order matches across chunks."""
+def test_groupby_wide_agg_list_matches_oracle():
+    """Eight aggregate columns at capacity 32,768 on the sort path (the
+    shape an older libtpu could not compile whole, which a chunk loop
+    worked around until PR 29): one program, identical to the
+    oracle."""
     import jax
     import pandas as pd
 
@@ -719,7 +719,6 @@ def test_groupby_wide_agg_list_chunks():
         cols.append(Column(dt.INT64, jnp.asarray(v), None))
     b = ColumnarBatch(cols, n)
     aggs = [gb.AggSpec("sum", i + 1) for i in range(nagg)]
-    assert nagg > gb._AOT_MAX_AGGS and cap >= gb._AOT_CHUNK_MIN_CAP
     out, _types = gb.groupby_aggregate(b, [0], aggs,
                                        [dt.INT64] * (nagg + 1))
     ng = out.realized_num_rows()
@@ -815,10 +814,9 @@ def test_groupby_dense_matches_sort_path_all_ops():
             np.testing.assert_allclose(af, bf, rtol=1e-9, err_msg=c)
 
 
-def test_groupby_dense_wide_agg_list_skips_chunking():
-    """A wide agg list over a dense-eligible key space must NOT chunk
-    (the dense kernel never builds the module the AOT workaround guards
-    against) and must match pandas."""
+def test_groupby_dense_wide_agg_list_matches_pandas():
+    """A wide agg list over a dense-eligible key space must match
+    pandas."""
     from spark_rapids_tpu.ops import groupby as gb
 
     rng = np.random.default_rng(23)

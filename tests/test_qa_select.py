@@ -24,7 +24,6 @@ from tests.compare import assert_cpu_and_tpu_equal
 CONF = RapidsConf({
     "rapids.tpu.sql.test.enabled": True,
     "rapids.tpu.sql.incompatibleOps.enabled": True,
-    "rapids.tpu.sql.variableFloatAgg.enabled": True,
 })
 
 

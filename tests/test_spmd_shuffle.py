@@ -134,6 +134,39 @@ def test_sort_mesh_matches_single_and_cpu(n_dev):
                                    rtol=1e-9)
 
 
+def test_spmd_mesh_8_shard_bitexact():
+    """Join + group-by + sort of integers on the 8-shard mesh is
+    BIT-equal to the single-device run: what happens inside the
+    shard_map programs changes nothing observable."""
+    r = np.random.default_rng(23)
+    fact = pd.DataFrame({
+        "k": r.integers(0, 40, _N).astype(np.int64),
+        "v": r.integers(0, 1000, _N).astype(np.int64)})
+    dim = pd.DataFrame({"k": np.arange(40, dtype=np.int64),
+                        "w": (np.arange(40, dtype=np.int64) * 3) % 7})
+
+    def run(s):
+        try:
+            s.create_temp_view("fact", s.create_dataframe(fact))
+            s.create_temp_view("dim", s.create_dataframe(dim))
+            return s.sql(
+                "SELECT dim.w AS w, SUM(fact.v) AS sv, COUNT(*) AS c "
+                "FROM fact JOIN dim ON fact.k = dim.k "
+                "GROUP BY dim.w ORDER BY w").to_pandas()
+        finally:
+            s.stop()
+
+    single, mesh = run(Session()), run(_mesh_session(8))
+    oracle = fact.merge(dim, on="k").groupby("w").agg(
+        sv=("v", "sum"), c=("v", "count")).reset_index()
+    assert list(single.columns) == list(mesh.columns) == ["w", "sv", "c"]
+    for c in single.columns:
+        np.testing.assert_array_equal(single[c].to_numpy(),
+                                      mesh[c].to_numpy(), err_msg=c)
+        np.testing.assert_array_equal(single[c].to_numpy(),
+                                      oracle[c].to_numpy(), err_msg=c)
+
+
 def test_empty_partition_shards_match():
     """Fewer rows than devices: most mesh positions receive ZERO rows
     and the collectives must still line up (the all_to_all ships empty

@@ -225,3 +225,41 @@ def test_q3_partition_kernel_compiles_for_v5e_at_sf1_batch_shape(
     seconds = time.perf_counter() - t0
     assert compiled.memory_analysis().generated_code_size_in_bytes > 0
     assert seconds < 30.0, f"_partition_kernel compiled in {seconds:.1f} s"
+
+
+def test_wide_sort_path_groupby_compiles_for_v5e(one_chip,
+                                                 no_compile_cache):
+    """The sort-path ``_groupby`` (an int64 key with no host-known
+    range, so no dense layout) with nine aggregate columns at capacity
+    32,768: the shape at which a libtpu of 2026-07 crashed its compile
+    helper, and for which a chunked launch loop existed until PR 29.
+    One whole program is the only schedule now; this case is the fence
+    that would see the crash come back."""
+    import time
+
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.ops import groupby as gb
+    from spark_rapids_tpu.ops.groupby import AggSpec
+
+    cap = 32768
+    dtypes = (dt.INT64, dt.FLOAT64, dt.INT64)
+    aggs = (AggSpec("sum", 1), AggSpec("min", 1), AggSpec("max", 1),
+            AggSpec("count", 1), AggSpec("sum", 2), AggSpec("min", 2),
+            AggSpec("max", 2), AggSpec("count", 2),
+            AggSpec("count_star"))
+    assert gb._dense_layout(list(dtypes), [0], (None,), (False,)) is None
+
+    def rows(t):
+        return jax.ShapeDtypeStruct((cap,), t, sharding=one_chip)
+
+    cols = [(rows(jnp.int64), None), (rows(jnp.float64), rows(jnp.bool_)),
+            (rows(jnp.int64), rows(jnp.bool_))]
+    t0 = time.perf_counter()
+    compiled = gb._groupby.lower(
+        cols, dtypes, (0,), aggs,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        live_mask=rows(jnp.bool_), key_ranges=(None,),
+        dense_ok=True).compile()
+    seconds = time.perf_counter() - t0
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+    assert seconds < 120.0, f"_groupby compiled in {seconds:.1f} s"
