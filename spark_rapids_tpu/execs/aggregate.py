@@ -19,6 +19,7 @@ from spark_rapids_tpu.execs.base import TpuExec, timed
 from spark_rapids_tpu.expressions.base import (Alias, BoundReference,
                                                Expression)
 from spark_rapids_tpu.expressions.compiler import CompiledProjection
+from spark_rapids_tpu.ops.buckets import MIN_CAPACITY
 from spark_rapids_tpu.ops.concat import concat_batches
 from spark_rapids_tpu.ops.filter import rebucket
 from spark_rapids_tpu.ops.groupby import AggSpec, groupby_aggregate, \
@@ -321,13 +322,7 @@ class HashAggregateExec(TpuExec):
                 if b.realized_num_rows() == 0:
                     continue
                 saw_input = True
-                with TraceRange("HashAggregateExec.updateAgg"):
-                    part = self.update_partials(b)
-                if running is None:
-                    running = part
-                else:
-                    with TraceRange("HashAggregateExec.mergeAgg"):
-                        running = self.merge_partials(running, part)
+                running = self._fold(running, b)
             if running is None:
                 if self.grouping or (self.mode == "final" and not saw_input):
                     # grouped agg over empty input -> no rows (in the
@@ -351,8 +346,29 @@ class HashAggregateExec(TpuExec):
                     running = rebucket(running)
                 yield running
                 return
+            if self.final_proj is None and \
+                    running.capacity <= MIN_CAPACITY:
+                # partials bound for an exchange, too few to shrink: the
+                # count stays on the device (rebucket would fetch it to
+                # find nothing to do), and the consumer's one sync reads
+                # every partition's
+                yield running
+                return
             yield self.finalize_partials(running)
         return timed(self, it())
+
+    def _fold(self, running: Optional[ColumnarBatch],
+              b: ColumnarBatch) -> ColumnarBatch:
+        """One batch into the partition's running partials: an update
+        launch and, from the second batch on, a concat and a merge
+        launch. FusedAggregateExec overrides this with its one program
+        where the partials have a small static shape."""
+        with TraceRange("HashAggregateExec.updateAgg"):
+            part = self.update_partials(b)
+        if running is None:
+            return part
+        with TraceRange("HashAggregateExec.mergeAgg"):
+            return self.merge_partials(running, part)
 
     def _merge_schema(self) -> Schema:
         types = self._merge_types()

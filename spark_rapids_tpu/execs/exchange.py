@@ -254,10 +254,20 @@ class ShuffleExchangeExec(TpuExec):
 
                     def flush():
                         nonlocal chunk_bytes
-                        ColumnarBatch.realize_counts(chunk)
-                        self._write_blocks(
-                            (b for b in chunk
-                             if b.realized_num_rows() > 0), into=out)
+                        if self.partitioning[0] == "single" and \
+                                len(chunk) == 1:
+                            # a gather of a map task's one batch (an
+                            # aggregate's partials) decides nothing by
+                            # its count: it stays on the device, and
+                            # the consumer's one sync reads every map
+                            # task's (a consumer that fetched a count a
+                            # batch would pay what this did, no more)
+                            self._write_blocks(chunk, into=out)
+                        else:
+                            ColumnarBatch.realize_counts(chunk)
+                            self._write_blocks(
+                                (b for b in chunk
+                                 if b.realized_num_rows() > 0), into=out)
                         chunk.clear()
                         chunk_bytes = 0
 
@@ -477,11 +487,14 @@ class ShuffleExchangeExec(TpuExec):
         block's alone."""
         catalog = get_catalog()
         for b in source:
+            if isinstance(b.num_rows, int) and b.num_rows == 0:
+                continue
             with TraceRange("ShuffleExchangeExec.gather"):
                 if catalog.owns(b):
                     b = b.slice(0, b.realized_num_rows())
                 block.append(SpillableBatch(
-                    b, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY, catalog))
+                    b, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY, catalog,
+                    defer_count=True))
 
     def map_output_sizes(self) -> List[int]:
         """Per-reduce-partition byte sizes of the materialized map output

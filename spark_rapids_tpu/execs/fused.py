@@ -39,6 +39,7 @@ attacking dispatch count rather than per-op time.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from spark_rapids_tpu.utils import lockorder
 from functools import partial
@@ -60,9 +61,12 @@ from spark_rapids_tpu.expressions.base import (Alias, BoundReference, ColV,
                                                Literal, broadcast)
 from spark_rapids_tpu.expressions.compiler import (
     _unwrap_alias, derive_stats, fused_cache_get_or_build)
+from spark_rapids_tpu.ops import groupby as gb
 from spark_rapids_tpu.ops import hashing, sortkeys
 from spark_rapids_tpu.ops import join as join_ops
+from spark_rapids_tpu.ops.buckets import MIN_CAPACITY
 from spark_rapids_tpu.ops.join import _BUILD_NULL, _PROBE_NULL
+from spark_rapids_tpu.utils import tracing
 from spark_rapids_tpu.utils.tracing import TraceRange
 
 _MAXH = jnp.iinfo(jnp.int64).max
@@ -688,6 +692,15 @@ class FusedChain:
 
     def _build_program(self, compact_out: bool, modes: tuple = (),
                        decode: tuple = (), inline: tuple = ()):
+        run, label = self._trace_fn(compact_out, modes, decode, inline)
+        run.__name__ = run.__qualname__ = label
+        return partial(jax.jit, static_argnames=("types",))(run)
+
+    def _trace_fn(self, compact_out: bool, modes: tuple = (),
+                  decode: tuple = (), inline: tuple = ()):
+        """-> (the chain's traceable function, its label). A program of
+        its own through ``_build_program``; FusedAggregateExec traces it
+        into its one-launch step."""
         steps = self.steps
         sort_step = steps[-1] if steps and \
             isinstance(steps[-1], SortStep) else None
@@ -818,8 +831,7 @@ class FusedChain:
             ("decode+" if decode else "") + \
             "+".join(type(s).__name__.replace("Step", "").lower()
                      for s in steps) + f"]@{tag:04x}"
-        run.__name__ = run.__qualname__ = label
-        return partial(jax.jit, static_argnames=("types",))(run)
+        return run, label
 
     def run(self, batch, preps: List[PreparedBuild],
             compact_out: bool):
@@ -834,28 +846,9 @@ class FusedChain:
         same-bucket dispatches from concurrent queries coalesce into
         one physical program launch, and the shape-bucket registry logs
         the (program, bucket) observation for warmup/stats."""
-        from spark_rapids_tpu.execs import interop as _interop
-
-        states, final_ghosts = self._ghost_states(batch, preps)
-        build_ops = tuple(
-            (p.h_sorted, p.datas, p.vals, p.n_valid, p.table,
-             None if p.table is None else p.dense_lo)
-            for p in preps)
-        # dense/hash probe mode is per-build runtime information (key
-        # stats), so it keys the compiled program separately
-        modes = tuple(p.table is not None for p in preps)
-        aux = self._aux_from_states(states)
-        if isinstance(batch, _interop.PackedBatch):
-            decode = batch.decode_key()
-            prog = self._program(compact_out, modes, decode)
-            args = (tuple(batch.bufs), tuple(batch.dec_bases),
-                    batch.num_rows_device(), build_ops, aux)
-        else:
-            decode = ()
-            prog = self._program(compact_out, modes)
-            args = ([c.data for c in batch.columns],
-                    [c.validity for c in batch.columns],
-                    batch.num_rows_device(), build_ops, aux)
+        modes, decode, args, final_ghosts = self.call_args(
+            batch, preps, batch.num_rows_device())
+        prog = self._program(compact_out, modes, decode)
         statics = {"types": tuple(self.source_types)}
         ctx = _batching_ctx()
         key = None if ctx is None else \
@@ -877,6 +870,29 @@ class FusedChain:
             outs, live = ctx.batcher.call(key, prog, args, statics,
                                           ctx.query_id, ctx.multi)
         return outs, live, final_ghosts
+
+    def call_args(self, batch, preps: List[PreparedBuild], num_rows):
+        """-> (modes, decode, the chain function's positional arguments,
+        final output ghosts) for one batch; the ghost walk runs once."""
+        from spark_rapids_tpu.execs import interop as _interop
+
+        states, final_ghosts = self._ghost_states(batch, preps)
+        build_ops = tuple(
+            (p.h_sorted, p.datas, p.vals, p.n_valid, p.table,
+             None if p.table is None else p.dense_lo)
+            for p in preps)
+        # dense/hash probe mode is per-build runtime information (key
+        # stats), so it keys the compiled program separately
+        modes = tuple(p.table is not None for p in preps)
+        aux = self._aux_from_states(states)
+        if isinstance(batch, _interop.PackedBatch):
+            return modes, batch.decode_key(), \
+                (tuple(batch.bufs), tuple(batch.dec_bases), num_rows,
+                 build_ops, aux), final_ghosts
+        return modes, (), \
+            ([c.data for c in batch.columns],
+             [c.validity for c in batch.columns], num_rows, build_ops,
+             aux), final_ghosts
 
     def run_inline(self, batch, descs: tuple, raw_builds: Sequence,
                    build_ghosts: Sequence, compact_out: bool):
@@ -1344,6 +1360,28 @@ def _fused_metric_children(exec_):
     return exec_.children
 
 
+def _valid_lane(validity, capacity: int):
+    return jnp.ones(capacity, dtype=bool) if validity is None \
+        else validity
+
+
+def _same_dictionary(a, b) -> bool:
+    """A host compare, for dictionaries as small as a dense key's; two
+    large ones are the same only if they are one object."""
+    return a is b or (len(a) == len(b) <= gb._DENSE_MAX_GROUPS and
+                      bool(np.array_equal(a, b)))
+
+
+@functools.lru_cache(maxsize=64)
+def _identity_carry(dtypes: tuple, capacity: int):
+    """Running partials of no rows in the one-launch step's carry
+    layout, on the device once a process: what a partition's first
+    batch merges into."""
+    return ([(jax.device_put(np.zeros(capacity, dtype=np.dtype(t))),
+              jax.device_put(np.zeros(capacity, dtype=bool)))
+             for t in dtypes], np.int32(0))
+
+
 class _InlineDupFallback(Exception):
     """Internal: the speculative build-inlined first launch found
     duplicate build-key hashes. Raised out of
@@ -1421,6 +1459,189 @@ class FusedAggregateExec(agg_exec.HashAggregateExec):
             # ops); row-aligned, so the live-mask stays valid
             out = self.input_proj(out)
         return out, live
+
+    # -- one launch a batch --------------------------------------------
+    # Where the partials have a small static shape (no grouping keys, or
+    # keys in a dense layout) chain, update and merge are ONE program a
+    # batch: its arguments are the batch's columns, the row count as a
+    # host int32 and the running partials; its only results are the new
+    # running partials. The kernels are ops/groupby's own, traced into
+    # it: the update over the chain's outputs under its live mask, then
+    # the merge kernel over [running rows, this batch's rows], the rows
+    # and the order ``merge_partials`` hands it after its concat. What
+    # the code cannot observe to be that case keeps the three launches
+    # of ``HashAggregateExec._fold``; ``fused_agg.*`` counts which.
+
+    def _fold(self, running, b):
+        from spark_rapids_tpu.memory import retry as _retry
+        from spark_rapids_tpu.memory.fault_injection import get_injector
+
+        reason, plan = self._step_plan(running, b)
+        if reason is None:
+            try:
+                get_injector().maybe_inject("aggregate.step")
+                with TraceRange("FusedAggregateExec.step"):
+                    out = self._step(running, *plan)
+                tracing.count("fused_agg.engaged")
+                return out
+            except Exception as exc:
+                if not _retry.is_oom_error(exc):
+                    raise
+                # the ladder (spill, retry, halve) is _agg_batch's
+                reason = "oom"
+        tracing.count("fused_agg.fallback." + reason)
+        return super()._fold(running, b)
+
+    def _step_plan(self, running, b):
+        """-> (why this batch keeps the three launches, None) or (None,
+        what ``_step`` needs), from what the host can see before the
+        launch."""
+        if _batching_ctx() is not None:
+            return "batching", None     # the chain program coalesces
+        if self._preps_ok is not True:
+            return "inline_build", None
+        if not self._proj_in_chain:
+            return "eager_projection", None     # of string dictionaries
+        if not self.input_proj.exprs:
+            return "rows_only", None    # count(*) alone: no column to reduce
+        known = isinstance(b.num_rows, int)
+        call = self.chain.call_args(
+            b, self._preps,
+            np.int32(b.num_rows) if known else b.num_rows)
+        ghosts = self._partial_ghosts(call[3])
+        if running is not None and not all(
+                g is not None and _same_dictionary(c.dictionary,
+                                                   g.dictionary)
+                for c, g in zip(running.columns, ghosts)
+                if isinstance(c, StringColumn)):
+            return "dictionary", None
+        nkeys = len(self.grouping)
+        types = self._merge_types()
+        if not nkeys:
+            return None, (call, ghosts, types, (), MIN_CAPACITY)
+        if not self._dense_ok() and gb.order_sensitive(
+                self.first_specs, self.input_types):
+            return "sort_path", None
+        for i in range(nkeys):
+            g = ghosts[i]
+            if running is not None and g.stats is not None:
+                # a numeric key's range covers the carry's rows too
+                rs = running.columns[i].stats
+                ghosts[i] = _Ghost(g.dtype, None, None if rs is None else (
+                    min(g.stats[0], rs[0]), max(g.stats[1], rs[1])))
+        ranges = tuple(gb.key_range_of(ghosts[i], types[i])
+                       for i in range(nkeys))
+        # the carry's keys always have a validity lane: a slot for NULL
+        layout = gb._dense_layout(types, range(nkeys), ranges,
+                                  (True,) * nkeys)
+        if layout is None:
+            return "sort_path", None
+        return None, (call, ghosts, types, ranges, layout[3])
+
+    def _partial_ghosts(self, ghosts: List[_Ghost]) -> list:
+        """The host mirror of each column of the merge schema: a key's
+        own, an aggregate's input's where its partial keeps the input's
+        dictionary (min, max, first, last of a string), else None."""
+        out = list(ghosts[:len(self.grouping)])
+        for spec, t in zip(self.first_specs, self.partial_types):
+            out.append(ghosts[spec.ordinal]
+                       if t is dt.STRING and spec.ordinal >= 0 else None)
+        return out
+
+    def _step(self, running, call, ghosts, types, ranges, capacity):
+        modes, decode, args, _ = call
+        if running is None:
+            carry = _identity_carry(
+                tuple(t.np_dtype.str for t in types), capacity)
+        else:
+            n = running.num_rows
+            carry = ([(c.data, c.validity) for c in running.columns],
+                     np.int32(n) if isinstance(n, int) else n)
+        outs, n = self._step_program(modes, decode)(
+            args, carry, key_ranges=ranges,
+            types=tuple(self.chain.source_types))
+        cols: List[Column] = []
+        for (data, validity), g, t in zip(outs, ghosts, types):
+            if t is dt.STRING and g is not None:
+                cols.append(StringColumn(data, g.dictionary, validity))
+            else:
+                cols.append(Column(t, data, validity,
+                                   stats=None if g is None else g.stats))
+        return ColumnarBatch(cols, 1 if n is None else n)
+
+    def _step_program(self, modes: tuple, decode: tuple):
+        programs = self.chain._programs
+        prog = programs.get(("fused_agg", modes, decode))
+        if prog is None:
+            ckey = self.chain.chain_key(False, modes, decode)
+            key = None if ckey is None else (
+                "fused_agg", ckey, len(self.grouping),
+                tuple(self.first_specs), tuple(self.input_types),
+                tuple(self.merge_specs), tuple(self._merge_types()))
+            prog = programs[("fused_agg", modes, decode)] = \
+                fused_cache_get_or_build(
+                    key, lambda: self._build_step(modes, decode))
+        return prog
+
+    def _build_step(self, modes: tuple, decode: tuple):
+        chain_fn, chain_label = self.chain._trace_fn(False, modes, decode)
+        nkeys = len(self.grouping)
+        key_ords = tuple(range(nkeys))
+        in_types = tuple(self.input_types)
+        first = tuple(self.first_specs)
+        merge_types = tuple(self._merge_types())
+        merge = tuple(self.merge_specs)
+
+        def step(chain_args, carry, key_ranges, types):
+            outs, live = chain_fn(*chain_args, types=types)
+            num_rows = chain_args[2]
+            carry_cols, carry_n = carry
+            if nkeys:
+                (kd, kv), (ad, av), n = gb._groupby(
+                    list(outs), in_types, key_ords, first, num_rows,
+                    live_mask=live, key_ranges=key_ranges, dense_ok=True)
+                part = list(zip(kd, kv)) + list(zip(ad, av))
+            else:
+                ad, av = gb._reduce(list(outs), in_types, first,
+                                    num_rows, live)
+                # _reduce spreads its one row over the batch's capacity,
+                # and so does a carry that the three launches left
+                part = [(d[:1], None if v is None else v[:1])
+                        for d, v in zip(ad, av)]
+                carry_cols = [(d[:1], None if v is None else v[:1])
+                              for d, v in carry_cols]
+                n = 1
+            # the merge's input: the carry's rows, then this batch's
+            cc, pc = carry_cols[0][0].shape[0], part[0][0].shape[0]
+            rows = jnp.concatenate([
+                jnp.arange(cc, dtype=jnp.int32) < carry_n,
+                jnp.arange(pc, dtype=jnp.int32) < n])
+            cat = [(jnp.concatenate([cd, pd]),
+                    jnp.concatenate([_valid_lane(cv, cc),
+                                     _valid_lane(pv, pc)]))
+                   for (cd, cv), (pd, pv) in zip(carry_cols, part)]
+            if nkeys:
+                (kd, kv), (ad, av), n = gb._groupby(
+                    cat, merge_types, key_ords, merge,
+                    jnp.int32(cc + pc), live_mask=rows,
+                    key_ranges=key_ranges, dense_ok=True)
+                new = list(zip(kd, kv)) + list(zip(ad, av))
+            else:
+                ad, av = gb._reduce(cat, merge_types, merge,
+                                    jnp.int32(cc + pc), rows)
+                new = [(jnp.full(MIN_CAPACITY, d[0]),
+                        None if v is None else jnp.full(MIN_CAPACITY, v[0]))
+                       for d, v in zip(ad, av)]
+                n = None        # one row, known to the host
+            # every column leaves with a validity lane, whatever the
+            # kernel knew statically: the carry's structure is then the
+            # merge schema's alone and one program serves every batch
+            return [(d, _valid_lane(v, d.shape[0])) for d, v in new], n
+
+        step.__name__ = step.__qualname__ = \
+            "fused_agg[" + chain_label[len("fused_chain["):]
+        return partial(jax.jit,
+                       static_argnames=("key_ranges", "types"))(step)
 
     def execute(self, partition: int = 0) -> Iterator[ColumnarBatch]:
         if self._preps_ok is None and self._inline_enabled():

@@ -92,12 +92,15 @@ def quantize_range(lo: int, hi: int) -> Tuple[int, int]:
     return (qlo, qlo + p - 1)
 
 
-def key_range_of(col: Column, dtype: dt.DType) -> Optional[Tuple[int, int]]:
+def key_range_of(col, dtype: dt.DType) -> Optional[Tuple[int, int]]:
     """Host-known closed value range for packed-key grouping, if any
     (quantized — see quantize_range). String dictionaries and booleans
-    always have one; numerics only when the column carries stats."""
-    if isinstance(col, StringColumn):
-        return quantize_range(0, max(len(col.dictionary) - 1, 0))
+    always have one; numerics only when the column carries stats.
+    ``col`` is a column or its host mirror (a fused chain's ghost):
+    anything with a ``dictionary`` or ``stats``."""
+    dictionary = getattr(col, "dictionary", None)
+    if dictionary is not None:
+        return quantize_range(0, max(len(dictionary) - 1, 0))
     if dtype is dt.BOOLEAN:
         return (0, 1)
     if dtype.is_integral or dtype in (dt.DATE, dt.TIMESTAMP):
@@ -105,6 +108,17 @@ def key_range_of(col: Column, dtype: dt.DType) -> Optional[Tuple[int, int]]:
         if s is not None:
             return quantize_range(int(s[0]), int(s[1]))
     return None
+
+
+def order_sensitive(aggs: Sequence["AggSpec"],
+                    dtypes: Sequence[dt.DType]) -> bool:
+    """Does any aggregate's value depend on the shape of the reduction
+    tree (float sums and what is built from them)? The others (integer
+    sums, counts, min/max, first/last) are exact on either path."""
+    return any(spec.op in ("sum_of_squares", "m2", "rterm") or
+               (spec.op == "sum" and spec.ordinal >= 0 and
+                dtypes[spec.ordinal].is_floating)
+               for spec in aggs)
 
 
 def groupby_aggregate(batch: ColumnarBatch, key_ordinals: List[int],
@@ -129,11 +143,7 @@ def groupby_aggregate(batch: ColumnarBatch, key_ordinals: List[int],
     # reductions; integer sums/counts/min/max are exact regardless of
     # reduction-tree shape, so a grouping-set aggregate over those
     # keeps the dense path
-    if not dense_ok and not any(
-            spec.op in ("sum_of_squares", "m2", "rterm") or
-            (spec.op == "sum" and spec.ordinal >= 0 and
-             dtypes[spec.ordinal].is_floating)
-            for spec in aggs):
+    if not dense_ok and not order_sensitive(aggs, dtypes):
         dense_ok = True
     (key_d, key_v), (agg_d, agg_v), num_groups = _groupby(
         cols, tuple(dtypes), tuple(key_ordinals), tuple(aggs),

@@ -334,19 +334,22 @@ _LAUNCH_KEYS = ("jit_calls", "eager_op_calls", "transfers")
 def snapshot() -> dict:
     return {"jit_calls": _jit_calls, "eager_op_calls": _eager_calls,
             "transfers": _transfers, "spans": tracing.table(),
-            "queries": tracing.queries()}
+            "queries": tracing.queries(),
+            "counters": tracing.counters()}
 
 
 def delta(before: dict) -> dict:
     """Since ``before`` (a ``snapshot()``): the three launch counts and
     their sum ``dispatch_count``, the span table's rows that moved
     (``spans``: ``{name: {"count", "total_s", "self_s"}}``) and the
-    ``query`` roots closed (``queries``)."""
+    ``query`` roots closed (``queries``), and the program's counters that
+    moved (``counters``: ``{name: n}``)."""
     now = snapshot()
     d = {k: now[k] - before[k] for k in _LAUNCH_KEYS}
     d["dispatch_count"] = sum(d.values())
     d["spans"] = tracing.table_delta(before["spans"])
     d["queries"] = now["queries"] - before["queries"]
+    d["counters"] = tracing.counters_delta(before["counters"])
     return d
 
 

@@ -19,6 +19,10 @@ hundreds to thousands of launches, so a timer keeps no record of its own:
 it adds to a row (one a name) on the span it ran under, with no lock and
 no allocation, and the row reaches the table when that span closes.
 
+Beside the spans there are plain counters (:func:`count`: which way
+the program decided, a batch at a time), kept whether recording is on or
+off.
+
 Kept in memory only: a cumulative table ``{name: {"count", "total_s",
 "self_s"}}`` (:func:`table`; ``dispatch.snapshot()`` carries it) and the
 spans of the last :data:`RING_QUERIES` queries (:func:`profile`;
@@ -53,6 +57,7 @@ _tls = threading.local()
 _lock = lockorder.make_lock("utils.tracing.table")
 _table: dict = {}     # name -> [count, total_ns, self_ns]
 _roots = 0            # `query` roots closed so far
+_counters: dict = {}  # name -> n (`count`)
 _ring: collections.deque = collections.deque(maxlen=RING_QUERIES)
 _query_ids = itertools.count(1)
 
@@ -284,6 +289,26 @@ def table_delta(before: dict) -> dict:
 def queries() -> int:
     """``query`` roots closed so far."""
     return _roots
+
+
+def count(name: str, n: int = 1) -> None:
+    """A counter of the program: which way a decision went
+    (``fused_agg.engaged`` against ``fused_agg.fallback.<reason>``, one
+    a batch). Kept whether recording is on or not, since it reads no
+    clock; ``dispatch.snapshot()`` carries it as ``counters``."""
+    with _lock:
+        _counters[name] = _counters.get(name, 0) + n
+
+
+def counters() -> dict:
+    with _lock:
+        return dict(_counters)
+
+
+def counters_delta(before: dict) -> dict:
+    """Counters that moved since ``before`` (a ``counters()``)."""
+    return {name: n - before.get(name, 0)
+            for name, n in counters().items() if n != before.get(name, 0)}
 
 
 def _kept_parent(sp: Span) -> Optional[Span]:

@@ -319,7 +319,10 @@ def test_empty_input_through_the_gather(session):
     none = _keyed(df.filter(col("v") < 0))
     assert len(none.collect()) == 0
     (ex,) = _exchanges(none._last_exec)
-    assert ex.partitioning == ("single",) and ex._blocks == {0: []}
+    # a map task's one batch is handed over with its count still on the
+    # device (PR 30): four partials of no group, not no block
+    assert ex.partitioning == ("single",) and set(ex._blocks) == {0}
+    assert [sb.num_rows for sb in ex._blocks[0]] == [0, 0, 0, 0]
     total = df.filter(col("v") < 0).agg(F.sum(col("v")).alias("sv"),
                                         F.count("*").alias("n")).collect()
     assert total["n"].tolist() == [0] and total["sv"].isna().all()

@@ -121,10 +121,13 @@ def test_entry_step_compiles_for_v5e(one_chip, no_compile_cache):
 
 def test_q6_chain_compiles_for_v5e_at_sf1_batch_shape(
         one_chip, no_compile_cache, tmp_path, monkeypatch):
-    """TPC-H q6's fused decode+filter+project chain, caught from a real
-    ``Session.sql`` run over one sf 1 scan split (sf 1 lineitem is 6.0 M
-    rows in four files of 1.5 M: capacity 2,097,152 per batch), then
-    compiled for the described chip at exactly those shapes."""
+    """TPC-H q6's one launch a scanned batch (since PR 30 the fused
+    decode+filter+project chain, the keyless reduction and the merge into
+    the running partials are one program, ``fused_agg[decode+...]``),
+    caught from a real ``Session.sql`` run over one sf 1 scan split (sf 1
+    lineitem is 6.0 M rows in four files of 1.5 M: capacity 2,097,152 per
+    batch), then compiled for the described chip at exactly those
+    shapes."""
     import pyarrow.parquet as pq
 
     import chip_smoke
@@ -152,7 +155,7 @@ def test_q6_chain_compiles_for_v5e_at_sf1_batch_shape(
     assert len(out) == 1 and np.isfinite(out["revenue"].iloc[0])
 
     chains = [c for c in rec.calls
-              if c[0].__name__.startswith("fused_chain[decode+filter")]
+              if c[0].__name__.startswith("fused_agg[decode+filter")]
     assert chains, [c[0].__name__ for c in rec.calls]
     fn, kw, a, k = chains[0]
     shapes = {x.shape for x in jax.tree_util.tree_leaves((a, k))
@@ -161,6 +164,69 @@ def test_q6_chain_compiles_for_v5e_at_sf1_batch_shape(
     a, k = _described((a, k), one_chip)
     compiled = jax.jit(fn, **kw).lower(*a, **k).compile()
     assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+
+
+def test_q1_fused_step_compiles_for_v5e_at_sf1_batch_shape(
+        one_chip, no_compile_cache, tmp_path, monkeypatch):
+    """TPC-H q1's one launch a cached batch (PR 30): filter and
+    projection, the dense group-by of its eight aggregates and the merge
+    into the running partials, caught from a ``Session.sql`` run over a
+    small cached lineitem and compiled for the described chip with every
+    row-capacity argument at 2,097,152 (the program is one Python function
+    at any capacity). 4.7 s and 6 MB of code in this sandbox (PR 30), the
+    two 15-slot compaction sorts included; the limit is ten times that: a
+    sort-path group-by traced in by mistake takes minutes at this shape
+    (493 s, PR 27). Its only results are the running partials."""
+    import time
+
+    from spark_rapids_tpu.api import Session
+    from spark_rapids_tpu.benchmarks import datagen
+    from spark_rapids_tpu.expressions import compiler
+
+    datagen.write_tables(str(tmp_path), 0.002, tables=["lineitem"])
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "queries",
+            "q1.sql")) as f:
+        q1 = f.read()
+
+    rec = _JitRecorder(jax.jit)
+    monkeypatch.setattr(jax, "jit", rec)
+    monkeypatch.setattr(compiler, "_FUSED_CACHE", {})
+    s = Session()
+    s.read.parquet(str(tmp_path / "lineitem")).cache() \
+        .create_or_replace_temp_view("lineitem")
+    out = s.sql(q1).collect()
+    rec.recording = False
+    monkeypatch.undo()
+    s.stop()
+    assert len(out) == 6
+
+    steps = [c for c in rec.calls if c[0].__name__.startswith("fused_agg[")]
+    assert steps, [c[0].__name__ for c in rec.calls]
+    fn, kw, a, k = steps[0]
+    small = max(x.shape[0] for x in jax.tree_util.tree_leaves((a, k))
+                if getattr(x, "shape", ()))
+    cap = 2097152
+
+    def at_cell_size(x):
+        if isinstance(x, (jax.Array, np.ndarray, np.generic)):
+            return jax.ShapeDtypeStruct(
+                (cap,) if x.shape == (small,) else x.shape, x.dtype,
+                sharding=one_chip)
+        return x
+
+    a, k = jax.tree_util.tree_map(at_cell_size, (a, k))
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn, **kw).lower(*a, **k).compile()
+    seconds = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    assert mem.generated_code_size_in_bytes > 0
+    assert mem.argument_size_in_bytes >= cap * 38    # q1's columns alone
+    # keys, seven sums and four counts, a validity each, and the count
+    results = jax.tree_util.tree_leaves(compiled.out_info)
+    assert len(results) == 27 and \
+        all(r.shape in ((15,), ()) for r in results), results
+    assert seconds < 47.0, f"q1's fused step compiled in {seconds:.1f} s"
 
 
 def test_mesh_exchange_step_compiles_for_four_v5e_chips(

@@ -132,25 +132,47 @@ by_hash.collect()
 rec["hash_tree"] = by_hash.last_profile()
 
 # -- TPC-H q1 and q6 through Session.sql over lineitem cached in 2, 4 and
-#    8 partitions: the gather's launch fence
-import tempfile
+#    8 partitions of 1 and 2 batches each: the launch fence of the gather
+#    and of the one launch a cached batch
+import math, tempfile
 from spark_rapids_tpu.benchmarks import datagen
 tmp = tempfile.mkdtemp()
-datagen.write_tables(tmp, 0.002, tables=["lineitem"])
+datagen.write_tables(tmp, 0.01, tables=["lineitem", "orders", "customer"])
+rows = len(pd.read_parquet(tmp + "/lineitem"))
 rec["tpch"] = {}
-for parts in (2, 4, 8):
-    s = Session()
-    s.read.parquet(tmp + "/lineitem").repartition(parts).cache() \
-        .create_or_replace_temp_view("lineitem")
-    for name in ("q1", "q6"):
-        text = open(__ROOT__ + "/benchmark/queries/" + name + ".sql").read()
-        s.sql(text).collect()
-        df = s.sql(text)
-        pre = disp.snapshot()
-        df.collect()
-        rec["tpch"]["%s.%d" % (name, parts)] = {
-            "delta": disp.delta(pre), "tree": df.last_profile()}
-    s.stop()
+for batches in (1, 2):
+    for parts in (2, 4, 8):
+        s = Session({"rapids.tpu.sql.reader.batchSizeRows":
+                     math.ceil(rows / batches)})
+        s.read.parquet(tmp + "/lineitem").repartition(parts).cache() \
+            .create_or_replace_temp_view("lineitem")
+        for name in ("q1", "q6"):
+            text = open(__ROOT__ + "/benchmark/queries/" + name
+                        + ".sql").read()
+            s.sql(text).collect()
+            df = s.sql(text)
+            pre = disp.snapshot()
+            df.collect()
+            rec["tpch"]["%s.%d.%d" % (name, parts, batches)] = {
+                "delta": disp.delta(pre), "tree": df.last_profile()}
+        s.stop()
+
+# -- TPC-H Q3 over three cached tables, one task thread: a sort-path
+#    aggregate (an int64 key of thousands of groups) over a chain with two
+#    joins keeps the three launches a batch
+import chip_smoke
+s = Session({"rapids.tpu.sql.taskThreads": 1,
+             "rapids.tpu.sql.reader.batchSizeRows": 20000})
+for t in ("lineitem", "orders", "customer"):
+    df = s.read.parquet(tmp + "/" + t).repartition(2).cache()
+    df.create_or_replace_temp_view(t)
+    df.count()
+s.sql(chip_smoke.Q3).collect()
+df = s.sql(chip_smoke.Q3)
+pre = disp.snapshot()
+out = df.collect()
+rec["q3"] = {"delta": disp.delta(pre), "rows": len(out)}
+s.stop()
 print(json.dumps(rec))
 """
 
@@ -346,15 +368,19 @@ def test_slicing_an_exchanged_batch_is_one_launch(on):
         assert set(kids) == {"launch.jit"} and kids["launch.jit"] <= 2, kids
 
 
+@pytest.mark.parametrize("batches", [1, 2])
 @pytest.mark.parametrize("parts", [2, 4, 8])
-@pytest.mark.parametrize("stmt,per_partition,per_query",
-                         [("q1", 5, 5), ("q6", 6, 14)])
-def test_gather_launch_fence(on, stmt, per_partition, per_query, parts):
-    """TPC-H q1 and q6 over ``parts`` cached partitions: no span of an
-    exchange that moves rows, one ``ShuffleExchangeExec.gather`` a partition
-    with no launch beneath it, and launches a query within the law PR 28
-    measured (q1 15, 25, 45; q6 26, 38, 62)."""
-    run = on["tpch"]["%s.%d" % (stmt, parts)]
+@pytest.mark.parametrize("stmt,per_query", [("q1", 6), ("q6", 14)])
+def test_gather_launch_fence(on, stmt, per_query, parts, batches):
+    """TPC-H q1 and q6 over ``parts`` cached partitions of ``batches``
+    batches: no span of an exchange that moves rows, one
+    ``ShuffleExchangeExec.gather`` a partition with no launch beneath it,
+    and the law of PR 30: ONE launch a cached batch and the final side's
+    6 (q1: the coalesce's one fetch of every partition's count, its concat,
+    the final group-by and its row count, the sorting chain, the result
+    fetch) or 14 (q6) a query, where PR 28 measured 5 a partition and 5 (q1:
+    15, 25, 45) and 6 a partition and 14 (q6: 26, 38, 62) at one batch."""
+    run = on["tpch"]["%s.%d.%d" % (stmt, parts, batches)]
     tree, d = run["tree"], run["delta"]
     for name in _MOVING_SPANS + ("AdaptiveShuffleReaderExec.next",):
         assert not _named(tree, name), name
@@ -364,8 +390,47 @@ def test_gather_launch_fence(on, stmt, per_partition, per_query, parts):
     assert d["spans"]["ShuffleExchangeExec.gather"]["count"] == parts
     for g in gathers:
         assert g["children"] == [], g["children"]
-    assert d["dispatch_count"] <= per_partition * parts + per_query, d
+    assert d["dispatch_count"] <= parts * batches + per_query, d
     assert d["queries"] == 1
+    # a map task: its pulls of the cache and one jit call a batch, with no
+    # eager launch, no transfer (so no wait for the device between a
+    # partition's batches) and no concat beneath it
+    tasks = _named(tree, "FusedAggregateExec.next")
+    assert len(tasks) == parts
+    for task in tasks:
+        below = [n for n in _walk(task) if n is not task]
+        assert {n["name"] for n in below} == {
+            "CachedExec.next", "CachedExec.acquire",
+            "FusedAggregateExec.step", "launch.jit"}, below
+        steps = _named(task, "FusedAggregateExec.step")
+        assert len(steps) == batches
+        for step in steps:
+            (launch,) = step["children"]
+            assert (launch["name"], launch["count"]) == ("launch.jit", 1)
+    spans = d["spans"]
+    assert spans["FusedAggregateExec.step"]["count"] == parts * batches
+    for name in ("FusedAggregateExec.chain", "HashAggregateExec.mergeAgg"):
+        assert name not in spans, name
+    # the final aggregate's one update over the coalesced partials
+    assert spans["HashAggregateExec.updateAgg"]["count"] == 1
+    assert d["counters"] == {"fused_agg.engaged": parts * batches}
+
+
+def test_sort_path_aggregate_over_joins_keeps_its_launches(on):
+    """TPC-H Q3 over three cached tables (lineitem 2 partitions of 3
+    batches): its aggregate's partials have the batch's capacity, so every
+    batch keeps chain, update and merge, and the query the 63 = 30 + 23 + 10
+    launches the parent of PR 30 counts for the same script."""
+    d = on["q3"]["delta"]
+    assert on["q3"]["rows"] == 10
+    assert d["counters"] == {"fused_agg.fallback.inline_build": 1,
+                             "fused_agg.fallback.sort_path": 5}
+    assert (d["jit_calls"], d["eager_op_calls"], d["transfers"]) == \
+        (30, 23, 10), d
+    spans = d["spans"]
+    assert "FusedAggregateExec.step" not in spans
+    assert spans["FusedAggregateExec.chain"]["count"] == 5
+    assert spans["HashAggregateExec.mergeAgg"]["count"] == 4
 
 
 def test_q1_table_is_the_tree(on):
@@ -426,7 +491,9 @@ def test_eager_count_when_install_follows_import_jax():
 def test_delta_has_spans_and_queries(on):
     d = on["q1_delta"]
     assert set(d) == {"jit_calls", "eager_op_calls", "transfers",
-                      "dispatch_count", "spans", "queries"}
+                      "dispatch_count", "spans", "queries", "counters"}
+    # three cached batches whose int key lost its range in the repartition
+    assert d["counters"] == {"fused_agg.fallback.sort_path": 3}
     assert d["dispatch_count"] == \
         d["jit_calls"] + d["eager_op_calls"] + d["transfers"]
     assert d["eager_op_calls"] > 0
