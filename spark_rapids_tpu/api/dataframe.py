@@ -319,13 +319,25 @@ class DataFrame:
         self._last_exec = apply_overrides(self._plan, self.session.conf)
         return self._last_exec
 
-    def collect(self):
+    def _run(self, plan_exec):
+        """The root of a query: plan (``plan_exec`` returns the physical
+        tree), run every partition, fetch the frame; then, fetched or
+        raised, the blocks the tree's exchanges registered for this
+        execution end with it (``execs/exchange.close_query_blocks``)."""
         from spark_rapids_tpu.execs.base import collect
+        from spark_rapids_tpu.execs.exchange import close_query_blocks
 
         with tracing.QueryRange() as query:
-            out = collect(self._exec(), conf=self.session.conf)
+            exec_ = plan_exec()
+            try:
+                out = collect(exec_, conf=self.session.conf)
+            finally:
+                close_query_blocks(exec_)
         self._last_query = query.query_id
         return out
+
+    def collect(self):
+        return self._run(self._exec)
 
     def collect_async(self, tenant: str = "default", priority: int = 0,
                       deadline=None):
@@ -366,13 +378,9 @@ class DataFrame:
 
         plan = pn.AggregateNode(
             [], [pn.AggCall(A.Count(None), "count")], self._plan)
-        from spark_rapids_tpu.execs.base import collect
         from spark_rapids_tpu.plan.overrides import apply_overrides
 
-        with tracing.QueryRange() as query:
-            df = collect(apply_overrides(plan, self.session.conf),
-                         conf=self.session.conf)
-        self._last_query = query.query_id
+        df = self._run(lambda: apply_overrides(plan, self.session.conf))
         return int(df["count"].iloc[0])
 
     def show(self, n: int = 20) -> None:  # pragma: no cover - console
@@ -504,14 +512,14 @@ class DataFrameWriter:
     partitionBy = partition_by
 
     def _write(self, path: str, fmt: str):
-        from spark_rapids_tpu.execs.base import collect
         from spark_rapids_tpu.io.write import WriteFilesNode
         from spark_rapids_tpu.plan.overrides import apply_overrides
 
         node = WriteFilesNode(self.df._plan, path, format=fmt,
                               partition_by=self._partition_by,
                               mode=self._mode)
-        return collect(apply_overrides(node, self.df.session.conf))
+        return self.df._run(
+            lambda: apply_overrides(node, self.df.session.conf))
 
     def parquet(self, path: str):
         return self._write(path, "parquet")

@@ -135,6 +135,26 @@ class TpuExec:
         return out
 
 
+def all_execs(root: TpuExec) -> Iterator[TpuExec]:
+    """Every exec of a tree, each once: ``children``, and what a fused
+    exec keeps beside them (its broadcast ``builds``, the unfused
+    ``fallback`` subtree). After execution the tree is the one that ran:
+    an adaptive join has put the plan it decided on in its children's
+    place."""
+    stack, seen = [root], set()
+    while stack:
+        e = stack.pop()
+        if id(e) in seen:
+            continue
+        seen.add(id(e))
+        yield e
+        stack.extend(e.children)
+        stack.extend(getattr(e, "builds", None) or ())
+        fallback = getattr(e, "fallback", None)
+        if fallback is not None:
+            stack.append(fallback)
+
+
 def timed(owner, it: Iterator[ColumnarBatch]
           ) -> Iterator[ColumnarBatch]:
     """Wrap an exec's output iterator with metric recording. ``owner`` is

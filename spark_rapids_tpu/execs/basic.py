@@ -161,7 +161,9 @@ class RangeExec(TpuExec):
 
 
 class LocalLimitExec(TpuExec):
-    """Slices batches until n rows have been emitted (per partition)."""
+    """Slices batches until n rows have been emitted (per partition).
+    Span ``LocalLimitExec.limit``, one a batch: the fetch of its row count
+    (a top-N's wait for the sorted batch lands here) and the cut."""
 
     def __init__(self, n: int, child: TpuExec):
         super().__init__([child], child.schema)
@@ -173,13 +175,12 @@ class LocalLimitExec(TpuExec):
             for b in self.children[0].execute(partition):
                 if remaining <= 0:
                     break
-                rows = b.realized_num_rows()
-                if rows <= remaining:
-                    remaining -= rows
-                    yield b
-                else:
-                    yield b.slice(0, remaining)
-                    remaining = 0
+                with TraceRange("LocalLimitExec.limit"):
+                    rows = b.realized_num_rows()
+                    if rows > remaining:
+                        b = b.slice(0, remaining)
+                remaining -= min(rows, remaining)
+                yield b
         return timed(self, it())
 
 

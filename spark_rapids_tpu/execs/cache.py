@@ -16,6 +16,7 @@ from typing import Dict, Iterator, List, Optional
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
 from spark_rapids_tpu.execs.base import TpuExec, timed
 from spark_rapids_tpu.memory import priorities
+from spark_rapids_tpu.memory.catalog import set_buffer_owner
 from spark_rapids_tpu.memory.spillable import SpillableBatch
 from spark_rapids_tpu.plan.nodes import PlanNode
 from spark_rapids_tpu.utils.tracing import TraceRange
@@ -59,8 +60,15 @@ class CacheHolder:
                 for b in child.execute(p):
                     if b.realized_num_rows() == 0:
                         continue
-                    handles.append(SpillableBatch(
-                        b, priorities.INPUT_FROM_SHUFFLE_PRIORITY))
+                    # the cache outlives the query that fills it: no
+                    # query's owner tag on its registrations (the service
+                    # sweeps a query's tag when the query ends)
+                    prev = set_buffer_owner(None)
+                    try:
+                        handles.append(SpillableBatch(
+                            b, priorities.INPUT_FROM_SHUFFLE_PRIORITY))
+                    finally:
+                        set_buffer_owner(prev)
                 parts[p] = handles
             self._parts = parts
 

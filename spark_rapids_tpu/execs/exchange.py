@@ -9,6 +9,18 @@ readers take local device hits zero-copy (RapidsCachingReader.scala:59-145).
 Single-process version: the shuffle "transport" is a per-exec block store of
 SpillableBatch handles (the local-catalog-hit path). The multi-host bulk
 path rides the mesh all_to_all in parallel/shuffle.py.
+
+Blocks end with the query. Every batch an exchange registers goes through
+its ``_register`` (counter ``exchange.blocks.registered``), and the root
+that ran the plan (``DataFrame.collect()``/``count()``/a write, the query
+service's finalize, a standing query's close) calls
+:func:`close_query_blocks` once its result is fetched or the plan raised:
+every registration of every exchange in the tree is removed from the
+catalog, whichever tier it sits on (``exchange.blocks.closed``), and the
+exchange is unmaterialized again, so a tree that is executed once more
+runs its map side once more. Only the exchange's own registrations go:
+a batch that a ``CachedExec`` also registered (a cache filled from these
+blocks) stays the cache's, arrays and all.
 """
 from __future__ import annotations
 
@@ -19,14 +31,14 @@ from typing import Dict, Iterator, List, Optional, Tuple
 import numpy as np
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
-from spark_rapids_tpu.execs.base import TpuExec, timed
+from spark_rapids_tpu.execs.base import TpuExec, all_execs, timed
 from spark_rapids_tpu.memory import priorities
 from spark_rapids_tpu.memory.catalog import get_catalog
 from spark_rapids_tpu.memory.spillable import SpillableBatch
 from spark_rapids_tpu.ops import partition as part_ops
 from spark_rapids_tpu.ops.concat import concat_batches
 from spark_rapids_tpu.ops.sortkeys import SortKeySpec
-from spark_rapids_tpu.utils.tracing import TraceRange
+from spark_rapids_tpu.utils.tracing import TraceRange, count
 
 
 def partition_batch(b: ColumnarBatch, partitioning: Tuple, types,
@@ -89,6 +101,10 @@ class ShuffleExchangeExec(TpuExec):
         self.task_threads = task_threads
         # block store: output partition -> spillable sub-batches
         self._blocks: Optional[Dict[int, List[SpillableBatch]]] = None
+        # every registration this exchange made for the execution in
+        # flight, blocks and staged range input alike, whether or not the
+        # map side got as far as ``_blocks``: what ``close_blocks`` removes
+        self._registered: List[SpillableBatch] = []
         # in-program mode (SPMD whole-stage exchange): the map side runs
         # as ONE compiled hash-route + all_to_all program over the mesh
         # instead of per-batch partition kernels + per-partition slices.
@@ -120,6 +136,7 @@ class ShuffleExchangeExec(TpuExec):
         state.pop("_mat_lock", None)
         state["_mat_running"] = False
         state["_blocks"] = None
+        state["_registered"] = []
         # meshes are process-local device handles; a shipped exchange
         # re-decides on the receiving side (cluster mode shuffles over
         # TCP anyway — the spmd gate never enables both)
@@ -175,6 +192,25 @@ class ShuffleExchangeExec(TpuExec):
                 self._materialize()
         return self.num_out_partitions
 
+    def _register(self, batch: ColumnarBatch, priority: int,
+                  **kw) -> SpillableBatch:
+        """The one way this exchange puts a batch into the catalog."""
+        sb = SpillableBatch(batch, priority, **kw)
+        self._registered.append(sb)      # map tasks append concurrently
+        count("exchange.blocks.registered")
+        return sb
+
+    def close_blocks(self) -> None:
+        """Remove every registration of the execution that just ended
+        (or failed half way) from the catalog and forget the map side:
+        the next ``execute`` materializes again. A handle a reader still
+        holds acquired goes at its release (``BufferCatalog.remove``)."""
+        registered, self._registered = self._registered, []
+        self._blocks = None
+        closed = sum(sb.close() for sb in registered)
+        if closed:
+            count("exchange.blocks.closed", closed)
+
     def _partition_batch(self, b: ColumnarBatch
                          ) -> Tuple[ColumnarBatch, np.ndarray]:
         return partition_batch(b, self.partitioning,
@@ -204,7 +240,7 @@ class ShuffleExchangeExec(TpuExec):
                 from spark_rapids_tpu.execs.base import run_partitions
 
                 def stage_task(in_p: int):
-                    return [SpillableBatch(
+                    return [self._register(
                         b, priorities.INPUT_FROM_SHUFFLE_PRIORITY)
                         for b in self.children[0].execute(in_p)
                         if b.realized_num_rows() > 0]
@@ -413,7 +449,7 @@ class ShuffleExchangeExec(TpuExec):
                     hd[ci][seg][idx], t,
                     validity=hv[ci][seg][idx],
                     capacity=cap) for ci, t in enumerate(types)]
-                blocks[p].append(SpillableBatch(
+                blocks[p].append(self._register(
                     ColumnarBatch(cols, len(idx)),
                     priorities.OUTPUT_FOR_SHUFFLE_PRIORITY))
         return blocks
@@ -467,12 +503,11 @@ class ShuffleExchangeExec(TpuExec):
                     for p, sub in enumerate(subs):
                         if sub is None:
                             continue
-                        blocks[p].append(SpillableBatch(
+                        blocks[p].append(self._register(
                             sub, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY))
         return blocks
 
-    @staticmethod
-    def _gather(source, block: List[SpillableBatch]) -> None:
+    def _gather(self, source, block: List[SpillableBatch]) -> None:
         """``("single",)`` moves no rows: the batch a map task is handed
         IS its block, with no partition kernel, no slice, no launch and
         no transfer. Who owns the arrays: a batch fresh from its producer
@@ -492,8 +527,8 @@ class ShuffleExchangeExec(TpuExec):
             with TraceRange("ShuffleExchangeExec.gather"):
                 if catalog.owns(b):
                     b = b.slice(0, b.realized_num_rows())
-                block.append(SpillableBatch(
-                    b, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY, catalog,
+                block.append(self._register(
+                    b, priorities.OUTPUT_FOR_SHUFFLE_PRIORITY,
                     defer_count=True))
 
     def map_output_sizes(self) -> List[int]:
@@ -518,7 +553,8 @@ class ShuffleExchangeExec(TpuExec):
         for sb in staged:
             with sb.acquired() as b:
                 yield b
-            sb.close()
+            if sb.close():
+                count("exchange.blocks.closed")
 
     def execute(self, partition: int = 0) -> Iterator[ColumnarBatch]:
         def it():
@@ -591,10 +627,27 @@ class BroadcastExchangeExec(TpuExec):
             self._cached = SpillableBatch(
                 merged, priorities.INPUT_FROM_SHUFFLE_PRIORITY,
                 defer_count=True)
+            count("exchange.blocks.registered")
         return self._cached
+
+    def close_blocks(self) -> None:
+        """As ``ShuffleExchangeExec.close_blocks``: the broadcast batch's
+        registration goes, the next ``execute`` materializes again."""
+        cached, self._cached = self._cached, None
+        if cached is not None and cached.close():
+            count("exchange.blocks.closed")
 
     def execute(self, partition: int = 0) -> Iterator[ColumnarBatch]:
         def it():
             with self._materialize().acquired() as batch:
                 yield batch
         return timed(self, it())
+
+
+def close_query_blocks(root: TpuExec) -> None:
+    """What the root of a query calls when its result is fetched or the
+    plan raised: every exchange of the tree that ran (``all_execs``)
+    closes its blocks."""
+    for e in all_execs(root):
+        if isinstance(e, (ShuffleExchangeExec, BroadcastExchangeExec)):
+            e.close_blocks()

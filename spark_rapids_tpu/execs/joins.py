@@ -25,7 +25,7 @@ from spark_rapids_tpu.expressions.base import Expression
 from spark_rapids_tpu.expressions.compiler import CompiledFilter
 from spark_rapids_tpu.ops.join import (cross_join, equi_join,
                                        nested_loop_join, prepare_build)
-from spark_rapids_tpu.utils.tracing import TraceRange
+from spark_rapids_tpu.utils.tracing import TraceRange, count
 
 _KIND_MAP = {"inner": "inner", "left": "left", "left_semi": "leftsemi",
              "left_anti": "leftanti", "full": "full"}
@@ -42,7 +42,20 @@ class HashJoinExec(TpuExec):
     sides hash-bucket by join key into spillable slices (matching rows
     share a bucket by construction) and each bucket joins independently
     at a bounded size — the sort exec's range-bucket pattern applied to
-    the join build."""
+    the join build.
+
+    Spans, once a partition: ``HashJoinExec.build`` (the build side
+    staged, concatenated and prepared) over ``HashJoinExec.buildStage``
+    (the build child drained into spillable chunks; an exchange's map
+    side runs under the first partition's unless an adaptive join
+    materialized it to decide) and ``HashJoinExec.buildPrepare``
+    (concat, key range, hash and sort);
+    then ``HashJoinExec.<kind>`` a stream batch. Counters, a partition
+    each: ``join.build.hash``, ``join.build.dense``, ``join.oob`` (which
+    way the build went), ``join.build_rows``; a stream batch each:
+    ``join.probe_rows``, and ``join.out_rows`` only where an output's
+    count is on the host already: an inner join's stays on the device
+    (``_compact_pairs``) and is left out, no fetch is made for it."""
 
     def __init__(self, kind: str, left: TpuExec, right: TpuExec,
                  left_keys: List[int], right_keys: List[int],
@@ -155,15 +168,14 @@ class HashJoinExec(TpuExec):
         right_types = list(self.children[1].schema.types)
 
         def it():
-            build_staged, build_total = self._stage(1, partition)
             budget = self._budget_rows()
-            if build_total > budget:
+            build_staged, build_total, build, prepared = self._build(
+                partition, budget, left_types, right_types)
+            if build is None:
                 yield from self._out_of_core(partition, build_staged,
                                              build_total, budget,
                                              left_types, right_types)
                 return
-            build = self._concat_staged(build_staged,
-                                        self.children[1].schema)
             if self.kind == "full":
                 # unmatched-build rows are emitted exactly once, so the
                 # stream side must arrive as one batch
@@ -172,29 +184,57 @@ class HashJoinExec(TpuExec):
                     stream_staged, self.children[0].schema)]
             else:
                 stream_batches = self.children[0].execute(partition)
-            # build-once/probe-many: hash + sort a single time, reused
-            # by every stream batch below (None when a join key is a
-            # string column).
-            # With the AQE dense hint armed, a measured-narrow key range
-            # upgrades the probe to a direct slot lookup instead.
-            prepared = self._dense_prepared(build, left_types,
-                                            right_types)
-            if prepared is None:
-                prepared = prepare_build(
-                    build, self.right_keys, right_types,
-                    [left_types[o] for o in self.left_keys])
             saw = False
             for b in stream_batches:
-                if b.realized_num_rows() == 0 and saw:
+                rows = b.realized_num_rows()
+                if rows == 0 and saw:
                     continue
                 saw = True
+                count("join.probe_rows", rows)
                 with TraceRange(f"HashJoinExec.{self.kind}"):
                     outs = self._probe_retry(b, build, left_types,
                                              right_types,
                                              tag="join.probe",
                                              prepared=prepared)
+                self._count_out(outs)
                 yield from outs
         return timed(self, it())
+
+    def _build(self, partition: int, budget: int, left_types, right_types):
+        """One partition's build side: (staged, rows, build, prepared),
+        ``build`` None where the rows pass ``budget`` and the join goes
+        bucket by bucket (the staged chunks are then the caller's)."""
+        with TraceRange("HashJoinExec.build"):
+            with TraceRange("HashJoinExec.buildStage"):
+                staged, total = self._stage(1, partition)
+            count("join.build_rows", total)
+            if total > budget:
+                count("join.oob")
+                return staged, total, None, None
+            with TraceRange("HashJoinExec.buildPrepare"):
+                build = self._concat_staged(staged,
+                                            self.children[1].schema)
+                # build-once/probe-many: hash + sort a single time,
+                # reused by every stream batch (None when a join key is
+                # a string column). With the AQE dense hint armed, a
+                # measured-narrow key range upgrades the probe to a
+                # direct slot lookup instead.
+                prepared = self._dense_prepared(build, left_types,
+                                                right_types)
+                count("join.build.dense" if prepared is not None
+                      else "join.build.hash")
+                if prepared is None:
+                    prepared = prepare_build(
+                        build, self.right_keys, right_types,
+                        [left_types[o] for o in self.left_keys])
+        return staged, total, build, prepared
+
+    @staticmethod
+    def _count_out(outs) -> None:
+        rows = sum(o.num_rows for o in outs
+                   if isinstance(o.num_rows, int))
+        if rows:
+            count("join.out_rows", rows)
 
     def _dense_prepared(self, build: ColumnarBatch, left_types,
                         right_types):
@@ -292,10 +332,12 @@ class HashJoinExec(TpuExec):
                 continue
             build_b = self._concat_staged(build_buckets[p],
                                           self.children[1].schema)
+            count("join.probe_rows", stream_b.realized_num_rows())
             with TraceRange(f"HashJoinExec.oob.{self.kind}"):
                 outs = self._probe_retry(stream_b, build_b, left_types,
                                          right_types,
                                          tag="join.oob.probe")
+            self._count_out(outs)
             emitted = True
             yield from outs
         if not emitted:

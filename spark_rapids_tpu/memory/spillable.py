@@ -85,10 +85,13 @@ class SpillableBatch:
     def update_priority(self, priority: int) -> None:
         self._catalog.update_priority(self._id, priority)
 
-    def close(self) -> None:
-        if not self._closed:
-            self._catalog.remove(self._id)
-            self._closed = True
+    def close(self) -> bool:
+        """Remove the registration; True for the call that did it."""
+        if self._closed:
+            return False
+        self._catalog.remove(self._id)
+        self._closed = True
+        return True
 
     def __enter__(self) -> "SpillableBatch":
         return self

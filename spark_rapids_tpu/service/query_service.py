@@ -19,6 +19,7 @@ from typing import Dict, Optional
 
 from spark_rapids_tpu import config as cfg
 from spark_rapids_tpu.config import RapidsConf
+from spark_rapids_tpu.execs.exchange import close_query_blocks
 from spark_rapids_tpu.memory.catalog import get_catalog
 from spark_rapids_tpu.service.admission import (AdmissionController,
                                                 parse_fairness_weights)
@@ -763,6 +764,11 @@ class QueryService:
         # charge, catalog buffers (an abandoned exec tree must not leak
         # staged batches), and its execution cursor
         self.admission.release(q)
+        if q.exec is not None:
+            # the tree's exchange blocks end with the query, as under
+            # DataFrame.collect(); the owner sweep takes what else the
+            # slices registered (staged join sides, sort runs)
+            close_query_blocks(q.exec)
         get_catalog().remove_owner(q.owner_tag)
         # drop the heavy execution state: the retention registry keeps
         # up to FINISHED_RETENTION terminal queries for stats history,

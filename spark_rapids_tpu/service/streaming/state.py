@@ -32,6 +32,10 @@ from __future__ import annotations
 
 from typing import Optional
 
+from spark_rapids_tpu.execs.base import all_execs
+from spark_rapids_tpu.execs.exchange import (BroadcastExchangeExec,
+                                             ShuffleExchangeExec,
+                                             close_query_blocks)
 from spark_rapids_tpu.memory.catalog import (StorageTier, get_catalog,
                                              set_buffer_owner)
 from spark_rapids_tpu.memory.priorities import STREAMING_STATE_PRIORITY
@@ -219,6 +223,7 @@ class StreamingAggregateState:
         if self._running is not None:
             self._running.close()
             self._running = None
+        close_query_blocks(self._child_exec)
         get_catalog().remove_owner(self.owner_tag)
 
     # -- per-fold exec-state reset -------------------------------------
@@ -238,38 +243,19 @@ class StreamingAggregateState:
         so build tables stay resident across folds."""
         from spark_rapids_tpu.execs.adaptive import \
             AdaptiveShuffleReaderExec
-        from spark_rapids_tpu.execs.exchange import (
-            BroadcastExchangeExec, ShuffleExchangeExec)
         from spark_rapids_tpu.execs.fused import FusedChainExec
 
         memo: dict = {}
-        stack = [self._child_exec]
-        seen: set = set()
-        while stack:
-            e = stack.pop()
-            if id(e) in seen:
-                continue
-            seen.add(id(e))
-            if isinstance(e, ShuffleExchangeExec) and \
-                    e._blocks is not None and \
+        for e in all_execs(self._child_exec):
+            if isinstance(e, (ShuffleExchangeExec,
+                              BroadcastExchangeExec)) and \
                     self._reaches_delta(e, memo):
-                for handles in e._blocks.values():
-                    for h in handles:
-                        h.close()
-                e._blocks = None
-            elif isinstance(e, BroadcastExchangeExec) and \
-                    e._cached is not None and \
-                    self._reaches_delta(e, memo):
-                e._cached.close()
-                e._cached = None
+                e.close_blocks()
             elif isinstance(e, AdaptiveShuffleReaderExec) and \
                     self._reaches_delta(e, memo):
                 e._groups = None
-            elif isinstance(e, FusedChainExec):
-                if any(self._reaches_delta(b, memo) for b in e.builds):
-                    with e._prep_lock:
-                        e._preps = None
-                        e._preps_ok = None
-                stack.append(e.fallback)
-                stack.extend(e.builds)
-            stack.extend(getattr(e, "children", ()))
+            elif isinstance(e, FusedChainExec) and any(
+                    self._reaches_delta(b, memo) for b in e.builds):
+                with e._prep_lock:
+                    e._preps = None
+                    e._preps_ok = None

@@ -24,7 +24,8 @@ from spark_rapids_tpu.execs.aggregate import HashAggregateExec
 from spark_rapids_tpu.execs.base import collect
 from spark_rapids_tpu.execs.batching import CoalesceBatchesExec, TargetSize
 from spark_rapids_tpu.execs.cache import CachedExec
-from spark_rapids_tpu.execs.exchange import ShuffleExchangeExec
+from spark_rapids_tpu.execs.exchange import (ShuffleExchangeExec,
+                                             close_query_blocks)
 from spark_rapids_tpu.execs.joins import ShuffledHashJoinExec
 from spark_rapids_tpu.execs.sort import SortExec
 from spark_rapids_tpu.execs.window import WindowExec
@@ -318,11 +319,15 @@ def test_empty_input_through_the_gather(session):
     df = _cached(session, pdf, 4)
     none = _keyed(df.filter(col("v") < 0))
     assert len(none.collect()) == 0
-    (ex,) = _exchanges(none._last_exec)
+    # collect() closes a query's blocks: look at them under the exec layer's
+    tree = none._exec()
+    assert len(collect(tree, conf=session.conf)) == 0
+    (ex,) = _exchanges(tree)
     # a map task's one batch is handed over with its count still on the
     # device (PR 30): four partials of no group, not no block
     assert ex.partitioning == ("single",) and set(ex._blocks) == {0}
     assert [sb.num_rows for sb in ex._blocks[0]] == [0, 0, 0, 0]
+    _close_blocks(tree)
     total = df.filter(col("v") < 0).agg(F.sum(col("v")).alias("sv"),
                                         F.count("*").alias("n")).collect()
     assert total["n"].tolist() == [0] and total["sv"].isna().all()
@@ -337,8 +342,11 @@ def test_every_partition_but_one_empty(session):
     assert df.count() == 500
     q = _keyed(df)
     _assert_matches(q, pdf)
-    (ex,) = _exchanges(q._last_exec)
+    tree = q._exec()
+    collect(tree, conf=session.conf)
+    (ex,) = _exchanges(tree)
     assert len(ex._blocks[0]) == 1
+    _close_blocks(tree)
 
 
 def test_null_keys_and_null_values_through_the_gather(session):
@@ -419,12 +427,8 @@ def _owners(catalog, array) -> int:
 
 
 def _close_blocks(tree):
-    for ex in _find(tree, ShuffleExchangeExec):
-        if ex._blocks:
-            for handles in ex._blocks.values():
-                for h in handles:
-                    h.close()
-            ex._blocks = None
+    close_query_blocks(tree)
+    assert all(ex._blocks is None for ex in _find(tree, ShuffleExchangeExec))
 
 
 @pytest.mark.parametrize("pull", ["limit", "sort"])

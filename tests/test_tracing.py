@@ -169,9 +169,41 @@ for t in ("lineitem", "orders", "customer"):
     df.count()
 s.sql(chip_smoke.Q3).collect()
 df = s.sql(chip_smoke.Q3)
+from spark_rapids_tpu.memory.catalog import get_catalog
+held = len(get_catalog())
 pre = disp.snapshot()
 out = df.collect()
-rec["q3"] = {"delta": disp.delta(pre), "rows": len(out)}
+rec["q3"] = {"delta": disp.delta(pre), "rows": len(out),
+             "tree": df.last_profile(),
+             "catalog": [held, len(get_catalog())]}
+s.stop()
+
+# -- the same statement where orders is too large to broadcast (as at sf 1):
+#    customer's build is inlined into the chain over orders, orders and
+#    lineitem meet in a shuffled hash join over two exchanges that move rows
+from spark_rapids_tpu.execs.joins import HashJoinExec
+s = Session({"rapids.tpu.sql.taskThreads": 1,
+             "rapids.tpu.sql.reader.batchSizeRows": 20000,
+             "rapids.tpu.sql.autoBroadcastJoinThreshold": "50k"})
+for t in ("lineitem", "orders", "customer"):
+    df = s.read.parquet(tmp + "/" + t).repartition(2).cache()
+    df.create_or_replace_temp_view(t)
+    df.count()
+s.sql(chip_smoke.Q3).collect()
+df = s.sql(chip_smoke.Q3)
+held = len(get_catalog())
+pre = disp.snapshot()
+out = df.collect()
+def joins_of(e):
+    found = [e] if isinstance(e, HashJoinExec) else []
+    for c in e.children:
+        found += joins_of(c)
+    return found
+rec["q3_shuffled"] = {
+    "delta": disp.delta(pre), "rows": len(out), "tree": df.last_profile(),
+    "catalog": [held, len(get_catalog())],
+    "joins": [[type(j).__name__, j.num_partitions]
+              for j in joins_of(df._last_exec)]}
 s.stop()
 print(json.dumps(rec))
 """
@@ -413,7 +445,10 @@ def test_gather_launch_fence(on, stmt, per_query, parts, batches):
         assert name not in spans, name
     # the final aggregate's one update over the coalesced partials
     assert spans["HashAggregateExec.updateAgg"]["count"] == 1
-    assert d["counters"] == {"fused_agg.engaged": parts * batches}
+    # the gather's one block a map task ends with the query
+    assert d["counters"] == {"fused_agg.engaged": parts * batches,
+                             "exchange.blocks.registered": parts,
+                             "exchange.blocks.closed": parts}
 
 
 def test_sort_path_aggregate_over_joins_keeps_its_launches(on):
@@ -424,13 +459,64 @@ def test_sort_path_aggregate_over_joins_keeps_its_launches(on):
     d = on["q3"]["delta"]
     assert on["q3"]["rows"] == 10
     assert d["counters"] == {"fused_agg.fallback.inline_build": 1,
-                             "fused_agg.fallback.sort_path": 5}
+                             "fused_agg.fallback.sort_path": 5,
+                             "exchange.blocks.registered": 4,
+                             "exchange.blocks.closed": 4}
     assert (d["jit_calls"], d["eager_op_calls"], d["transfers"]) == \
         (30, 23, 10), d
     spans = d["spans"]
     assert "FusedAggregateExec.step" not in spans
     assert spans["FusedAggregateExec.chain"]["count"] == 5
     assert spans["HashAggregateExec.mergeAgg"]["count"] == 4
+    # both builds are broadcast into the chain: no join exec, no join span;
+    # the two gathered partials and the two inlined builds end with the query
+    assert not [n for n in spans if n.startswith("HashJoinExec")]
+    held, after = on["q3"]["catalog"]
+    assert after == held
+    # the top-N's wait for the sorted batch and its cut, under its pull
+    (limit,) = _named(on["q3"]["tree"], "LocalLimitExec.limit")
+    assert spans["LocalLimitExec.limit"]["count"] == 1
+    assert {c["name"] for c in limit["children"]} <= {
+        "launch.jit", "launch.device_get"}
+
+
+def test_shuffled_join_spans_and_counters(on):
+    """The same statement with orders too large to broadcast, the plan sf 1
+    takes: a ``ShuffledHashJoinExec`` over two hash exchanges of 16
+    partitions that the adaptive reader coalesces. ``HashJoinExec.build``
+    once a join partition, over ``.buildStage`` and ``.buildPrepare`` and
+    beside the probe's ``HashJoinExec.inner``; the ``join.*`` counters a
+    partition and a stream batch each, ``join.out_rows`` left out (an inner
+    join's count stays on the device); the launches the parent counts for
+    this script (49 + 73 + 18), so the spans and counters cost no launch
+    and no transfer; every block of the three exchanges closed."""
+    run = on["q3_shuffled"]
+    d, tree, spans = run["delta"], run["tree"], run["delta"]["spans"]
+    assert run["rows"] == 10
+    ((kind, parts),) = run["joins"]
+    assert kind == "ShuffledHashJoinExec" and parts >= 1
+    builds = _named(tree, "HashJoinExec.build")
+    assert len(builds) == parts == spans["HashJoinExec.build"]["count"]
+    for b in builds:
+        assert [c["name"] for c in b["children"]] == [
+            "HashJoinExec.buildStage", "HashJoinExec.buildPrepare"]
+        assert not _named(b, "launch.device_get")
+    for name in ("HashJoinExec.buildStage", "HashJoinExec.buildPrepare"):
+        assert spans[name]["count"] == parts
+    probes = spans["HashJoinExec.inner"]["count"]
+    assert probes >= parts
+    for name in _MOVING_SPANS:
+        assert spans[name]["count"] == 8, name    # 2 tables x 2 x 2 batches
+    c = d["counters"]
+    assert c.pop("join.build_rows") > 0 and c.pop("join.probe_rows") > 0
+    registered = c.pop("exchange.blocks.registered")
+    assert registered > 8 and c.pop("exchange.blocks.closed") == registered
+    assert c == {"join.build.hash": parts,
+                 "fused_agg.fallback.inline_build": 1}, c
+    assert (d["jit_calls"], d["eager_op_calls"], d["transfers"]) == \
+        (49, 73, 18), d
+    held, after = run["catalog"]
+    assert after == held
 
 
 def test_q1_table_is_the_tree(on):
@@ -493,7 +579,10 @@ def test_delta_has_spans_and_queries(on):
     assert set(d) == {"jit_calls", "eager_op_calls", "transfers",
                       "dispatch_count", "spans", "queries", "counters"}
     # three cached batches whose int key lost its range in the repartition
-    assert d["counters"] == {"fused_agg.fallback.sort_path": 3}
+    # (and the gather's three blocks, registered and closed with the query)
+    assert d["counters"] == {"fused_agg.fallback.sort_path": 3,
+                             "exchange.blocks.registered": 3,
+                             "exchange.blocks.closed": 3}
     assert d["dispatch_count"] == \
         d["jit_calls"] + d["eager_op_calls"] + d["transfers"]
     assert d["eager_op_calls"] > 0
