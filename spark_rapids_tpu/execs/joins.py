@@ -23,9 +23,10 @@ from spark_rapids_tpu.execs.base import TpuExec, timed
 from spark_rapids_tpu.execs.batching import RequireSingleBatch
 from spark_rapids_tpu.expressions.base import Expression
 from spark_rapids_tpu.expressions.compiler import CompiledFilter
-from spark_rapids_tpu.ops.join import (cross_join, equi_join,
-                                       nested_loop_join, prepare_build)
-from spark_rapids_tpu.utils.tracing import TraceRange, count
+from spark_rapids_tpu.ops.join import (PreparedBuild, cross_join, equi_join,
+                                       nested_loop_join, prepare_build,
+                                       probe_rounds)
+from spark_rapids_tpu.utils.tracing import TraceRange, count, recording
 
 _KIND_MAP = {"inner": "inner", "left": "left", "left_semi": "leftsemi",
              "left_anti": "leftanti", "full": "full"}
@@ -55,7 +56,12 @@ class HashJoinExec(TpuExec):
     way the build went), ``join.build_rows``; a stream batch each:
     ``join.probe_rows``, and ``join.out_rows`` only where an output's
     count is on the host already: an inner join's stays on the device
-    (``_compact_pairs``) and is left out, no fetch is made for it."""
+    (``_compact_pairs``) and is left out, no fetch is made for it. A
+    hash build each, after the partition's last probe and only while
+    spans are recorded (it is the one fetch a counter costs):
+    ``join.probe.rounds``, the halvings a probe of that build made,
+    against ``join.probe.rounds_full``, those of one search of the
+    whole build."""
 
     def __init__(self, kind: str, left: TpuExec, right: TpuExec,
                  left_keys: List[int], right_keys: List[int],
@@ -198,6 +204,10 @@ class HashJoinExec(TpuExec):
                                              prepared=prepared)
                 self._count_out(outs)
                 yield from outs
+            if recording() and isinstance(prepared, PreparedBuild):
+                rounds, full = probe_rounds(prepared)
+                count("join.probe.rounds", rounds)
+                count("join.probe.rounds_full", full)
         return timed(self, it())
 
     def _build(self, partition: int, budget: int, left_types, right_types):

@@ -2,10 +2,15 @@
 
 The reference drives cuDF's hash joins (GpuHashJoin.scala:302-318:
 inner/left/leftSemi/leftAnti/full). TPUs have no device hash tables; the
-TPU-native design uses *sorted hashes + searchsorted*:
+TPU-native design uses *sorted hashes + a bucketed search*:
 
   build:  h_b = hash64(keys);  sort build rows by h_b           (one sort)
-  probe:  h_p = hash64(keys);  lo/hi = searchsorted(h_b, h_p)   (binary search)
+          the build's DISTINCT hashes with each one's run start, and a
+          directory over their top bits (:class:`HashIndex`)    (once a build)
+  probe:  h_p = hash64(keys);  lo/hi = searchsorted(h_b, h_p) left/right,
+          found by halving inside h_p's directory bucket alone: a gather
+          costs by the element, and a bucket of a few distinct hashes
+          takes 4 rounds where the whole build took 2 x 20 (PR 32)
   expand: pair k -> (probe_row i, build_row lo[i] + k-offset[i]) via one
           searchsorted over the match-count prefix sum
   verify: exact key equality per pair kills hash collisions; compaction
@@ -100,14 +105,46 @@ def unify_join_strings(left: ColumnarBatch, right: ColumnarBatch,
             ColumnarBatch(rcols, right.num_rows))
 
 
+class HashIndex(NamedTuple):
+    """What a probe searches in place of the hash-sorted build ``sb_h``
+    (capacity ``b_cap``): its ``n_u`` distinct hashes and a directory
+    over their top bits, made once with the build. Over DISTINCT hashes
+    because the build's padding (int64 max), a NULL-key run and a hot
+    key are each one long run: they cost the directory one entry and the
+    search no round, where a directory over ``sb_h`` itself would take
+    its round count from the longest run. Both tables are int32 lanes
+    stacked as ``take_rows`` stacks columns: on the v5e a gather costs by
+    the index, and a 64-bit element twice a 32-bit one, so a probe
+    fetches a hash as two words and everything it needs of a row at
+    once."""
+
+    runs: jax.Array    # int32[4, b_cap + 1], a distinct hash a column,
+    #                    ascending: its two words (_hash_words), where
+    #                    its run starts in sb_h, where the next one's
+    #                    does; from n_u on int64 max's words and b_cap
+    dir: jax.Array     # int32[2, 2**bits]: bucket b's distinct hashes
+    #                    are columns dir[0, b] to dir[1, b] of runs
+    rounds: jax.Array  # int32 scalar, on the device: halvings that
+    #                    settle the fullest bucket
+
+
 class PreparedBuild(NamedTuple):
     """Build side prepared once and probed across every stream batch:
-    the hash-sorted build. Only valid when no JOIN KEY is a string
-    column — string keys re-unify dictionaries per stream batch,
-    changing the build hashes (non-key string columns are fine)."""
+    the hash-sorted build and the index a probe searches. Only valid
+    when no JOIN KEY is a string column — string keys re-unify
+    dictionaries per stream batch, changing the build hashes (non-key
+    string columns are fine)."""
 
     sorted_build: ColumnarBatch
-    sb_h: jax.Array
+    index: HashIndex
+
+
+def probe_rounds(prepared: PreparedBuild) -> Tuple[int, int]:
+    """(halvings a probe of this build makes, halvings ONE whole-build
+    search of its capacity makes): one fetch from the device, for the
+    ``join.probe.rounds*`` counters of a traced run."""
+    b_cap = prepared.index.runs.shape[1] - 1
+    return int(jax.device_get(prepared.index.rounds)), b_cap.bit_length()
 
 
 def prepare_build(build: ColumnarBatch, build_keys: List[int],
@@ -126,13 +163,13 @@ def prepare_build(build: ColumnarBatch, build_keys: List[int],
         return None
     h_b = _key_hashes(build, build_keys, build_types, _BUILD_NULL,
                       target_types=commons)
-    sb_h, sb_datas, sb_vals = _build_sorted(
+    index, sb_datas, sb_vals = _build_sorted(
         [c.data for c in build.columns],
         [c.validity for c in build.columns], h_b,
         build.num_rows_device())
     cols = [c._like(d, v) for c, d, v in
             zip(build.columns, sb_datas, sb_vals)]
-    return PreparedBuild(ColumnarBatch(cols, build.num_rows), sb_h)
+    return PreparedBuild(ColumnarBatch(cols, build.num_rows), index)
 
 
 class DensePreparedBuild(NamedTuple):
@@ -284,15 +321,15 @@ def equi_join(stream: ColumnarBatch, build: ColumnarBatch,
                           target_types=commons)
         sorted_build = prepared.sorted_build
         lo, hi, counts, total = _probe_sorted(
-            prepared.sb_h, h_p, stream.num_rows_device())
+            prepared.index, h_p, stream.num_rows_device())
     else:
         h_p = _key_hashes(stream, stream_keys, stream_types, _PROBE_NULL,
                           target_types=commons)
         # ---- phase 1 (device): sort build, probe, count matches
         b_datas = [c.data for c in build.columns]
         b_vals = [c.validity for c in build.columns]
-        (sb_h, sb_datas, sb_vals, lo, hi, counts, total) = _probe_counts(
-            b_datas, b_vals, h_b := _key_hashes(
+        sb_datas, sb_vals, lo, hi, counts, total = _probe_counts(
+            b_datas, b_vals, _key_hashes(
                 build, build_keys, build_types, _BUILD_NULL,
                 target_types=commons),
             build.num_rows_device(), h_p, stream.num_rows_device())
@@ -335,17 +372,89 @@ def _sort_build(b_datas, b_vals, h_b, b_rows):
     h_b_l = jnp.where(live_b, h_b, jnp.iinfo(jnp.int64).max)
     order, (sb_h,) = sortkeys.stable_order([h_b_l])
     sb_datas, sb_vals = sortkeys.take_rows(order, b_datas, b_vals)
-    return sb_h, sb_datas, sb_vals
+    return _hash_index(sb_h), sb_datas, sb_vals
 
 
-def _hash_probe(sb_h, h_p, s_rows):
-    """Leftmost hash-match position + run length per probe row."""
+def _directory_bits(b_cap: int) -> int:
+    """Top bits of a hash that name its directory bucket: as many
+    buckets as the build has capacity, so a bucket of a uniform 64-bit
+    hash holds under one distinct hash on average and the fullest of
+    half a million about 8 (4 rounds)."""
+    return max((b_cap - 1).bit_length(), 1)
+
+
+def _bucket(h: jax.Array, bits: int) -> jax.Array:
+    """Directory bucket of an int64 hash, in [0, 2**bits): its top bits,
+    shifted so that signed order is bucket order."""
+    return ((h >> (64 - bits)) + (1 << (bits - 1))).astype(jnp.int32)
+
+
+def _hash_words(h: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """An int64 hash as two int32 words that order as it does, compared
+    high word first (the low word's sign bit flipped: unsigned order)."""
+    low = h.astype(jnp.uint32) ^ jnp.uint32(1 << 31)
+    return ((h >> 32).astype(jnp.int32),
+            jax.lax.bitcast_convert_type(low, jnp.int32))
+
+
+def _hash_index(sb_h: jax.Array) -> HashIndex:
+    b_cap = sb_h.shape[0]
+    bits = _directory_bits(b_cap)
+    pos = jnp.arange(b_cap, dtype=jnp.int32)
+    first = (pos == 0) | (sb_h != jnp.roll(sb_h, 1))
+    starts, _ = sortkeys.stable_order([~first])  # run starts to the front
+    live_u = pos < jnp.sum(first, dtype=jnp.int32)
+    u_h = jnp.append(jnp.where(live_u, jnp.take(sb_h, starts),
+                               jnp.iinfo(jnp.int64).max),
+                     jnp.iinfo(jnp.int64).max)
+    u_start = jnp.append(jnp.where(live_u, starts, b_cap),
+                         jnp.full((2,), b_cap, jnp.int32))
+    runs = jnp.stack([*_hash_words(u_h), u_start[:-1], u_start[1:]])
+    # distinct hashes a bucket (entries past them park in a bucket no
+    # probe names), then where each bucket starts and ends: a scatter-add
+    # over sorted indices and a prefix sum, 5 ms where a searchsorted of
+    # every bucket number took 70 (scripts/probecost.py, PR 32)
+    u_bucket = jnp.where(live_u, _bucket(u_h[:-1], bits), 1 << bits)
+    held = jax.ops.segment_sum(jnp.ones_like(u_bucket), u_bucket,
+                               num_segments=(1 << bits) + 1,
+                               indices_are_sorted=True)[:-1]
+    ends = _prefix_sum(held)
+    return HashIndex(runs, jnp.stack([ends - held, ends]),
+                     32 - jax.lax.clz(jnp.max(held)))  # its bit length
+
+
+def _hash_probe(index: HashIndex, h_p, s_rows):
+    """Leftmost hash-match position + run length per probe row: exactly
+    ``searchsorted(sb_h, h_p)`` left and right, found among the distinct
+    hashes of ``h_p``'s directory bucket. Gathers of the stream batch's
+    width: ``rounds + 2`` of stacked int32 lanes, where two whole-build
+    searches made ``2 * bit_length(b_cap)`` of int64. ``rounds`` is read
+    on the device (a traced trip count is a ``while``): no fetch, and one
+    program whatever the build holds. Distinct hashes that share their
+    top bits cost rounds, up to a whole-build search's; repeats of one
+    hash, NULL keys and padding cost none."""
+    runs, directory, rounds = index
+    bits = (directory.shape[1] - 1).bit_length()
     s_cap = h_p.shape[0]
     live_p = jnp.arange(s_cap, dtype=jnp.int32) < s_rows
-    lo = jnp.searchsorted(sb_h, h_p, side="left")
-    hi = jnp.searchsorted(sb_h, h_p, side="right")
-    # clamp hi to live build rows (padding key int64-max never matches a
-    # real hash, but belt-and-braces if a hash equals the sentinel)
+    high, low = _hash_words(h_p)
+    start, end = jnp.take(directory, _bucket(h_p, bits), axis=1,
+                          mode="clip")
+
+    def halve(_, span):
+        l, r = span
+        mid = (l + r) >> 1
+        m_high, m_low, _, _ = jnp.take(runs, mid, axis=1, mode="clip")
+        below = (l < r) & ((m_high < high) |
+                           ((m_high == high) & (m_low < low)))
+        return jnp.where(below, mid + 1, l), jnp.where(below, r, mid)
+
+    # j: the first distinct hash of the bucket that is >= h_p, or the
+    # bucket's end, where the next bucket's first run starts
+    j, _ = jax.lax.fori_loop(0, rounds, halve, (start, end))
+    j_high, j_low, lo, nxt = jnp.take(runs, j, axis=1, mode="clip")
+    found = (j < end) & (j_high == high) & (j_low == low)
+    hi = jnp.where(found, nxt, lo)
     counts = jnp.where(live_p, hi - lo, 0).astype(jnp.int64)
     total = jnp.sum(counts)
     return lo, hi, counts, total
@@ -353,23 +462,23 @@ def _hash_probe(sb_h, h_p, s_rows):
 
 @jax.jit
 def _probe_counts(b_datas, b_vals, h_b, b_rows, h_p, s_rows):
-    sb_h, sb_datas, sb_vals = _sort_build(b_datas, b_vals, h_b, b_rows)
-    lo, hi, counts, total = _hash_probe(sb_h, h_p, s_rows)
-    return sb_h, sb_datas, sb_vals, lo, hi, counts, total
+    index, sb_datas, sb_vals = _sort_build(b_datas, b_vals, h_b, b_rows)
+    lo, hi, counts, total = _hash_probe(index, h_p, s_rows)
+    return sb_datas, sb_vals, lo, hi, counts, total
 
 
 @jax.jit
 def _build_sorted(b_datas, b_vals, h_b, b_rows):
     """Build-once half of the prepared path: one program sorts the
-    build."""
+    build and makes the index its probes search."""
     return _sort_build(b_datas, b_vals, h_b, b_rows)
 
 
 @jax.jit
-def _probe_sorted(sb_h, h_p, s_rows):
+def _probe_sorted(index: HashIndex, h_p, s_rows):
     """Probe-many half of the prepared path (one program per stream
     batch, no build work)."""
-    return _hash_probe(sb_h, h_p, s_rows)
+    return _hash_probe(index, h_p, s_rows)
 
 
 def _prefix_sum(x: jax.Array) -> jax.Array:

@@ -54,6 +54,39 @@ def n_virtual_devices():
     return len(jax.devices())
 
 
+def _count_lines(path: str) -> int:
+    try:
+        with open(path) as f:
+            return sum(1 for _ in f)
+    except OSError:  # no /proc: nothing to watch
+        return 0
+
+
+def _max_map_count() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530
+
+
+def pytest_runtest_teardown(item, nextitem):
+    """Keep the run's one process under the kernel's limit on memory
+    mappings. A loaded XLA:CPU executable holds about 12 of them, jax's
+    caches keep every program of the run alive, and a process may have
+    ``vm.max_map_count`` (65,530): the 1,161 tests of PR 31 ended within
+    a few per cent of it and PR 32's 22 more passed it (60,313 mappings
+    at test 1,082, then a segmentation fault inside XLA's compile or its
+    cache read, in a different test each run). Between two modules, once
+    half the limit is in use, shed jax's caches: the executables go and
+    their mappings with them (4,166 -> 595 for 300 programs); what a
+    later module needs again it reads from the compile cache."""
+    if nextitem is not None and nextitem.module is item.module:
+        return
+    if _count_lines("/proc/self/maps") > _max_map_count() // 2:
+        jax.clear_caches()
+
+
 def pytest_collection_modifyitems(config, items):
     """Two-tier suite: anything not explicitly `full` (the 140-query TPC
     oracle matrices) is the `smoke` tier — `pytest -m smoke` stays under

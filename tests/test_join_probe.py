@@ -1,13 +1,15 @@
 """The hash-join probe against plain oracles (ops/join).
 
-The probe is two ``searchsorted`` calls over the hash-sorted build
-(``_hash_probe``) behind ``equi_join``. Until PR 29 these cases held a
-Pallas bucket-table kernel bit-equal to that path; the kernel is gone
-and the same data now holds the path that remains to oracles that share
-no code with it: numpy's ``searchsorted`` for the probe's (lo, hi,
-counts, total) contract, a nested loop over rows for every join type —
-across single, composite and string keys, nulls on the key, an empty
-build side and the build-once/probe-many prepared path.
+The probe's contract is two ``searchsorted`` calls over the hash-sorted
+build (``_hash_probe``) behind ``equi_join``; since PR 32 it is computed
+by halving inside one bucket of a directory over the build's distinct
+hashes (``HashIndex``). Until PR 29 these cases held a Pallas
+bucket-table kernel bit-equal to that path; the kernel is gone and the
+same data now holds the path that remains to oracles that share no code
+with it: numpy's ``searchsorted`` for the probe's (lo, hi, counts,
+total) contract, a nested loop over rows for every join type — across
+single, composite and string keys, nulls on the key, an empty build side
+and the build-once/probe-many prepared path.
 """
 from __future__ import annotations
 
@@ -130,24 +132,99 @@ def test_join_probe_empty_build():
     assert int(jax.device_get(out.num_rows_device())) == 10
 
 
-def test_hash_probe_matches_numpy_searchsorted():
+_MAXH, _MINH = np.iinfo(np.int64).max, np.iinfo(np.int64).min
+
+
+def _uniform(r, n):
+    return r.integers(_MINH, _MAXH, size=n)
+
+
+#: what a build side may hold, as (capacity, generator) -> its live hashes
+_BUILDS = {
+    "unique_uniform": lambda r, cap: _uniform(r, cap * 3 // 4),
+    "37_distinct_repeated": lambda r, cap: r.choice(_uniform(r, 37),
+                                                    cap * 3 // 4),
+    "a_third_null": lambda r, cap: np.concatenate(
+        [np.full(cap // 4, int(J._BUILD_NULL)), _uniform(r, cap // 2)]),
+    "empty": lambda r, cap: _uniform(r, 0),
+    "full_to_capacity": lambda r, cap: _uniform(r, cap),
+}
+
+
+def _build_args(h_b, b_cap):
+    """A build side of one column, its row numbers, under the hashes
+    ``h_b``: the arguments ``_build_sorted`` and ``_probe_counts`` share."""
+    padded = np.zeros(b_cap, np.int64)
+    padded[:len(h_b)] = h_b
+    return ([jnp.arange(b_cap, dtype=jnp.int32)], [None],
+            jnp.asarray(padded), jnp.asarray(len(h_b), jnp.int32))
+
+
+def _build_and_probe(path, h_b, b_cap, h_p, s_rows):
+    """(lo, hi, counts, total) and the sorted build's one column through
+    the prepared pair of programs or the one unprepared program."""
+    build = _build_args(h_b, b_cap)
+    h_p_d, s_rows = jnp.asarray(h_p), jnp.asarray(s_rows, jnp.int32)
+    if path == "_probe_sorted":
+        index, (rows,), _ = J._build_sorted(*build)
+        return J._probe_sorted(index, h_p_d, s_rows), rows
+    (rows,), _, *out = J._probe_counts(*build, h_p_d, s_rows)
+    return out, rows
+
+
+@pytest.mark.parametrize("path", ["_probe_sorted", "_probe_counts"])
+@pytest.mark.parametrize("b_cap", [128, 65536])
+@pytest.mark.parametrize("build", list(_BUILDS))
+def test_hash_probe_matches_numpy_searchsorted(build, b_cap, path):
     """``_hash_probe``'s (lo, hi, counts, total) contract is
     ``searchsorted`` left/right over the hash-sorted build side, with
-    the probe's padding rows counting nothing."""
-    r = np.random.default_rng(7)
-    maxh = np.iinfo(np.int64).max
-    h_b = r.integers(-2**62, 2**62, size=64)
-    h_b[48:] = maxh                     # tail is padding
-    sh = np.sort(h_b)
-    h_p = np.concatenate([r.choice(sh[:48], 20),
-                          r.integers(-2**62, 2**62, size=12)])
-    s_rows = 27                         # 5 probe rows are padding
-    lo, hi, counts, total = J._probe_sorted(
-        jnp.asarray(sh), jnp.asarray(h_p), jnp.asarray(s_rows, jnp.int32))
+    the probe's padding rows counting nothing: exact, whatever the build
+    holds (repeats, a NULL-key run, nothing, no padding), for probes that
+    hit, that miss, and that hold int64's ends and the probe's NULL."""
+    r = np.random.default_rng([7, b_cap, list(_BUILDS).index(build)])
+    h_b = _BUILDS[build](r, b_cap)
+    s_cap = 2 * b_cap
+    hits = r.choice(h_b, s_cap // 2) if len(h_b) else _uniform(r, s_cap // 2)
+    h_p = np.concatenate([hits, _uniform(r, s_cap // 2 - 3),
+                          [_MAXH, _MINH, int(J._PROBE_NULL)]])
+    h_p = h_p[r.permutation(s_cap)]
+    s_rows = s_cap - 5                  # 5 probe rows are padding
+    (lo, hi, counts, total), rows = _build_and_probe(
+        path, h_b, b_cap, h_p, s_rows)
+    sh = np.sort(np.concatenate([h_b, np.full(b_cap - len(h_b), _MAXH)]),
+                 kind="stable")
     want_lo = np.searchsorted(sh, h_p, side="left")
     want_hi = np.searchsorted(sh, h_p, side="right")
-    want_counts = np.where(np.arange(32) < s_rows, want_hi - want_lo, 0)
+    want_counts = np.where(np.arange(s_cap) < s_rows, want_hi - want_lo, 0)
+    assert lo.dtype == hi.dtype == jnp.int32 and counts.dtype == jnp.int64
     np.testing.assert_array_equal(np.asarray(lo), want_lo)
     np.testing.assert_array_equal(np.asarray(hi), want_hi)
     np.testing.assert_array_equal(np.asarray(counts), want_counts)
-    assert int(total) == want_counts.sum() >= 20
+    assert int(total) == want_counts.sum()
+    if len(h_b):
+        assert int(total) >= s_cap // 2 - 5
+    # the build's rows in hash order, ties in row order, padding last
+    live = np.argsort(h_b, kind="stable")
+    np.testing.assert_array_equal(np.asarray(rows)[:len(h_b)], live)
+
+
+def test_probe_rounds_follow_distinct_hashes_not_rows():
+    """50,000 distinct uniform hashes in a capacity of 65,536 settle in at
+    most 5 halvings (a whole-build search makes 17, twice), and a hot key
+    adds none: with half the build on ONE hash the fullest bucket holds no
+    more distinct hashes than before."""
+    r = np.random.default_rng(32)
+    b_cap, n = 65536, 50000
+    h_b = _uniform(r, n)
+
+    def rounds(h):
+        index, _, _ = J._build_sorted(*_build_args(h, b_cap))
+        got, full = J.probe_rounds(J.PreparedBuild(None, index))
+        assert full == 17
+        return got
+
+    spread = rounds(h_b)
+    assert 1 <= spread <= 5
+    hot = h_b.copy()
+    hot[r.permutation(n)[:n // 2]] = h_b[0]
+    assert rounds(hot) <= spread

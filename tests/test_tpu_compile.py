@@ -293,6 +293,50 @@ def test_q3_partition_kernel_compiles_for_v5e_at_sf1_batch_shape(
     assert seconds < 30.0, f"_partition_kernel compiled in {seconds:.1f} s"
 
 
+def test_q3_join_build_and_probe_compile_for_v5e_at_sf1_shapes(
+        one_chip, no_compile_cache):
+    """TPC-H Q3's shuffled join at sf 1: ``ops/join._build_sorted`` over
+    the orders side (its 8 columns, no validity, capacity 524,288: the
+    sort of the hashed key, the distinct hashes compacted by a one-bit
+    ``stable_order``, the directory by a scatter-add and a prefix sum)
+    and ``_probe_sorted`` of a 1,048,576-row stream batch against it (a
+    ``while`` of one gather of four stacked int32 lanes a round). The
+    parent's two programs (a sort and a gather a dtype; two
+    ``searchsorted`` loops) took 20.4 s and 0.4 s in this sandbox, these
+    28.5 s and 1.2 s (PR 32; the scatter-add is the build's difference).
+    The limits are three times that: a cumulative operation over the
+    whole capacity (65 s at 1 M rows, ``_prefix_sum``), a sort that
+    carries the hashes, a second two-operand sort in the index or an
+    unrolled search breaks them."""
+    import time
+
+    from spark_rapids_tpu.ops import join as J
+
+    b_cap, s_cap = 524288, 1048576
+    orders = [jnp.int64] * 2 + [jnp.int32] + [jnp.float64] + [jnp.int32] * 4
+
+    def arr(n, t):
+        shape = n if isinstance(n, tuple) else (n,)
+        return jax.ShapeDtypeStruct(shape, t, sharding=one_chip)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    build = J._build_sorted.lower(
+        [arr(b_cap, t) for t in orders], [None] * len(orders),
+        arr(b_cap, jnp.int64), scalar).compile()
+    t1 = time.perf_counter()
+    bits = J._directory_bits(b_cap)
+    index = J.HashIndex(arr((4, b_cap + 1), jnp.int32),
+                        arr((2, 1 << bits), jnp.int32), scalar)
+    probe = J._probe_sorted.lower(
+        index, arr(s_cap, jnp.int64), scalar).compile()
+    t2 = time.perf_counter()
+    for compiled in (build, probe):
+        assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+    assert t1 - t0 < 90.0, f"_build_sorted compiled in {t1 - t0:.1f} s"
+    assert t2 - t1 < 4.0, f"_probe_sorted compiled in {t2 - t1:.1f} s"
+
+
 def test_wide_sort_path_groupby_compiles_for_v5e(one_chip,
                                                  no_compile_cache):
     """The sort-path ``_groupby`` (an int64 key with no host-known

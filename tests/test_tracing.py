@@ -208,6 +208,40 @@ s.stop()
 print(json.dumps(rec))
 """
 
+# the shuffled Q3 of _ON_SCRIPT with the recorder OFF: no install(), the
+# transfers counted by a plain wrapper the engine's calls look up
+_OFF_SCRIPT = r"""
+import json, sys, tempfile
+sys.path.insert(0, __ROOT__)
+import jax
+real_get, gets = jax.device_get, []
+def counting_get(x):
+    gets.append(1)
+    return real_get(x)
+jax.device_get = counting_get
+from spark_rapids_tpu.utils import tracing
+from spark_rapids_tpu.api import Session
+from spark_rapids_tpu.benchmarks import datagen
+import chip_smoke
+tmp = tempfile.mkdtemp()
+datagen.write_tables(tmp, 0.01, tables=["lineitem", "orders", "customer"])
+s = Session({"rapids.tpu.sql.taskThreads": 1,
+             "rapids.tpu.sql.reader.batchSizeRows": 20000,
+             "rapids.tpu.sql.autoBroadcastJoinThreshold": "50k"})
+for t in ("lineitem", "orders", "customer"):
+    df = s.read.parquet(tmp + "/" + t).repartition(2).cache()
+    df.create_or_replace_temp_view(t)
+    df.count()
+s.sql(chip_smoke.Q3).collect()
+df = s.sql(chip_smoke.Q3)
+before, n = tracing.counters(), len(gets)
+out = df.collect()
+print(json.dumps({"recording": tracing.recording(), "rows": len(out),
+                  "transfers": len(gets) - n,
+                  "counters": tracing.counters_delta(before)}))
+s.stop()
+"""
+
 _AFTER_JAX_SCRIPT = r"""
 import json, sys
 sys.path.insert(0, __ROOT__)
@@ -487,9 +521,12 @@ def test_shuffled_join_spans_and_counters(on):
     once a join partition, over ``.buildStage`` and ``.buildPrepare`` and
     beside the probe's ``HashJoinExec.inner``; the ``join.*`` counters a
     partition and a stream batch each, ``join.out_rows`` left out (an inner
-    join's count stays on the device); the launches the parent counts for
-    this script (49 + 73 + 18), so the spans and counters cost no launch
-    and no transfer; every block of the three exchanges closed."""
+    join's count stays on the device); the launches the parent of PR 32
+    counts for this script (49 + 73 + 18) and one transfer a build, the
+    fetch of ``join.probe.rounds`` after the partition's last probe: the
+    build's index is made inside ``_build_sorted`` and searched inside
+    ``_probe_sorted``, no new program and no eager operation; every block
+    of the three exchanges closed."""
     run = on["q3_shuffled"]
     d, tree, spans = run["delta"], run["tree"], run["delta"]["spans"]
     assert run["rows"] == 10
@@ -511,12 +548,28 @@ def test_shuffled_join_spans_and_counters(on):
     assert c.pop("join.build_rows") > 0 and c.pop("join.probe_rows") > 0
     registered = c.pop("exchange.blocks.registered")
     assert registered > 8 and c.pop("exchange.blocks.closed") == registered
+    # a build each: the halvings its probes made against those of one
+    # whole-build search (a capacity of at least 128 rows takes 8)
+    rounds, full = c.pop("join.probe.rounds"), c.pop("join.probe.rounds_full")
+    assert parts <= rounds < full and full >= 8 * parts, (rounds, full)
     assert c == {"join.build.hash": parts,
                  "fused_agg.fallback.inline_build": 1}, c
     assert (d["jit_calls"], d["eager_op_calls"], d["transfers"]) == \
-        (49, 73, 18), d
+        (49, 73, 18 + parts), d
     held, after = run["catalog"]
     assert after == held
+
+
+def test_shuffled_join_fetches_no_rounds_with_the_recorder_off():
+    """The same statement over the same tables without ``install()``: the
+    18 transfers of the parent of PR 32 and no ``join.probe.*`` counter;
+    the round count stays on the device."""
+    run = _run(_OFF_SCRIPT)
+    assert not run["recording"] and run["rows"] == 10
+    c = run["counters"]
+    assert c["join.build.hash"] >= 1
+    assert not [name for name in c if name.startswith("join.probe.")], c
+    assert run["transfers"] == 18, run
 
 
 def test_q1_table_is_the_tree(on):
