@@ -69,32 +69,35 @@ def concat_batches(batches: List[ColumnarBatch]) -> ColumnarBatch:
     return ColumnarBatch(out_cols, total)
 
 
-def _fit(x: jax.Array, cap: int) -> jax.Array:
-    """Static resize to ``cap`` inside a trace (slice or zero-pad)."""
-    n = x.shape[0]
-    if n == cap:
-        return x
-    if n > cap:
-        return x[:cap]
-    return jnp.concatenate([x, jnp.zeros(cap - n, dtype=x.dtype)])
-
-
 @partial(jax.jit, static_argnames=("out_cap",))
 def _concat_kernel(datas, valids, offs, ns, out_cap: int):
+    live = jnp.arange(out_cap, dtype=jnp.int64) < offs[-1] + ns[-1]
     out_d, out_v = [], []
     for col_datas, col_valids in zip(datas, valids):
-        any_v = any(v is not None for v in col_valids)
-        acc = jnp.zeros(out_cap, dtype=col_datas[0].dtype)
-        accv = jnp.zeros(out_cap, dtype=bool) if any_v else None
-        for bi, (d, v) in enumerate(zip(col_datas, col_valids)):
-            acc = _place_traced(acc, _fit(d, out_cap), offs[bi], ns[bi])
-            if any_v:
-                vv = jnp.ones(out_cap, dtype=bool) if v is None \
-                    else _fit(v, out_cap)
-                accv = _place_traced(accv, vv, offs[bi], ns[bi])
-        out_d.append(acc)
-        out_v.append(accv)
+        out_d.append(_place_all(col_datas, offs, live))
+        if any(v is not None for v in col_valids):
+            out_v.append(_place_all(
+                [jnp.ones(d.shape[0], dtype=bool) if v is None else v
+                 for d, v in zip(col_datas, col_valids)], offs, live))
+        else:
+            out_v.append(None)
     return out_d, out_v
+
+
+def _place_all(pieces, offs, live) -> jax.Array:
+    """The pieces' live rows one after another, zero from the last live
+    row on. Each piece is written whole at its traced offset, in order, so
+    the next piece overwrites its padding; the buffer is a piece longer
+    than the output, so no write is clamped. Slice writes and not a
+    gather a piece: a gather would read the whole output capacity for
+    every piece of every column. Runs INSIDE _concat_kernel's trace."""
+    out_cap = live.shape[0]
+    acc = jnp.zeros(out_cap + max(p.shape[0] for p in pieces),
+                    dtype=pieces[0].dtype)
+    for bi, p in enumerate(pieces):
+        acc = jax.lax.dynamic_update_slice(acc, p.astype(acc.dtype),
+                                           (offs[bi],))
+    return jnp.where(live, acc[:out_cap], jnp.zeros((), acc.dtype))
 
 
 def interleave_batches(batches: List[ColumnarBatch]) -> ColumnarBatch:
@@ -139,14 +142,3 @@ def interleave_batches(batches: List[ColumnarBatch]) -> ColumnarBatch:
 @jax.jit
 def _interleave(arrs: List[jax.Array]) -> jax.Array:
     return jnp.stack(arrs, axis=1).reshape(-1)
-
-
-def _place_traced(dst: jax.Array, src: jax.Array, offset, n):
-    """Write src[0:n] into dst[offset:offset+n]. ``offset``/``n`` are traced
-    scalars (a single shifted gather + select — no dynamic shapes);
-    runs INSIDE _concat_kernel's trace."""
-    cap = dst.shape[0]
-    idx = jnp.arange(cap, dtype=jnp.int64) - offset
-    vals = jnp.take(src, jnp.clip(idx, 0, cap - 1))
-    mask = (idx >= 0) & (idx < n)
-    return jnp.where(mask, vals, dst)

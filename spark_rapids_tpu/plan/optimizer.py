@@ -17,12 +17,15 @@ impact (each collapsed node is one fewer jitted executable per batch):
 """
 from __future__ import annotations
 
+import copy
+import dataclasses
 from typing import List, Optional
 
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.expressions.base import (Alias, BoundReference,
                                                Expression)
 from spark_rapids_tpu.plan import nodes as pn
+from spark_rapids_tpu.utils import tracing
 
 
 def _substitute(e: Expression, inner: List[Expression]) -> Expression:
@@ -667,6 +670,187 @@ def _reorder_joins_one(node: pn.PlanNode, _memo) -> pn.PlanNode:
     return pn.ProjectNode(exprs, cur, names=list(out_schema.names))
 
 
+# ---------------------------------------------------------------------------
+# Column pruning below joins (the join part of Catalyst's ColumnPruning):
+# a join emits every column of both inputs, so each input it is handed is
+# gathered, null-extended and concatenated in full, string dictionaries
+# included, whatever the plan above reads. Walking down from the root,
+# every node asks its child for the columns it reads; a join input that
+# outputs more than that is wrapped in a projection of bare references,
+# and the ordinals of the join and of the nodes above it, up to the next
+# node that re-projects, are rewritten old -> new. A node that reads every
+# column of its child (window, union, expand, exchanges, ...) asks for all
+# of them, and pruning starts afresh below it. A node several parents
+# share is asked for all of its columns, so it is never copied per
+# consumer; a projection holding a nondeterministic expression is kept
+# whole, with all of its input.
+# ---------------------------------------------------------------------------
+
+
+def _remap(e: Expression, cmap: Optional[dict]) -> Expression:
+    """``e`` with its bound references moved through ``cmap`` (old ->
+    new ordinal; None: unchanged)."""
+    if cmap is None:
+        return e
+
+    def fn(n):
+        if isinstance(n, BoundReference) and cmap[n.ordinal] != n.ordinal:
+            n = copy.copy(n)
+            n.ordinal = cmap[n.ordinal]
+        return n
+    return e.transform(fn)
+
+
+def _refs(exprs) -> set:
+    return {o for e in exprs for o in e.references()}
+
+
+class _JoinInputPruner:
+    """One walk of a plan. ``prune(node, need)`` returns the node asked
+    for the output ordinals ``need`` (None: all of them) and the map from
+    its old output ordinals to its new ones (None: its output is
+    unchanged); ``removed`` counts the columns taken off join inputs."""
+
+    def __init__(self, plan: pn.PlanNode):
+        self.parents: dict = {}
+        self.memo: dict = {}
+        self.removed = 0
+        self.has_join = False
+        stack = [plan]
+        while stack:
+            node = stack.pop()
+            self.has_join |= isinstance(node, pn.JoinNode)
+            for c in node.children:
+                self.parents[id(c)] = self.parents.get(id(c), 0) + 1
+                if self.parents[id(c)] == 1:
+                    stack.append(c)
+
+    def prune(self, node: pn.PlanNode, need: Optional[set]):
+        if self.parents.get(id(node), 0) <= 1:
+            return self._prune_one(node, need)
+        hit = self.memo.get(id(node))
+        if hit is None:
+            hit = self.memo[id(node)] = (node,
+                                         self._prune_one(node, None)[0])
+        return hit[1], None
+
+    def _prune_one(self, node: pn.PlanNode, need: Optional[set]):
+        if isinstance(node, pn.JoinNode):
+            return self._join(node, need)
+        if isinstance(node, pn.ProjectNode):
+            whole = not _all_deterministic(node.exprs)
+            keep = list(range(len(node.exprs))) if need is None or whole \
+                else sorted(need) or [0]
+            exprs = [node.exprs[i] for i in keep]
+            child, cm = self.prune(node.children[0],
+                                   None if whole else _refs(exprs))
+            if len(keep) == len(node.exprs) and \
+                    child is node.children[0]:
+                return node, None
+            out = pn.ProjectNode([_remap(e, cm) for e in exprs], child,
+                                 names=[node.names[i] for i in keep])
+            return out, (None if len(keep) == len(node.exprs) else
+                         {o: j for j, o in enumerate(keep)})
+        if isinstance(node, pn.FilterNode):
+            cond = node.condition
+            child, cm = self.prune(node.children[0], None if need is None
+                                   else need | _refs([cond]))
+            if child is node.children[0]:
+                return node, None
+            return pn.FilterNode(_remap(cond, cm), child), cm
+        if isinstance(node, pn.AggregateNode) and node.mode == "complete":
+            child, cm = self.prune(node.children[0], _refs(
+                list(node.grouping) + [a.fn for a in node.aggs]))
+            if child is node.children[0]:
+                return node, None
+            return pn.AggregateNode(
+                [_remap(e, cm) for e in node.grouping],
+                [pn.AggCall(_remap(a.fn, cm), a.name) for a in node.aggs],
+                child, grouping_names=list(node.grouping_names)), None
+        if isinstance(node, pn.SortNode):
+            child, cm = self.prune(node.children[0], None if need is None
+                                   else need | {s.ordinal
+                                                for s in node.specs})
+            if child is node.children[0]:
+                return node, None
+            specs = [dataclasses.replace(s, ordinal=cm[s.ordinal])
+                     if cm is not None else s for s in node.specs]
+            return pn.SortNode(specs, child, node.global_sort), cm
+        if isinstance(node, pn.LimitNode):
+            child, cm = self.prune(node.children[0], need)
+            if child is node.children[0]:
+                return node, None
+            return node.with_children([child]), cm
+        children = [self.prune(c, None)[0] for c in node.children]
+        if any(n is not o for n, o in zip(children, node.children)):
+            node = node.with_children(children)
+        return node, None
+
+    def _input(self, node: pn.PlanNode, need: Optional[set]):
+        """A join input asked for ``need``, narrowed to exactly that (a
+        cross join's side that nothing reads keeps its first column)."""
+        if need is not None and not need:
+            need = {0}
+        node, cm = self.prune(node, need)
+        if need is None:
+            return node, None
+        keep = sorted(need)
+        schema = node.output_schema()
+        if len(schema) == len(keep):
+            return node, cm
+        self.removed += len(schema) - len(keep)
+        at = [o if cm is None else cm[o] for o in keep]
+        node = pn.ProjectNode([BoundReference(i, schema.types[i])
+                               for i in at], node,
+                              names=[schema.names[i] for i in at])
+        return node, {o: j for j, o in enumerate(keep)}
+
+    def _join(self, node: pn.JoinNode, need: Optional[set]):
+        left, right = node.children
+        lw = len(left.output_schema())
+        semi = node.kind in ("left_semi", "left_anti")
+        cond = node.condition
+        read = _refs([cond] if cond is not None else [])
+        if need is not None:
+            read |= need
+        lneed = set(node.left_keys) | {o for o in read if o < lw}
+        rneed = set(node.right_keys) | {o - lw for o in read if o >= lw}
+        if need is None:
+            lneed = None
+            if not semi:
+                rneed = None
+        new_left, lcm = self._input(left, lneed)
+        new_right, rcm = self._input(right, rneed)
+        if new_left is left and new_right is right:
+            return node, None
+        nlw = len(new_left.output_schema())
+        both = {o: (o if lcm is None else lcm[o]) for o in range(lw)
+                if lcm is None or o in lcm}
+        both.update({lw + o: nlw + (o if rcm is None else rcm[o])
+                     for o in range(len(right.output_schema()))
+                     if rcm is None or o in rcm})
+        out = pn.JoinNode(
+            node.kind, new_left, new_right,
+            [both[o] for o in node.left_keys],
+            [both[lw + o] - nlw for o in node.right_keys],
+            condition=None if cond is None else _remap(cond, both))
+        cm = lcm if semi else (None if lcm is None and rcm is None
+                               else both)
+        return out, cm
+
+
+def prune_join_inputs(plan: pn.PlanNode) -> tuple:
+    """``plan`` with every join input narrowed to the columns read above
+    it; returns (plan, columns removed from join inputs). The root's
+    schema is unchanged, and a plan without a join comes back as it was."""
+    pruner = _JoinInputPruner(plan)
+    if not pruner.has_join:
+        return plan, 0
+    out, cm = pruner.prune(plan, None)
+    assert cm is None
+    return out, pruner.removed
+
+
 def optimize(plan: pn.PlanNode) -> pn.PlanNode:
     plan = collapse_project(plan)
     # a join's one-sided ON conjuncts become filters on its inputs, which
@@ -681,7 +865,14 @@ def optimize(plan: pn.PlanNode) -> pn.PlanNode:
     plan = reorder_joins(plan)
     # the reorder's restore-projection may now collapse with outer ones
     plan = collapse_project(plan)
-    return rewrite_distinct_aggregates(plan)
+    plan = rewrite_distinct_aggregates(plan)
+    # last: every join input narrowed to what is read above it, and the
+    # narrowing projections collapsed with the ones they meet
+    plan, removed = prune_join_inputs(plan)
+    if removed:
+        tracing.count("plan.prune.columns", removed)
+        plan = collapse_project(plan)
+    return plan
 
 
 # ---------------------------------------------------------------------------

@@ -267,7 +267,11 @@ def test_multi_join_distinct_key_shapes_pair_preps_correctly():
         s.create_temp_view("f", s.create_dataframe(fact))
         s.create_temp_view("wide", s.create_dataframe(wide))
         s.create_temp_view("narrow", s.create_dataframe(narrow))
-    sql = ("SELECT f.k AS k, sum(f.v) AS sv, count(*) AS n "
+    # the statement reads every column of wide: the planner narrows a
+    # join input to the columns read above it, and the wide build must
+    # keep its four columns and its key at ordinal 2
+    sql = ("SELECT f.k AS k, sum(f.v) AS sv, "
+           "sum(wide.pad0 + wide.pad1 + wide.w) AS sw, count(*) AS n "
            "FROM f JOIN wide ON f.k = wide.id "
            "WHERE f.s IN (SELECT sid FROM narrow) "
            "GROUP BY f.k ORDER BY k")

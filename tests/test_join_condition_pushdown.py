@@ -74,12 +74,18 @@ def session():
 
 
 def _join_and_children(text: str):
-    """The join's line of an explain() and the lines of its two inputs."""
+    """The join's line of an explain() and the lines of its two inputs.
+    An input the planner narrowed to the columns read above the join
+    (``plan/optimizer.prune_join_inputs``: a semi or anti join reads only
+    its right key) is a ``Project`` over the input: its line is looked
+    through, to the line below it."""
     lines = text.splitlines()
     at = next(i for i, ln in enumerate(lines) if "Join[" in ln)
     depth = len(lines[at]) - len(lines[at].lstrip())
-    kids = [ln for ln in lines[at + 1:]
-            if len(ln) - len(ln.lstrip()) == depth + 2]
+    kids = [i for i in range(at + 1, len(lines))
+            if len(lines[i]) - len(lines[i].lstrip()) == depth + 2]
+    kids = [lines[i + 1] if lines[i].strip().startswith("* Project[")
+            else lines[i] for i in kids]
     return lines[at], kids
 
 

@@ -348,6 +348,38 @@ def test_concat_strings_and_nulls():
     assert dv is not None and list(dv) == [True, True, True, False]
 
 
+@pytest.mark.parametrize("rows,caps", [
+    ([3, 0, 5], [8, 4, 8]),          # an empty piece between two
+    ([7, 2], [8, 2]),                 # padding longer than the next piece
+    ([1, 1, 1, 1], [16, 16, 16, 16]),  # output far narrower than a piece
+    ([5, 4, 0], [8, 4, 4]),           # the last piece empty
+])
+def test_concat_places_live_rows_over_padding(rows, caps):
+    """Each piece's padding holds garbage (-7, valid) that must not reach
+    the output: its live rows in order, zero and not valid from the total
+    on, whatever the pieces' capacities."""
+    rng = np.random.default_rng(sum(rows) * 31 + len(caps))
+    batches, want_d, want_v = [], [], []
+    for i, (n, cap) in enumerate(zip(rows, caps)):
+        data = np.full(cap, -7, dtype=np.int64)
+        data[:n] = rng.integers(0, 100, n)
+        valid = np.ones(cap, dtype=bool)
+        valid[:n] = rng.random(n) > 0.3
+        cols = [Column(dt.INT64, jnp.asarray(data),
+                       jnp.asarray(valid) if i % 2 == 0 else None)]
+        batches.append(ColumnarBatch(cols, n))
+        want_d.append(data[:n])
+        want_v.append(valid[:n] if i % 2 == 0 else np.ones(n, dtype=bool))
+    out = concat.concat_batches(batches)
+    total = sum(rows)
+    assert out.realized_num_rows() == total and out.capacity >= total
+    d = np.asarray(out.columns[0].data)
+    v = np.asarray(out.columns[0].validity)
+    np.testing.assert_array_equal(d[:total], np.concatenate(want_d))
+    np.testing.assert_array_equal(v[:total], np.concatenate(want_v))
+    assert not d[total:].any() and not v[total:].any()
+
+
 # ---------------------------------------------------------------- join
 
 def _join_oracle(left, right, how):

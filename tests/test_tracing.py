@@ -505,12 +505,17 @@ def test_sort_path_aggregate_over_joins_keeps_its_launches(on):
     batches): its aggregate's partials have the batch's capacity, so every
     batch keeps chain, update and merge, and the query the 63 = 30 + 23 + 10
     launches the parent of PR 30 counts for the same script."""
+    # the planner takes 20 columns off the joins' inputs
+    # (plan.prune.columns: lineitem 14 -> 3, orders 7 -> 4, customer 6 -> 1
+    # and the lower join's output 7 -> 6); at this size the launches do not
+    # move with it
     d = on["q3"]["delta"]
     assert on["q3"]["rows"] == 10
     assert d["counters"] == {"fused_agg.fallback.inline_build": 1,
                              "fused_agg.fallback.sort_path": 5,
                              "exchange.blocks.registered": 4,
-                             "exchange.blocks.closed": 4}
+                             "exchange.blocks.closed": 4,
+                             "plan.prune.columns": 20}
     assert (d["jit_calls"], d["eager_op_calls"], d["transfers"]) == \
         (30, 23, 10), d
     spans = d["spans"]
@@ -542,6 +547,8 @@ def test_shuffled_join_spans_and_counters(on):
     build's index is made inside ``_build_sorted`` and searched inside
     ``_probe_sorted``, no new program and no eager operation; every block
     of the three exchanges closed."""
+    # the 20 columns the planner takes off the joins' inputs
+    # (plan.prune.columns) leave the launches as they were at this size
     run = on["q3_shuffled"]
     d, tree, spans = run["delta"], run["tree"], run["delta"]["spans"]
     assert run["rows"] == 10
@@ -568,7 +575,8 @@ def test_shuffled_join_spans_and_counters(on):
     rounds, full = c.pop("join.probe.rounds"), c.pop("join.probe.rounds_full")
     assert parts <= rounds < full and full >= 8 * parts, (rounds, full)
     assert c == {"join.build.hash": parts,
-                 "fused_agg.fallback.inline_build": 1}, c
+                 "fused_agg.fallback.inline_build": 1,
+                 "plan.prune.columns": 20}, c
     assert (d["jit_calls"], d["eager_op_calls"], d["transfers"]) == \
         (49, 73, 18 + parts), d
     held, after = run["catalog"]
