@@ -76,6 +76,7 @@ def _aqe_conf(sf: float):
 def run_join(query: str, sf: float, data_dir: str, skew: float) -> dict:
     from spark_rapids_tpu.benchmarks.runner import (ALL_BENCHMARKS,
                                                     BenchmarkRunner)
+    from spark_rapids_tpu.execs import adaptive
     from spark_rapids_tpu.execs.base import collect
     from spark_rapids_tpu.plan.overrides import apply_overrides
 
@@ -83,12 +84,12 @@ def run_join(query: str, sf: float, data_dir: str, skew: float) -> dict:
     r.ensure_data("tpch")
     # warm run traces + compiles; the fence times the steady state
     collect(apply_overrides(ALL_BENCHMARKS[query](data_dir), r.conf))
-    pre = disp.replan_snapshot()
+    pre = adaptive.replan_snapshot()
     t0 = time.perf_counter()
     df = collect(apply_overrides(ALL_BENCHMARKS[query](data_dir),
                                  r.conf))
     wall = time.perf_counter() - t0
-    events = disp.replan_delta(pre)
+    events = adaptive.replan_delta(pre)
     cmp_ = r.compare_results(query, df)
     return {
         "skew": skew,
@@ -107,6 +108,7 @@ def check_salting() -> dict:
     from spark_rapids_tpu.columnar import dtypes as dt
     from spark_rapids_tpu.columnar.batch import ColumnarBatch, Schema
     from spark_rapids_tpu.columnar.column import Column
+    from spark_rapids_tpu.execs import adaptive
     from spark_rapids_tpu.execs.base import TpuExec
     from spark_rapids_tpu.execs.exchange import ShuffleExchangeExec
     from spark_rapids_tpu.parallel.mesh import data_mesh
@@ -147,13 +149,13 @@ def check_salting() -> dict:
 
     num_out = 5
     want = drain(ShuffleExchangeExec(("hash", [0]), num_out, _Rows()))
-    pre = disp.replan_snapshot()
+    pre = adaptive.replan_snapshot()
     prog = ShuffleExchangeExec(("hash", [0]), num_out, _Rows())
     prog.enable_in_program(data_mesh(8),
                            skew=SkewSpec(factor=2.0, threshold=1024,
                                          max_splits=8))
     got = drain(prog)
-    events = disp.replan_delta(pre)
+    events = adaptive.replan_delta(pre)
     salted = any(k.startswith("skew_salt") for k in events)
     equal = bool(prog.in_program) and all(
         got[p] == want[p] for p in range(num_out))

@@ -34,10 +34,12 @@ if not os.environ.get("SPARK_RAPIDS_TPU_NO_X64"):
 # (sorts + scans over x64-rewritten graphs) and compile for a long time;
 # caching makes every process after the first start hot. The directory
 # rule is utils/progcache's.
-if not os.environ.get("SPARK_RAPIDS_TPU_NO_COMPILE_CACHE"):
-    from spark_rapids_tpu.utils import progcache as _progcache
+from spark_rapids_tpu.utils import progcache as _progcache  # noqa: E402
 
+if not os.environ.get("SPARK_RAPIDS_TPU_NO_COMPILE_CACHE"):
     _progcache.configure()
+# the compile counters and timers, from JAX's monitoring events
+_progcache.listen()
 
 __version__ = "0.1.0"
 

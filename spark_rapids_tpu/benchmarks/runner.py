@@ -113,6 +113,8 @@ class BenchmarkRunner:
         from spark_rapids_tpu.memory import fault_injection as _fi
         from spark_rapids_tpu.memory import retry as _retry
         from spark_rapids_tpu.memory.catalog import get_catalog
+        from spark_rapids_tpu.execs import adaptive
+        from spark_rapids_tpu.io import scanpipe
         from spark_rapids_tpu.utils import dispatch as disp
 
         from spark_rapids_tpu.parallel import spmd
@@ -130,13 +132,11 @@ class BenchmarkRunner:
         # and ICI-vs-DCN seam decisions over the same window
         run_pre_mesh_fb = pmesh.mesh_fallback_snapshot()
         run_pre_seam = spmd.seam_snapshot()
-        # AQE replan events over the whole run (counters live in
-        # execs.adaptive; the dispatch module passes through so the
-        # telemetry consumers snapshot from one place)
-        run_pre_replan = disp.replan_snapshot()
+        # AQE replan events over the whole run (execs.adaptive's counters)
+        run_pre_replan = adaptive.replan_snapshot()
         # scan-pipeline activity over the run (io/scanpipe counters:
         # bytes read vs pruned, decode/h2d seconds, overlap fraction)
-        run_pre_scan = disp.scan_snapshot()
+        run_pre_scan = scanpipe.snapshot()
         # run-relative snapshots: totals, per-site map, catalog spill
         # counters and injector counts all report DELTAS over this run
         # — a second benchmark in the same process must not inherit the
@@ -200,10 +200,10 @@ class BenchmarkRunner:
         # every AQE replan this run made (skew splits/salting, strategy
         # switches, re-bucketing), with counts — zeros/empty when the
         # static plan ran unchanged
-        result["replan_events"] = disp.replan_delta(run_pre_replan)
+        result["replan_events"] = adaptive.replan_delta(run_pre_replan)
         # ingest telemetry: how much the scan layer read, what pruning
         # saved, and how much of the read+pack hid behind compute
-        result["io_scan"] = disp.scan_delta(run_pre_scan)
+        result["io_scan"] = scanpipe.delta(run_pre_scan)
         if telemetry and result["iterations"]:
             # the BASELINE.md-promised split: dispatch_count x RTT vs
             # time actually spent computing on the device
@@ -244,7 +244,7 @@ class BenchmarkRunner:
                 # which seam (intra-host ICI vs cross-host DCN) carried
                 # each shuffle decision this run
                 "seam_decisions": spmd.seam_delta(run_pre_seam),
-                "replan_events": disp.replan_delta(run_pre_replan),
+                "replan_events": adaptive.replan_delta(run_pre_replan),
                 "compile_cache": progcache.stats(),
             }
         result["query_plan"] = exec_.tree_string()

@@ -27,6 +27,7 @@ import numpy as np
 
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.ops.buckets import bucket_capacity
+from spark_rapids_tpu.utils.tracing import TraceRange, count
 
 
 class Scalar:
@@ -238,9 +239,12 @@ class StringColumn(Column):
         null_mask = np.array([v is None for v in arr], dtype=bool)
         non_null = arr[~null_mask].astype(str) if (~null_mask).any() \
             else np.array([], dtype=str)
-        dictionary, inv = (np.unique(non_null, return_inverse=True)
-                           if len(non_null) else
-                           (np.array([], dtype=object), np.array([], int)))
+        if len(non_null):
+            with TraceRange("strings.dictionary"):
+                dictionary, inv = np.unique(non_null, return_inverse=True)
+            count("strings.dictionary_values", len(non_null))
+        else:
+            dictionary, inv = np.array([], dtype=object), np.array([], int)
         codes = np.zeros(cap, dtype=np.int32)
         codes_valid = np.zeros(n, dtype=np.int32)
         codes_valid[~null_mask] = inv.astype(np.int32)
@@ -287,10 +291,13 @@ def unify_dictionaries(cols: List[StringColumn]) -> List[StringColumn]:
     """
     if not cols:
         return cols
-    merged = np.unique(np.concatenate([c.dictionary.astype(str)
-                                       if len(c.dictionary) else
-                                       np.array([], dtype=str)
-                                       for c in cols]))
+    values = np.concatenate([c.dictionary.astype(str)
+                             if len(c.dictionary) else
+                             np.array([], dtype=str)
+                             for c in cols])
+    with TraceRange("strings.dictionary"):
+        merged = np.unique(values)
+    count("strings.dictionary_values", len(values))
     merged_obj = merged.astype(object)
     out = []
     for c in cols:

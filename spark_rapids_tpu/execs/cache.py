@@ -19,7 +19,7 @@ from spark_rapids_tpu.memory import priorities
 from spark_rapids_tpu.memory.catalog import set_buffer_owner
 from spark_rapids_tpu.memory.spillable import SpillableBatch
 from spark_rapids_tpu.plan.nodes import PlanNode
-from spark_rapids_tpu.utils.tracing import TraceRange
+from spark_rapids_tpu.utils.tracing import TraceRange, count
 
 
 class CacheNode(PlanNode):
@@ -55,21 +55,27 @@ class CacheHolder:
             if self._parts is not None:
                 return
             parts: Dict[int, List[SpillableBatch]] = {}
-            for p in range(child.num_partitions):
-                handles = []
-                for b in child.execute(p):
-                    if b.realized_num_rows() == 0:
-                        continue
-                    # the cache outlives the query that fills it: no
-                    # query's owner tag on its registrations (the service
-                    # sweeps a query's tag when the query ends)
-                    prev = set_buffer_owner(None)
-                    try:
-                        handles.append(SpillableBatch(
-                            b, priorities.INPUT_FROM_SHUFFLE_PRIORITY))
-                    finally:
-                        set_buffer_owner(prev)
-                parts[p] = handles
+            with TraceRange("cache.fill"):
+                for p in range(child.num_partitions):
+                    handles = []
+                    for b in child.execute(p):
+                        rows = b.realized_num_rows()
+                        if rows == 0:
+                            continue
+                        # the cache outlives the query that fills it: no
+                        # query's owner tag on its registrations (the
+                        # service sweeps a query's tag when the query ends)
+                        prev = set_buffer_owner(None)
+                        try:
+                            with TraceRange("cache.register"):
+                                handles.append(SpillableBatch(
+                                    b,
+                                    priorities.INPUT_FROM_SHUFFLE_PRIORITY))
+                        finally:
+                            set_buffer_owner(prev)
+                        count("cache.fill.rows", rows)
+                        count("cache.fill.batches")
+                    parts[p] = handles
             self._parts = parts
 
     def num_partitions(self) -> int:

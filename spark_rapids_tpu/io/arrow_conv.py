@@ -13,6 +13,7 @@ import numpy as np
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.batch import Schema
 from spark_rapids_tpu.io.hoststrings import HostStrings
+from spark_rapids_tpu.utils.tracing import TraceRange, count
 
 
 def schema_from_arrow(arrow_schema, columns: Optional[List[str]] = None
@@ -79,7 +80,9 @@ def column_to_host(col, typ: dt.DType) -> Tuple[np.ndarray, np.ndarray]:
         dvals = col.dictionary.to_numpy(zero_copy_only=False)
         if len(dvals):
             ds = dvals.astype(str)
-            order = np.argsort(ds, kind="stable")
+            with TraceRange("strings.dictionary"):
+                order = np.argsort(ds, kind="stable")
+            count("strings.dictionary_values", len(ds))
             rank = np.empty(len(order), dtype=np.int32)
             rank[order] = np.arange(len(order), dtype=np.int32)
             codes = rank[codes]

@@ -21,6 +21,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from spark_rapids_tpu.utils.tracing import TraceRange, count
+
 
 class HostStrings:
     """One host string column: ``codes`` (int32, one per row; invalid
@@ -68,10 +70,13 @@ class HostStrings:
         null = np.array([x is None for x in arr], dtype=bool)
         non_null = arr[~null].astype(str) if (~null).any() \
             else np.array([], dtype=str)
-        dictionary, inv = (np.unique(non_null, return_inverse=True)
-                           if len(non_null) else
-                           (np.array([], dtype=object),
-                            np.array([], dtype=np.int64)))
+        if len(non_null):
+            with TraceRange("strings.dictionary"):
+                dictionary, inv = np.unique(non_null, return_inverse=True)
+            count("strings.dictionary_values", len(non_null))
+        else:
+            dictionary = np.array([], dtype=object)
+            inv = np.array([], dtype=np.int64)
         codes = np.zeros(len(arr), dtype=np.int32)
         codes[~null] = inv.astype(np.int32)
         return HostStrings(codes, np.asarray(dictionary, dtype=object))
@@ -87,7 +92,10 @@ class HostStrings:
                 np.concatenate([p.codes for p in parts])
                 if parts else np.array([], dtype=np.int32),
                 np.array([], dtype=object))
-        merged = np.unique(np.concatenate(dicts))
+        values = np.concatenate(dicts)
+        with TraceRange("strings.dictionary"):
+            merged = np.unique(values)
+        count("strings.dictionary_values", len(values))
         out_codes = []
         for p in parts:
             if len(p.dictionary):

@@ -41,7 +41,7 @@ import time
 from typing import Optional
 
 from spark_rapids_tpu import config as cfg
-from spark_rapids_tpu.utils import lockorder
+from spark_rapids_tpu.utils import lockorder, tracing
 from spark_rapids_tpu.utils.tracing import TraceRange
 
 # ---------------------------------------------------------------------------
@@ -475,7 +475,8 @@ def _pack_slices(source, exec_, partition, stats, emit):
     while True:
         t0 = time.perf_counter()
         try:
-            chunk = next(chunks)
+            with TraceRange("ScanExec.read"):
+                chunk = next(chunks)
         except StopIteration:
             busy += time.perf_counter() - t0
             break
@@ -657,7 +658,12 @@ def _scan_async(exec_, partition, stats, origin, depth, landing, conf):
                 continue
         return False
 
+    # the producer's spans hang under the consumer's, as run_partitions'
+    # tasks do under their caller's
+    parent_span = tracing.current()
+
     def produce():
+        prev_span = tracing.adopt(parent_span)
         try:
             def emit(p):
                 # charge the admission budget while the packed slice
@@ -677,6 +683,7 @@ def _scan_async(exec_, partition, stats, origin, depth, landing, conf):
         except BaseException as e:  # surface in the consumer
             put(("error", e))
         finally:
+            tracing.adopt(prev_span)
             done_evt.set()
 
     _get_io_pool(conf).submit(produce)

@@ -394,40 +394,6 @@ def stage_program_delta(before: dict) -> dict:
     return out
 
 
-def replan_snapshot() -> dict:
-    """AQE replan-event counts so far ({"rule: detail": n}) — thin
-    passthrough so telemetry consumers snapshot dispatches and replans
-    from one module (the counters live in execs.adaptive)."""
-    from spark_rapids_tpu.execs import adaptive
-
-    return adaptive.replan_snapshot()
-
-
-def replan_delta(before: dict) -> dict:
-    """Replan events recorded since ``before`` (a replan_snapshot)."""
-    from spark_rapids_tpu.execs import adaptive
-
-    return adaptive.replan_delta(before)
-
-
-def scan_snapshot() -> dict:
-    """Scan-pipeline telemetry counters so far — thin passthrough to
-    io.scanpipe so telemetry consumers snapshot dispatches, replans and
-    scans from one module."""
-    from spark_rapids_tpu.io import scanpipe
-
-    return scanpipe.snapshot()
-
-
-def scan_delta(before: dict) -> dict:
-    """The ``io.scan`` block accumulated since ``before`` (a
-    scan_snapshot): bytes read/pruned, decode vs h2d seconds, measured
-    scan–compute overlap fraction, per-format unprunable reasons."""
-    from spark_rapids_tpu.io import scanpipe
-
-    return scanpipe.delta(before)
-
-
 def executable_count() -> int:
     """Distinct compiled executables across all jitted entry points
     (one jit fn compiles once per argument-shape signature)."""

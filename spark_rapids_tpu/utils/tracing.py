@@ -209,6 +209,21 @@ def leaf(name: str, start_ns: int, end_ns: int) -> None:
         _add_rows([(name, 1, dur, dur)])
 
 
+def covered_leaf(name: str, ns: int) -> None:
+    """A timer another clock measured (a compile step, from JAX's own
+    monitoring event): ``ns`` under ``name`` beside the launch timers of
+    the span open on this thread, but not one of its children, since it
+    ran inside the launch that asked for it and that launch's timer
+    already counts. Nothing while recording is off; reads no clock."""
+    if not _recording:
+        return
+    sp = getattr(_tls, "span", None)
+    if sp is not None and sp.thread == threading.get_ident():
+        _add_leaf(sp, name, 1, ns)
+    else:
+        _add_rows([(name, 1, ns, ns)])
+
+
 class TraceRange:
     """Named profiler span (NvtxRange analogue); recorded when recording
     is on."""

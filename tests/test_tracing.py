@@ -178,10 +178,14 @@ for batches in (1, 2):
 import chip_smoke
 s = Session({"rapids.tpu.sql.taskThreads": 1,
              "rapids.tpu.sql.reader.batchSizeRows": 20000})
+rec["fill"] = {}
 for t in ("lineitem", "orders", "customer"):
     df = s.read.parquet(tmp + "/" + t).repartition(2).cache()
     df.create_or_replace_temp_view(t)
-    df.count()
+    pre = disp.snapshot()
+    rec["fill"][t] = {"rows": df.count(), "delta": disp.delta(pre),
+                      "tree": df.last_profile(),
+                      "file_rows": len(pd.read_parquet(tmp + "/" + t))}
 s.sql(chip_smoke.Q3).collect()
 df = s.sql(chip_smoke.Q3)
 from spark_rapids_tpu.memory.catalog import get_catalog
@@ -220,6 +224,21 @@ rec["q3_shuffled"] = {
     "joins": [[type(j).__name__, j.num_partitions]
               for j in joins_of(df._last_exec)]}
 s.stop()
+
+# -- a first call after clear_caches(): its trace, lowering and compile (or
+#    cache read) under the span that launched it, counted as the
+#    benchmark's CompileMeter counts
+from benchmark.meter import CompileMeter
+meter = CompileMeter()
+x = jax.block_until_ready(jnp.arange(8))
+jax.clear_caches()
+c0, before = meter.snapshot(), tracing.counters()
+with tracing.QueryRange() as q:
+    with tracing.TraceRange("hand.first_call"):
+        jax.block_until_ready(jax.jit(lambda v: v * 3 + 1)(x))
+rec["first_call"] = {"tree": tracing.profile(q.query_id),
+                     "counters": tracing.counters_delta(before),
+                     "meter": meter.delta(c0)}
 print(json.dumps(rec))
 """
 
@@ -460,7 +479,9 @@ def test_gather_launch_fence(on, stmt, per_query, parts, batches):
     6 (q1: the coalesce's one fetch of every partition's count, its concat,
     the final group-by and its row count, the sorting chain, the result
     fetch) or 14 (q6) a query, where PR 28 measured 5 a partition and 5 (q1:
-    15, 25, 45) and 6 a partition and 14 (q6: 26, 38, 62) at one batch."""
+    15, 25, 45) and 6 a partition and 14 (q6: 26, 38, 62) at one batch.
+    q1's final side merges its two key columns' dictionaries over every
+    partition's partials: 3 + 2 values a partition sorted on the host."""
     run = on["tpch"]["%s.%d.%d" % (stmt, parts, batches)]
     tree, d = run["tree"], run["delta"]
     for name in _MOVING_SPANS + ("AdaptiveShuffleReaderExec.next",):
@@ -495,9 +516,13 @@ def test_gather_launch_fence(on, stmt, per_query, parts, batches):
     # the final aggregate's one update over the coalesced partials
     assert spans["HashAggregateExec.updateAgg"]["count"] == 1
     # the gather's one block a map task ends with the query
-    assert d["counters"] == {"fused_agg.engaged": parts * batches,
-                             "exchange.blocks.registered": parts,
-                             "exchange.blocks.closed": parts}
+    want = {"fused_agg.engaged": parts * batches,
+            "exchange.blocks.registered": parts,
+            "exchange.blocks.closed": parts}
+    if stmt == "q1":
+        want["strings.dictionary_values"] = 5 * parts
+        assert spans["strings.dictionary"]["count"] == 2
+    assert d["counters"] == want
 
 
 def test_sort_path_aggregate_over_joins_keeps_its_launches(on):
@@ -681,7 +706,12 @@ def test_off_like_counts_entries_and_records_no_span():
     df = s.create_dataframe(pd.DataFrame({"c": words[np.arange(30) % 3]}))
     before = tracing.counters()
     assert df.filter(col("c").like("%special%requests%")).count() == 10
-    assert tracing.counters_delta(before) == {"strings.dict_entries": 3}
+    d = tracing.counters_delta(before)
+    # whether a shape compiles here depends on what this process ran before
+    d.pop("compile.requests", None)
+    d.pop("compile.cache_hits", None)
+    # the scan of the frame sorts its 30 strings into a dictionary
+    assert d == {"strings.dict_entries": 3, "strings.dictionary_values": 30}
     assert tracing.table() == {}
     s.stop()
 
@@ -729,3 +759,140 @@ def test_install_fails_loudly_when_jax_moved_the_hook():
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-3000:]
     assert out.stdout.strip().splitlines()[-1] == "refused"
+
+
+# -- set-up: the cache fill, its dictionary sorts, a first call's compile ---
+
+#: what set-up records, and a window over cached tables does not
+_SETUP_SPANS = ("cache.fill", "cache.register", "compile.trace",
+                "compile.lower", "compile.backend", "compile.cache_read")
+_SETUP_COUNTERS = ("cache.fill.rows", "cache.fill.batches",
+                   "compile.requests", "compile.cache_hits")
+
+
+@pytest.mark.parametrize("table", ["lineitem", "orders", "customer"])
+def test_cache_fill_holds_the_scan_beneath_it(on, table):
+    """A ``count()`` that fills a cache over a scan in 2 partitions: one
+    ``cache.fill``, and beneath it every span of the scan: the chunk reads,
+    dictionary sorts and packs of the IO pool's producer (another thread,
+    which adopts the consumer's span), the uploads, and one
+    ``cache.register`` a batch cached, as many as ``cache.fill.batches``."""
+    run = on["fill"][table]
+    d, tree = run["delta"], run["tree"]
+    (fill,) = _named(tree, "cache.fill")
+    for name in ("ScanExec.read", "ScanExec.pack", "ScanExec.upload",
+                 "cache.register", "strings.dictionary"):
+        below = _named(fill, name)
+        assert below, name
+        # none stands apart as a root: the window's rows are the fill's
+        assert len(below) == d["spans"][name]["count"], name
+    producers = {n["thread"] for n in _named(fill, "ScanExec.read")}
+    assert fill["thread"] not in producers
+    c = d["counters"]
+    assert run["rows"] == run["file_rows"] == c["cache.fill.rows"]
+    assert c["cache.fill.batches"] == len(_named(fill, "cache.register"))
+    assert c["strings.dictionary_values"] >= \
+        d["spans"]["strings.dictionary"]["count"]
+
+
+def test_first_call_times_its_compile_under_the_launching_span(on):
+    """A first call after ``jax.clear_caches()``: its trace, lowering and
+    compile (or cache read) are timers under the span that launched it,
+    inside that launch's own timer, so they take nothing off the span's
+    self time; ``compile.requests`` and ``compile.cache_hits`` count what
+    the benchmark's ``CompileMeter`` counts."""
+    run = on["first_call"]
+    (span,) = run["tree"]["children"]
+    assert span["name"] == "hand.first_call"
+    kids = {c["name"]: c for c in span["children"]}
+    steps = ("compile.trace", "compile.lower", "compile.backend")
+    for name in steps:
+        assert kids[name]["count"] >= 1 and kids[name]["total_ns"] > 0, name
+    launch = kids["launch.jit"]
+    assert launch["count"] == 1
+    assert sum(kids[n]["total_ns"] for n in steps) <= launch["total_ns"]
+    assert span["self_ns"] == span["total_ns"] - launch["total_ns"]
+    m, c = run["meter"], run["counters"]
+    assert c["compile.requests"] == m["programs_compiled"] + m["cache_hits"]
+    assert c["compile.requests"] == kids["compile.backend"]["count"]
+    assert c.get("compile.cache_hits", 0) == m["cache_hits"]
+    assert ("compile.cache_read" in kids) == bool(m["cache_hits"])
+
+
+@pytest.mark.parametrize("window", ["q1", "like", "tpch", "q3",
+                                    "q3_shuffled"])
+def test_window_over_cached_tables_fills_and_compiles_nothing(on, window):
+    """A repeated query over cached tables: no span and no counter of
+    set-up moves, and no dictionary is sorted but by TPC-H q1's final side
+    (two, see ``test_gather_launch_fence``)."""
+    deltas = {"q1": [on["q1_delta"]], "like": [on["like_delta"]],
+              "tpch": [r["delta"] for r in on["tpch"].values()],
+              "q3": [on["q3"]["delta"]],
+              "q3_shuffled": [on["q3_shuffled"]["delta"]]}[window]
+    for d in deltas:
+        assert not [n for n in d["spans"]
+                    if n in _SETUP_SPANS or n.startswith("ScanExec.")], d
+        assert not [n for n in d["counters"] if n in _SETUP_COUNTERS], d
+    if window != "tpch":
+        for d in deltas:
+            assert "strings.dictionary" not in d["spans"], d
+
+
+def test_off_set_up_sites_read_no_clock_and_still_count(monkeypatch):
+    """With the recorder off, a cache fill that sorts a string dictionary
+    and a first call read no clock of ``utils/tracing`` and leave no
+    record; the counters count all the same."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.api import Session
+    from spark_rapids_tpu.utils import tracing
+
+    class _NoClock:
+        @staticmethod
+        def perf_counter_ns():
+            raise AssertionError("a clock was read with recording off")
+
+    assert not tracing.recording()
+    x = jax.block_until_ready(jnp.arange(8))
+    s = Session()
+    words = np.array(["special requests", "pending", "requests special"],
+                     dtype=object)
+    df = s.create_dataframe(
+        pd.DataFrame({"c": words[np.arange(30) % 3]})).cache()
+    monkeypatch.setattr(tracing, "time", _NoClock)
+    before = tracing.counters()
+    assert df.count() == 30
+    jax.block_until_ready(jax.jit(lambda v: v * 5 - 2)(x))
+    d = tracing.counters_delta(before)
+    assert (d["cache.fill.rows"], d["cache.fill.batches"]) == (30, 1), d
+    assert d["strings.dictionary_values"] == 30, d
+    assert d["compile.requests"] >= 1, d
+    assert tracing.table() == {}
+    s.stop()
+
+
+@pytest.mark.parametrize("metric,want", [("fill_s", 5.0),
+                                         ("fill_dictionary_s", None),
+                                         ("program_load_s", 5.5)])
+def test_setup_readers_take_the_process_less_the_window(monkeypatch, metric,
+                                                        want):
+    """The set-up metrics read the process's span table less the window's:
+    ``compile.cache_read`` lies inside ``compile.backend`` and is not
+    added; a span the program never recorded reads nothing."""
+    from benchmark import run as bench
+    from spark_rapids_tpu.utils import dispatch
+
+    def row(total_s):
+        return {"count": 1, "total_s": total_s, "self_s": total_s}
+
+    table = {"cache.fill": row(5.0), "compile.trace": row(1.0),
+             "compile.lower": row(0.5), "compile.backend": row(4.5),
+             "compile.cache_read": row(3.0), "query": row(9.0)}
+    window = {"compile.backend": row(0.5), "query": row(2.0)}
+    monkeypatch.setattr(dispatch, "snapshot", lambda: {"spans": table})
+    how = bench.load_json(bench.HERE, "metrics", metric + ".json")
+    reader = bench.load_module("readers", how["reader"])
+    got = reader.read({"dispatch": {"spans": window}}, **how["args"])
+    assert got == pytest.approx(want) if want is not None else got is None
+    assert reader.read({"dispatch": None}, **how["args"]) is None
